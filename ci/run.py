@@ -260,9 +260,10 @@ def stage_compile_cache_smoke(_):
 
 
 def stage_bench_smoke(_):
-    """bench.py CPU fallback path must emit its JSON line."""
+    """bench.py's phase code must emit its JSON line. On the CPU because
+    this stage SAYS so: cpu_mesh_env exports JAX_PLATFORMS=cpu, without
+    which bench.py refuses any platform but the chip."""
     env = _env_cpu_mesh(1)
-    env["_BENCH_CHILD"] = "1"
     return subprocess.call(
         [sys.executable, os.path.join(ROOT, "bench.py"), "--run"],
         env=env, cwd=ROOT)

@@ -2,9 +2,10 @@
 
 Mirrors the reference's layering: Python rides a flat C ABI over the native
 library (reference: python/mxnet/base.py check_call over libmxnet.so). The
-library is built on demand with `make -C src` the first time it's needed;
-environments without a toolchain fall back to pure-Python paths where one
-exists (callers check `available()`).
+library is built on demand with `make -C src` the first time it's needed, and
+again whenever a file under src/ is newer than it; environments without a
+toolchain fall back to pure-Python paths where one exists (callers check
+`available()`).
 """
 from __future__ import annotations
 
@@ -34,12 +35,27 @@ def _build():
         return False
 
 
+def _stale():
+    """True when the library is missing or any file under src/ is newer
+    than it — a checkout updated under a built library must not keep
+    loading the old ABI."""
+    try:
+        built = os.path.getmtime(_LIB_PATH)
+    except OSError:
+        return True
+    for root, _, files in os.walk(_SRC_DIR):
+        for name in files:
+            if os.path.getmtime(os.path.join(root, name)) > built:
+                return True
+    return False
+
+
 def _load():
     global _lib
     with _lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_LIB_PATH) and not _build():
+        if _stale() and not _build():
             return None
         try:
             lib = ctypes.CDLL(_LIB_PATH)

@@ -388,8 +388,6 @@ def extract_contract(builder, args, mesh=None, plan=None, site=None):
         per_axis[c["axis"]] = per_axis.get(c["axis"], 0) + c["bytes"]
 
     ca = lowered.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     ma = exe.memory_analysis()
     arg_b = int(getattr(ma, "argument_size_in_bytes", 0) or 0)
     out_b = int(getattr(ma, "output_size_in_bytes", 0) or 0)

@@ -95,56 +95,39 @@ _compile_cache_state = {"configured": False, "dir": None}
 
 
 def configure_compile_cache():
-    """Wire `MXNET_TPU_COMPILE_CACHE` into JAX's persistent compilation
-    cache (docs/faq/env_var.md). When the variable names a directory, XLA
-    executables — including every serving bucket program — are persisted
-    there so cold-start compile cost survives process restarts: a warmed
-    serving engine's re-warmup after redeploy becomes a disk read.
+    """Place JAX's persistent compilation cache (docs/faq/env_var.md).
+
+    Where `JAX_COMPILATION_CACHE_DIR` (or an earlier `jax.config` update)
+    already names a directory, that directory is the cache and this sets no
+    other: whoever runs the program places its cache from outside. Otherwise
+    `MXNET_TPU_COMPILE_CACHE`, when it names a directory, is wired in, so
+    XLA executables — including every serving bucket program — survive
+    process restarts and a redeployed engine's re-warmup is a disk read.
 
     Idempotent and safe to call from any number of entry points (serving
-    program cache, Executor.warmup); explicit JAX_COMPILATION_CACHE_DIR /
-    prior jax.config settings win, mirroring how the reference's env knobs
-    defer to more specific configuration. Returns the active cache dir or
-    None."""
+    program cache, Executor.warmup). Returns the active cache dir or None."""
     if _compile_cache_state["configured"]:
         return _compile_cache_state["dir"]
     _compile_cache_state["configured"] = True
+    import jax
+    current = jax.config.jax_compilation_cache_dir
+    if current:
+        _compile_cache_state["dir"] = current
+        return current
     path = get_env("MXNET_TPU_COMPILE_CACHE")
     if not path:
         return None
-    import jax
-    try:
-        current = jax.config.jax_compilation_cache_dir
-    except AttributeError:  # pragma: no cover - very old jax
-        return None
-    if current:  # user already pointed jax at a cache; don't fight it
-        _compile_cache_state["dir"] = current
-        return current
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-    except Exception:  # cache genuinely off
-        return None
+    jax.config.update("jax_compilation_cache_dir", path)
     # serving bucket programs are small and fast-compiling relative to
-    # train steps; cache them all so warmup hits disk, not XLA. On a jax
-    # without these tuning knobs the cache is STILL ON (dir was set above)
-    # with that jax's default thresholds — the return value must say so.
-    for opt, val in (("jax_persistent_cache_min_compile_time_secs", 0),
-                     ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(opt, val)
-        except Exception:
-            pass
-    try:
-        # jax initializes its compilation cache LAZILY on the first
-        # compile and then never re-reads the config — and importing
-        # mxnet_tpu itself triggers a small compile, so by the time this
-        # runs the cache has typically been frozen as "disabled". Reset
-        # it so the next compile re-initializes against the dir above
-        # (without this the env var silently configured a dead cache).
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:
-        pass
+    # train steps; cache them all so warmup hits disk, not XLA
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # jax initializes its compilation cache LAZILY on the first compile and
+    # then never re-reads the config; a compile before this call froze it
+    # as "disabled". Reset it so the next compile re-initializes against
+    # the dir above.
+    from jax.experimental.compilation_cache import compilation_cache as _cc
+    _cc.reset_cache()
     _compile_cache_state["dir"] = path
     return path
 

@@ -350,40 +350,33 @@ class ProgramBuilder:
 
     def _compile_after_cache_corruption(self, lowered, err):
         """A compile that failed WITH a persistent compile cache
-        configured is most plausibly a truncated/corrupt cache entry
-        (half-written by a killed process, bit-rotted on shared disk) —
-        that must degrade to a cache miss, never crash warmup. Recompile
-        once with the cache bypassed; a genuine compile error fails the
-        retry identically and surfaces. No cache configured: the original
-        error surfaces untouched (zero behavior change)."""
+        configured may be a truncated/corrupt cache entry (half-written by
+        a killed process, bit-rotted on shared disk) — that must degrade
+        to a cache miss, never crash warmup. Recompile once with the cache
+        bypassed. Where the retry fails too, the cache was not the cause:
+        the FIRST error — the XLA/Mosaic compile error itself — is what
+        surfaces, and nothing is counted as corruption. No cache
+        configured: the original error surfaces untouched."""
         from ..base import compile_cache_dir
         if compile_cache_dir() is None:
             raise err
+        import jax
+        with _CACHE_BYPASS_LOCK:
+            jax.config.update("jax_enable_compilation_cache", False)
+            try:
+                prog = lowered.compile()  # tpulint: allow-lock-device-call recovery must serialize: the bypass toggles the process-global compilation-cache flag
+            except Exception:
+                raise err from None
+            finally:
+                jax.config.update("jax_enable_compilation_cache", True)
         from .. import profiler as _prof
         _prof.record_compile_corrupt(self.site)
         import logging
         logging.getLogger(__name__).warning(
             "persistent compile cache read failed for %s (%s: %s); "
-            "degrading to a cache miss and recompiling", self.site,
+            "degraded to a cache miss and recompiled", self.site,
             type(err).__name__, err)
-        import jax
-        with _CACHE_BYPASS_LOCK:
-            disabled = False
-            try:
-                jax.config.update("jax_enable_compilation_cache", False)
-                disabled = True
-            except Exception:
-                # jax without the knob: still retry once — transient cache
-                # I/O may clear, and a persistent failure surfaces below
-                pass  # tpulint: allow-swallowed-exception best-effort cache bypass; the retry below surfaces real errors
-            try:
-                return lowered.compile()  # tpulint: allow-lock-device-call recovery must serialize: the bypass toggles the process-global compilation-cache flag
-            finally:
-                if disabled:
-                    try:
-                        jax.config.update("jax_enable_compilation_cache", True)
-                    except Exception:
-                        pass  # tpulint: allow-swallowed-exception re-enable is best-effort; cache-off only costs persistence
+        return prog
 
     # ------------------------------------------------------------------
     # dispatch

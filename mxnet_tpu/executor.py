@@ -399,8 +399,6 @@ class Executor:
         # these shapes dispatches (no second program for the analysis)
         lowered = builder.lowered(*args)
         ca = lowered.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
         ma = builder.aot(*args).memory_analysis()
         return {"flops": float(ca.get("flops", 0.0)),
                 # peak live set (activations included) — temp_size alone
@@ -409,18 +407,26 @@ class Executor:
                 "temp_bytes": float(getattr(ma, "temp_size_in_bytes", 0))}
 
     def forward(self, is_train=False, **kwargs):
+        dev = self._ctx.jax_device
         for k, v in kwargs.items():
             if k not in self.arg_dict:
                 raise MXNetError("unknown forward argument %r" % k)
             if isinstance(v, NDArray):
-                self.arg_dict[k]._data = v._data
-            elif isinstance(v, jax.Array):
+                v = v._data
+            if isinstance(v, jax.Array):
                 # already device-resident (e.g. a prefetch-staged batch):
                 # adopt the buffer as-is — np.asarray() would round-trip
-                # it device->host->device
+                # it device->host->device. A buffer committed to ANOTHER
+                # single device (a default-context host NDArray handed to
+                # an executor bound on the chip) is moved here, as the
+                # reference's copy into the bound array does; jit refuses
+                # arguments committed to two devices.
+                devs = v.devices()
+                if len(devs) == 1 and devs != {dev}:
+                    v = jax.device_put(v, dev)
                 self.arg_dict[k]._data = v
             else:
-                self.arg_dict[k]._data = jnp.asarray(_np.asarray(v))
+                self.arg_dict[k]._data = jax.device_put(_np.asarray(v), dev)
 
         arg_vals = {n: a._data for n, a in self.arg_dict.items()}
         aux_vals = {n: a._data for n, a in self.aux_dict.items()}

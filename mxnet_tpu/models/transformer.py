@@ -101,8 +101,11 @@ def transformer_sharding_rules(cfg, mesh):
     pipeline path shards it over 'pp' instead.
     """
     tp = "tp" if "tp" in mesh.axis_names else None
+    # a vocabulary the tp degree does not divide (GPT-2's 50257) cannot be
+    # split by rows: that table stays replicated
+    vocab_tp = tp if tp and cfg.vocab_size % mesh.shape[tp] == 0 else None
     return {
-        "embed": P(tp, None),
+        "embed": P(vocab_tp, None),
         "pos_embed": P(),
         "ln_f_scale": P(),
         "ln_f_bias": P(),
@@ -170,8 +173,11 @@ def _attention(q, k, v, cfg, mesh):
     if pad:
         padw = ((0, 0), (0, 0), (0, pad), (0, 0))
         q, k, v = (jnp.pad(t, padw) for t in (q, k, v))
-    out = shard_map(local, mesh=mesh, in_specs=(spec,) * 3,
-                    out_specs=spec)(q, k, v)
+    # the Pallas kernels declare no varying-mesh-axes type on their outputs
+    # (and the interpret-mode evaluator cannot carry one), so the island
+    # checks replication only on the lax tier — as flash_attention_mesh does
+    out = shard_map(local, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
+                    check_vma=not (kt_pallas or kt_interpret))(q, k, v)
     return out[:, :, :S] if pad else out
 
 

@@ -22,29 +22,9 @@ import numpy as _np
 _DIST_INITIALIZED = False
 
 
-def shard_map(f, mesh=None, in_specs=None, out_specs=None, **kwargs):
-    """Version-portable `shard_map`.
-
-    JAX moved manual SPMD from `jax.experimental.shard_map.shard_map` to
-    the top-level `jax.shard_map` (and removed the experimental home); the
-    pinned toolchain here ships only one of the two depending on version.
-    Every caller in this tree (ring attention, pipeline, transformer
-    attention islands, the multichip harness, tests) routes through this
-    resolver so a jax upgrade/downgrade can't strand the sequence-parallel
-    stack on a missing symbol again (the PR-5-era tier-1 ring-attention
-    failure)."""
-    impl = getattr(jax, "shard_map", None)
-    if impl is None:  # pre-move toolchains: the experimental home
-        from jax.experimental.shard_map import shard_map as impl
-    # the replication-check kwarg was renamed check_rep -> check_vma in the
-    # move; accept either spelling and hand the impl the one it knows
-    check = kwargs.pop("check_vma", kwargs.pop("check_rep", None))
-    if check is not None:
-        import inspect
-        params = set(inspect.signature(impl).parameters)
-        kwargs["check_vma" if "check_vma" in params else "check_rep"] = check
-    return impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                **kwargs)
+# manual SPMD entry point of the installed JAX, re-exported so the parallel
+# stack (ring attention, pipeline, kernel islands) names it in one place
+shard_map = jax.shard_map
 
 
 def ensure_distributed():

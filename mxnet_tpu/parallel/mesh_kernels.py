@@ -175,7 +175,7 @@ def flash_attention_mesh(q, k, v, mesh, *, causal=False, sm_scale=None,
 
     spec = P(bq, hq, None, None)
     fn = shard_map(body, mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
-                   check_rep=False)
+                   check_vma=False)
     return fn(q, k, v)
 
 
@@ -294,7 +294,7 @@ def fused_update_mesh(optimizer, hp, params, opt_state, grads, mesh,
         return new_params, new_state
 
     fn = shard_map(body, mesh=mesh, in_specs=(P(), P(), P(), P()),
-                   out_specs=(P(), P()), check_rep=False)
+                   out_specs=(P(), P()), check_vma=False)
     return fn(params, opt_state, grads, jnp.asarray(hp["lr"], jnp.float32))
 
 

@@ -197,5 +197,5 @@ def apply_update_sharded(optimizer, hp, params, opt_state, grads, layout,
         in_specs=({n: P() for n in params}, state_specs,
                   {n: P() for n in params}, P()),
         out_specs=({n: P() for n in params}, state_specs),
-        check_rep=False)
+        check_vma=False)
     return fn(params, opt_state, grads, jnp.asarray(hp["lr"], jnp.float32))

@@ -555,8 +555,7 @@ _pcache_tls = threading.local()
 
 def _pcache_listener(event, **kwargs):
     # jax.monitoring fires this name once per compile served from the
-    # persistent compilation cache (any jax version that lacks the event
-    # simply never calls us). It fires SYNCHRONOUSLY on the thread
+    # persistent compilation cache. It fires SYNCHRONOUSLY on the thread
     # running the compile, so the thread-local count lets a builder
     # attribute a hit to ITS compile even while another thread's compile
     # (compile-outside-lock) is in flight.
@@ -574,13 +573,8 @@ def ensure_compile_listener():
         if _pcache["listener"]:
             return
         _pcache["listener"] = True
-    try:
-        from jax import monitoring as _monitoring
-        _monitoring.register_event_listener(_pcache_listener)
-    except Exception:
-        # jax without the monitoring API: persistent hits read 0, the
-        # compile_ms counters still carry the cold/warm signal
-        _pcache["listener"] = False
+    from jax import monitoring as _monitoring
+    _monitoring.register_event_listener(_pcache_listener)
 
 
 def persistent_cache_hit_count():

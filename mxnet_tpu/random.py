@@ -15,9 +15,22 @@ __all__ = ["seed", "next_key", "current_key", "set_key"]
 
 class _RngState(threading.local):
     def __init__(self):
-        self.key = jax.random.PRNGKey(0)
+        # the chain's key is made on first use: building it here would
+        # initialise the default backend — take the chip — on `import
+        # mxnet_tpu`, in every helper process that merely imports the package
+        self._key = None
         self.trace_key = None  # set while tracing a jitted program
         self.trace_consumed = False  # did the current trace draw a key?
+
+    @property
+    def key(self):
+        if self._key is None:
+            self._key = jax.random.PRNGKey(0)
+        return self._key
+
+    @key.setter
+    def key(self, value):
+        self._key = value
 
 
 _STATE = _RngState()

@@ -45,8 +45,13 @@ class PallasKernel(object):
                 for a in args]
         out_shape = self._out_shape_fn(*[jax.ShapeDtypeStruct(v.shape, v.dtype)
                                          for v in vals])
+        # compiled where the ARGUMENTS live on a TPU, interpreted anywhere
+        # else: default-context (host) arrays on a chip machine lower for
+        # the CPU, where Pallas only interprets
+        platform = (next(iter(vals[0].devices())).platform if vals
+                    else jax.default_backend())
         interpret = (self._interpret if self._interpret is not None
-                     else jax.default_backend() != "tpu")
+                     else platform != "tpu")
         call_kwargs = dict(out_shape=out_shape, interpret=interpret)
         if grid is not None:
             call_kwargs["grid"] = grid

@@ -57,6 +57,13 @@ class LocalProcessLauncher:
         Extra environment for spawned workers (merged over os.environ —
         e.g. a PYTHONPATH carrying the builder module, or
         ``MXNET_SERVING_AUTH_KEY``).
+
+    One process per chip: a launcher whose own process has initialised a
+    JAX backend holds whatever chips this host has, so a local worker that
+    asked for one would fail or hang. Workers of such a parent are pinned
+    to the CPU backend (``JAX_PLATFORMS=cpu``) unless ``env`` names the
+    platform itself; workers that are to own a chip are launched from a
+    process that has not touched JAX.
     """
 
     def __init__(self, gateway, builder, env=None, python=None,
@@ -76,6 +83,10 @@ class LocalProcessLauncher:
         env = dict(os.environ)
         if self._env:
             env.update(self._env)
+        from jax._src import xla_bridge
+        if "JAX_PLATFORMS" not in (self._env or {}) \
+                and xla_bridge.backends_are_initialized():
+            env["JAX_PLATFORMS"] = "cpu"
         proc = subprocess.Popen(
             [self._python, "-m", "mxnet_tpu.serving.worker",
              "--gateway", str(self._gateway),

@@ -51,10 +51,7 @@ def _donate_supported():
     """Buffer donation is a no-op (with a per-compile warning) on the CPU
     backend; only enable it where XLA honors it."""
     import jax
-    try:
-        return jax.devices()[0].platform not in ("cpu",)
-    except Exception:
-        return False
+    return jax.devices()[0].platform != "cpu"
 
 
 class BucketedProgramCache:

@@ -726,8 +726,7 @@ class TestF64:
         assert not check_symbol_f64(out)
 
     def test_jaxpr_f64_flagged_under_x64(self):
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             jx = jax.make_jaxpr(lambda x: x * 2.0)(np.zeros(3, np.float64))
         fs = check_jaxpr_f64(jx)
         assert fs and all(f.rule_id == "TPL201" for f in fs)
@@ -735,8 +734,7 @@ class TestF64:
     def test_nested_pjit_leak_counted_once(self):
         # a pjit sub-jaxpr repeats the program invars — one leak must
         # produce one finding, not one per nesting level
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             inner = jax.jit(lambda x: x * 2.0)
             jx = jax.make_jaxpr(lambda x: inner(x) + 1.0)(
                 np.float64(1.0))
@@ -746,8 +744,7 @@ class TestF64:
     def test_pjit_wrapper_outvar_not_double_counted(self):
         # the pjit eqn re-exports its sub-jaxpr's result — the inner scan
         # reports the producing op; the wrapper must not tally it again
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             inner = jax.jit(lambda x: x.astype(np.float64) * 2.0)
             jx = jax.make_jaxpr(lambda x: inner(x))(np.float32(1.0))
         fs = check_jaxpr_f64(jx)
@@ -959,10 +956,9 @@ class TestInt8ProgramShapes:
         # even under x64 (where a stray Python-float promotion WOULD
         # surface): the int8 program's int32 accumulators and range
         # arithmetic stay out of f64
-        from jax.experimental import enable_x64
         jx, _ = self._quantized_jaxpr()
         assert not check_jaxpr_f64(jx)
-        with enable_x64():
+        with jax.enable_x64(True):
             jx64, _ = self._quantized_jaxpr()
         assert not check_jaxpr_f64(jx64)
 

@@ -135,3 +135,25 @@ def test_held_output_reference_sees_forward_results():
     exe.arg_dict["x"][:] = [5.0, 5.0]
     exe.forward()
     np.testing.assert_allclose(held.asnumpy(), [10.0, 10.0])
+
+
+def test_forward_moves_a_host_array_to_the_bound_device():
+    """A default-context NDArray handed to an executor bound on another
+    device is moved there (the reference copies into the bound array); jit
+    refuses arguments committed to two devices — on a chip machine that is
+    every `exe.forward(data=mx.nd.array(x))`."""
+    import jax
+    data = mx.sym.Variable("data")
+    net = mx.sym.FullyConnected(data, num_hidden=3, name="fc")
+    exe = net.simple_bind(mx.tpu(0), grad_req="null", data=(2, 4))
+    exe.arg_dict["fc_weight"][:] = 0.5
+    x = np.arange(8, dtype=np.float32).reshape(2, 4)
+    bound = mx.tpu(0).jax_device
+    for value in (mx.nd.array(x, ctx=mx.cpu(1)),           # NDArray elsewhere
+                  jax.device_put(x, mx.cpu(1).jax_device),  # jax.Array elsewhere
+                  x):                                       # host numpy
+        out = exe.forward(is_train=False, data=value)[0]
+        assert exe.arg_dict["data"]._data.devices() == {bound}
+        assert out._data.devices() == {bound}
+        np.testing.assert_allclose(out.asnumpy(),
+                                   x @ np.full((4, 3), 0.5, np.float32))

@@ -865,3 +865,28 @@ def test_multiprocess_fleet_kill_exactly_once_and_readmission():
                 p.wait(timeout=15)
             except subprocess.TimeoutExpired:
                 p.kill()
+
+
+def test_local_launcher_pins_workers_off_a_held_chip(monkeypatch):
+    """One process per chip: a launcher whose own process has initialised
+    JAX holds the host's chips, so its local workers are pinned to the CPU
+    backend in the env it builds — unless the caller names the platform."""
+    import jax
+    from mxnet_tpu.serving import autoscaler as _as
+    jax.devices()  # this process holds its backend
+    seen = []
+
+    class _Proc:
+        pid = 0
+
+        def poll(self):
+            return None
+
+    monkeypatch.setattr(_as.subprocess, "Popen",
+                        lambda cmd, env=None: seen.append(env) or _Proc())
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    _as.LocalProcessLauncher("127.0.0.1:1", "m:f").launch()
+    _as.LocalProcessLauncher("127.0.0.1:1", "m:f",
+                             env={"JAX_PLATFORMS": "tpu"}).launch()
+    assert seen[0]["JAX_PLATFORMS"] == "cpu"
+    assert seen[1]["JAX_PLATFORMS"] == "tpu"

@@ -503,3 +503,26 @@ def test_det_record_iter_sharding(det_rec_file):
     s1 = ImageDetRecordIter(num_parts=2, part_index=1, **kw)
     assert s0.num_samples + s1.num_samples == full.num_samples
     assert abs(s0.num_samples - s1.num_samples) <= 1
+
+
+def test_stale_library_is_rebuilt(tmp_path, monkeypatch):
+    """A library older than anything under src/ is stale: _load rebuilds
+    before loading instead of reusing the old ABI, and a failed rebuild is
+    'unavailable', not a silent load of the stale file."""
+    from mxnet_tpu import _native
+    src = tmp_path / "src"
+    src.mkdir()
+    (src / "a.cc").write_text("// source")
+    lib_mtime = os.path.getmtime(_native._LIB_PATH)
+    monkeypatch.setattr(_native, "_SRC_DIR", str(src))
+    os.utime(str(src / "a.cc"), (lib_mtime - 10, lib_mtime - 10))
+    assert not _native._stale()
+    os.utime(str(src / "a.cc"), (lib_mtime + 10, lib_mtime + 10))
+    assert _native._stale()
+    monkeypatch.setattr(_native, "_LIB_PATH", str(tmp_path / "missing.so"))
+    assert _native._stale()
+
+    calls = []
+    monkeypatch.setattr(_native, "_lib", None)
+    monkeypatch.setattr(_native, "_build", lambda: calls.append(1) or False)
+    assert _native._load() is None and calls == [1]

@@ -95,10 +95,28 @@ def test_fused_lax_bitwise_parity(optimizer, clip):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def _assert_within_operand_ulps(got, want, operands, ulps=4):
+    """|got - want| <= `ulps` units in the last place AT THE MAGNITUDE OF THE
+    LARGEST OPERAND, and all but 1% of elements bit-identical: the parity
+    contract of kernels/opt_update.py for the interpret tier, where XLA:CPU
+    contracts a*b + c into a fused multiply-add per fusion (one rounding
+    per contraction; a raw ulp count of the result would blow up wherever
+    the update cancels)."""
+    got, want = np.asarray(got), np.asarray(want)
+    scale = np.max([np.abs(np.asarray(o)) for o in operands]
+                   + [np.abs(got), np.abs(want)], axis=0)
+    bound = ulps * np.spacing(scale.astype(np.float32))
+    assert (np.abs(got - want) <= bound).all(), \
+        float((np.abs(got - want) / np.spacing(scale)).max())
+    assert (got != want).mean() <= 0.01
+
+
 @pytest.mark.parametrize("optimizer", ["sgd", "sgd_momentum", "adam"])
 def test_fused_kernel_interpret_parity(optimizer):
-    """The Pallas kernel body (interpret mode — same arithmetic the TPU
-    kernel executes) is bit-identical to the jitted tree-map route."""
+    """The Pallas kernel body in interpret mode agrees with the jitted
+    tree-map route to the contract's 4 operand-ulps. (On the TPU the
+    compiled kernel and the lax leaf are bit-identical — chip_smoke.py's
+    kernels phase measures that; here both sides are XLA:CPU programs.)"""
     opt = "sgd" if optimizer.startswith("sgd") else optimizer
     rng = np.random.RandomState(3)
     params = _make_tree(rng)
@@ -117,9 +135,16 @@ def test_fused_kernel_interpret_parity(optimizer):
     lr = np.float32(hp["lr"])
     p_ref, s_ref = ref(params, st, grads, lr)
     p_k, s_k = kern(params, st, grads, lr)
-    for a, b in zip(jax.tree_util.tree_leaves((p_ref, s_ref)),
-                    jax.tree_util.tree_leaves((p_k, s_k))):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for name in params:
+        operands = [params[name], grads[name]] + [
+            slot[name] for slot in st.values() if isinstance(slot, dict)]
+        _assert_within_operand_ulps(p_k[name], p_ref[name], operands)
+        for key, slot in s_ref.items():
+            if isinstance(slot, dict):
+                _assert_within_operand_ulps(s_k[key][name], slot[name],
+                                            operands)
+    if "t" in s_ref:
+        assert int(s_k["t"]) == int(s_ref["t"])
 
 
 def test_kernel_eligibility_split():

@@ -509,6 +509,33 @@ class TestCacheCorruptionTolerance:
         assert site["cache_corrupt"] == 1
         assert site["compiles"] == 1         # the recompile succeeded
 
+    def test_compile_error_with_cache_is_reported_as_itself(self, tmp_path,
+                                                            monkeypatch):
+        """A genuine compile error fails the cache-bypassed retry too: the
+        FIRST error is what surfaces (an XLA/Mosaic refusal must read as
+        itself, not as the retry's), and nothing counts as corruption."""
+        from mxnet_tpu import base as mx_base
+        monkeypatch.setitem(mx_base._compile_cache_state, "dir",
+                            str(tmp_path))
+        b = ProgramBuilder(_fn, site="compile_error_first")
+        lowered = b.lowered(_sds(), _sds())
+        errors = iter([RuntimeError("first: the compiler refused"),
+                       RuntimeError("second: the retry's")])
+
+        class _Refusing:
+            def compile(self):
+                raise next(errors)
+
+        monkeypatch.setitem(b._lowered, b.key(_sds(), _sds()), _Refusing())
+        with pytest.raises(RuntimeError, match="first: the compiler"):
+            b.aot(_sds(), _sds())
+        assert lowered is not None
+        site = profiler.compile_counters()["sites"].get(
+            "compile_error_first", {})
+        assert site.get("cache_corrupt", 0) == 0
+        import jax
+        assert jax.config.jax_enable_compilation_cache  # bypass undone
+
     def test_cache_read_fault_without_cache_surfaces(self, monkeypatch):
         """No persistent cache configured: a compile failure is a real
         compile failure — zero behavior change, the error surfaces."""

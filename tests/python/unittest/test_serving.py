@@ -452,25 +452,33 @@ def test_engine_from_hybrid_block():
 # MXNET_TPU_COMPILE_CACHE (satellite: base.py env wiring)
 # ---------------------------------------------------------------------------
 
-def test_compile_cache_env_wiring(tmp_path, monkeypatch):
+@pytest.mark.parametrize("placed_outside", [False, True])
+def test_compile_cache_env_wiring(tmp_path, monkeypatch, placed_outside):
+    """MXNET_TPU_COMPILE_CACHE places the cache only where nobody placed it
+    from outside: with JAX_COMPILATION_CACHE_DIR set (jax reads it into
+    jax_compilation_cache_dir) that directory is used and no other is set
+    in code."""
     import jax
     from mxnet_tpu import base
     prev_dir = jax.config.jax_compilation_cache_dir
     prev_state = dict(base._compile_cache_state)
+    outside = str(tmp_path / "outside")
     try:
+        jax.config.update("jax_compilation_cache_dir",
+                          outside if placed_outside else None)
         base._compile_cache_state.update(configured=False, dir=None)
         monkeypatch.delenv("MXNET_TPU_COMPILE_CACHE", raising=False)
-        assert base.configure_compile_cache() is None  # unset -> no-op
-        base._compile_cache_state.update(configured=False, dir=None)
-        monkeypatch.setenv("MXNET_TPU_COMPILE_CACHE", str(tmp_path))
-        if prev_dir:  # explicit jax config wins over our env var
-            assert base.configure_compile_cache() == prev_dir
-        else:
-            assert base.configure_compile_cache() == str(tmp_path)
-            assert jax.config.jax_compilation_cache_dir == str(tmp_path)
-        # idempotent: second call returns the cached answer
+        # our variable unset: whatever is (not) there stays
         assert base.configure_compile_cache() == \
-            base._compile_cache_state["dir"]
+            (outside if placed_outside else None)
+        base._compile_cache_state.update(configured=False, dir=None)
+        monkeypatch.setenv("MXNET_TPU_COMPILE_CACHE", str(tmp_path / "ours"))
+        want = outside if placed_outside else str(tmp_path / "ours")
+        assert base.configure_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert base.compile_cache_dir() == want
+        # idempotent: second call returns the cached answer
+        assert base.configure_compile_cache() == want
     finally:
         jax.config.update("jax_compilation_cache_dir", prev_dir)
         base._compile_cache_state.clear()

@@ -1,0 +1,160 @@
+"""The TPU compiler as a tier-1 guard: the kernels of the main path compiled
+at their real widths for a DESCRIBED v5e (no chip attached, nothing runs).
+
+What interpret mode cannot show — a slice the tiling rejects, more VMEM than
+a kernel may use — the compiler refuses here, at no chip time. Every case
+asserts the Pallas kernel is in the compiled text (`tpu_custom_call`).
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load the TPU library, xdist workers all import this
+file, and only the worker that runs it may make the call. All cases stay in
+this one file (a second file could land on another worker, whose fixture
+would then skip), compile in this process, and keep the persistent compile
+cache off (a described-device executable cannot be read back from it).
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from mxnet_tpu.kernels.flash_attention import (flash_attention,
+                                               flash_attention_with_lse)
+from mxnet_tpu.kernels.opt_update import fused_update_step
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no compiler logs in /tmp
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def program_defaults():
+    """Compile as a user's process would: persistent cache off (see above),
+    and the matmul precision JAX ships with — conftest.py raises it to
+    "highest" for the numeric-gradient suites, and Mosaic refuses an fp32
+    contraction on the kernels' bf16 operands."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev_cache = jax.config.jax_enable_compilation_cache
+    prev_precision = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_default_matmul_precision", None)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev_cache)
+    jax.config.update("jax_default_matmul_precision", prev_precision)
+    cc.reset_cache()
+
+
+def _compile(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+    return text
+
+
+def _flash_fwd_bwd(variant, block_q, block_k):
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, block_q=block_q,
+                              block_k=block_k, use_pallas=True,
+                              variant=variant)
+        return jnp.sum(out.astype(jnp.float32))
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))
+
+
+@pytest.mark.parametrize("variant,block_q,block_k",
+                         [("stream", 1024, 512), ("grid", 512, 512)])
+def test_flash_fwd_bwd_compiles_at_bench_shape(one_chip, variant, block_q,
+                                               block_k):
+    """[4,8,4096,128] bf16 causal, the production block sizes: the forward
+    kernel and the two backward kernels (dq, dk/dv) all reach Mosaic."""
+    q = jax.ShapeDtypeStruct((4, 8, 4096, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    text = _compile(_flash_fwd_bwd(variant, block_q, block_k), q, q, q)
+    assert text.count("tpu_custom_call") >= 3
+
+
+def _offset_fwd_bwd(block_q, block_k, variant="stream"):
+    def loss(q, k, v, offs):
+        out, lse = flash_attention_with_lse(
+            q, k, v, offs, 1.0 / np.sqrt(q.shape[-1]), True, block_q,
+            block_k, False, variant)
+        return jnp.sum(out.astype(jnp.float32)) + jnp.sum(lse)
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))
+
+
+@pytest.mark.parametrize("name,q_shape,k_shape,block_q,block_k", [
+    # DecodeEngine chunked prefill at GPT-2-small widths: a 256-token chunk
+    # against the sequence's 1024 gathered cache positions, head dim 64
+    ("decode_prefill", (1, 12, 256, 64), (1, 12, 1024, 64), 256, 512),
+    # one ring-attention step: a 2048-token local block, head dim 128
+    ("ring_step", (1, 8, 2048, 128), (1, 8, 2048, 128), 512, 512),
+])
+def test_offset_kernel_compiles(one_chip, name, q_shape, k_shape, block_q,
+                                block_k):
+    q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16, sharding=one_chip)
+    k = jax.ShapeDtypeStruct(k_shape, jnp.bfloat16, sharding=one_chip)
+    offs = jax.ShapeDtypeStruct((2,), jnp.int32, sharding=one_chip)
+    _compile(_offset_fwd_bwd(block_q, block_k), q, k, k, offs)
+
+
+@pytest.mark.parametrize("optimizer", ["sgd_momentum", "adam"])
+def test_fused_update_kernel_compiles(one_chip, optimizer):
+    """(1000, 2048) — ResNet-50's classifier weight: rows do not divide the
+    kernel's 512-row block, so the ragged last grid step is compiled too."""
+    leaf = jax.ShapeDtypeStruct((1000, 2048), jnp.float32, sharding=one_chip)
+    tree = {"w": leaf}
+    if optimizer == "adam":
+        hp = {"lr": 1e-3, "beta1": 0.9, "beta2": 0.999, "eps": 1e-8}
+        state = {"m": tree, "v": tree,
+                 "t": jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)}
+        opt = "adam"
+    else:
+        hp = {"lr": 0.05, "momentum": 0.9}
+        state = {"mom": tree}
+        opt = "sgd"
+    _compile(lambda p, s, g: fused_update_step(
+        opt, hp, p, s, g, rescale=1.0 / 32, wd=1e-4, use_pallas=True),
+        tree, state, tree)
+
+
+def _flash_fwd(variant):
+    return lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=512, block_k=512, use_pallas=True,
+        variant=variant)
+
+
+def test_stream_compiles_at_8k(one_chip):
+    q = jax.ShapeDtypeStruct((1, 8, 8192, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    _compile(_flash_fwd("stream"), q, q, q)
+
+
+def test_stream_is_refused_for_vmem_at_16k(one_chip):
+    """`stream` keeps the whole K/V sequence resident in VMEM: at 16384
+    positions that is past the kernel's 16 MiB scoped limit and the
+    compiler says so. `stream` is still the default variant and nothing
+    picks `grid` from the length (ROADMAP C5) — this pins the limit until
+    something does."""
+    q = jax.ShapeDtypeStruct((1, 8, 16384, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    with pytest.raises(Exception, match="(?i)vmem"):
+        jax.jit(_flash_fwd("stream")).lower(q, q, q).compile()
+
+
+def test_grid_compiles_at_16k(one_chip):
+    q = jax.ShapeDtypeStruct((1, 8, 16384, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    _compile(_flash_fwd("grid"), q, q, q)
