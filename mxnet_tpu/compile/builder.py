@@ -317,35 +317,38 @@ class ProgramBuilder:
                 logging.getLogger("mxnet_tpu.analysis").warning(
                     "tpulint: compile-time hook for %s crashed: %s",
                     self.site, e)
-        with self._lock:
-            lowered = self._lowered.get(key)
-            traced = self._traced.get(key)
-        if lowered is None:
-            # lower WITHOUT retaining: the executable is what this path
-            # is for, and nothing re-reads an un-requested Lowered (see
-            # lowered() for the analysis-consumer retention rule). A
-            # trace an analysis consumer already paid for IS reused —
-            # lint + audit + compile share one trace per program.
-            lowered = traced.lower() if traced is not None \
-                else self._jit.lower(*args)
+        with _prof.span("mx.compile", site=self.site,
+                        aot=(mode == "aot")) as sp:
             with self._lock:
-                self.lowerings += 1
-        # persistent-hit attribution diffs the THREAD-local event count:
-        # jax fires the cache-hit event synchronously on the compiling
-        # thread, so a concurrent compile on another thread (the whole
-        # point of compile-outside-lock) can never cross-contaminate it
-        phits0 = _prof.thread_persistent_cache_hits()
-        t0 = time.perf_counter()
-        try:
-            from ..resilience import faults as _faults
-            _faults.fault_point("compile.cache_read", builder=self.site)
-            prog = lowered.compile()
-        except Exception as e:
-            prog = self._compile_after_cache_corruption(lowered, e)
-        ms = (time.perf_counter() - t0) * 1e3
-        _prof.record_compile(
-            self.site, ms, aot=(mode == "aot"),
-            persistent_hit=_prof.thread_persistent_cache_hits() > phits0)
+                lowered = self._lowered.get(key)
+                traced = self._traced.get(key)
+            if lowered is None:
+                # lower WITHOUT retaining: the executable is what this path
+                # is for, and nothing re-reads an un-requested Lowered (see
+                # lowered() for the analysis-consumer retention rule). A
+                # trace an analysis consumer already paid for IS reused —
+                # lint + audit + compile share one trace per program.
+                lowered = traced.lower() if traced is not None \
+                    else self._jit.lower(*args)
+                with self._lock:
+                    self.lowerings += 1
+            # persistent-hit attribution diffs the THREAD-local event count:
+            # jax fires the cache-hit event synchronously on the compiling
+            # thread, so a concurrent compile on another thread (the whole
+            # point of compile-outside-lock) can never cross-contaminate it
+            phits0 = _prof.thread_persistent_cache_hits()
+            t0 = time.perf_counter()
+            try:
+                from ..resilience import faults as _faults
+                _faults.fault_point("compile.cache_read", builder=self.site)
+                prog = lowered.compile()
+            except Exception as e:
+                prog = self._compile_after_cache_corruption(lowered, e)
+            ms = (time.perf_counter() - t0) * 1e3
+            hit = _prof.thread_persistent_cache_hits() > phits0
+            sp.set_metadata(persistent_hit=hit)
+        _prof.record_compile(self.site, ms, aot=(mode == "aot"),
+                             persistent_hit=hit)
         return prog
 
     def _compile_after_cache_corruption(self, lowered, err):

@@ -65,6 +65,12 @@ def default_stage_fn(device=None, sharding=None):
     return stage
 
 
+def _batch_nbytes(batch):
+    """Bytes the stager moves for one batch (shapes only, no copy)."""
+    return sum(int(getattr(getattr(a, "_data", a), "nbytes", 0))
+               for a in list(batch.data or []) + list(batch.label or []))
+
+
 class DevicePrefetchIter(DataIter):
     """Background-thread iterator wrapper staging the NEXT batch onto
     device while the current step runs.
@@ -149,19 +155,23 @@ class DevicePrefetchIter(DataIter):
                 hb.beat()
                 if self._pending is None:
                     try:
-                        self._pending = self.base.next()
+                        with _prof.span("mx.prefetch.fetch"):
+                            self._pending = self.base.next()
                     except StopIteration:
                         self._put(self._STOP)
                         hb.close()
                         return
                 t0 = time.perf_counter()
-                staged = stage_retry.call(_stage_once, self._pending)
+                with _prof.span("mx.prefetch.stage",
+                                bytes=_batch_nbytes(self._pending)):
+                    staged = stage_retry.call(_stage_once, self._pending)
                 _prof.record_pipeline_event(
                     prefetch_stage_ms=(time.perf_counter() - t0) * 1e3)
                 self.counters["staged"] += 1
                 hb.idle()  # a put() blocked on a full queue is downstream
                 #            backpressure, not a prefetch stall
-                self._put(staged)
+                with _prof.span("mx.prefetch.put"):
+                    self._put(staged)
                 self._pending = None  # delivered (or shutdown drained it)
             hb.close()  # clean stop
         except BaseException as e:  # transported to next(), then sticky

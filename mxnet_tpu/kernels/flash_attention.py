@@ -356,6 +356,7 @@ def _flash_fwd_offs_pallas(q, k, v, offs, sm_scale, causal, block_q, block_k,
     ]
     out, lse = pl.pallas_call(
         kernel,
+        name="mx_flash_fwd_offs",
         grid_spec=grid_spec,
         out_shape=out_shapes,
         compiler_params=None if interpret else _grid_parallel(),
@@ -509,6 +510,7 @@ def _flash_bwd_offs_pallas(q, k, v, offs, do, dlse, out, lse, sm_scale,
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_offs_kernel, sm_scale=sm_scale,
                           causal=causal, block_k=block_k, kv_len=sk),
+        name="mx_flash_bwd_dq_offs",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b * h, sq // block_q),
@@ -531,6 +533,7 @@ def _flash_bwd_offs_pallas(q, k, v, offs, do, dlse, out, lse, sm_scale,
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_offs_kernel, sm_scale=sm_scale,
                           causal=causal, block_q=block_q, q_len=sq),
+        name="mx_flash_bwd_dkv_offs",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b * h, sk // block_k),
@@ -652,6 +655,7 @@ def _flash_fwd_offs_grid_pallas(q, k, v, offs, sm_scale, causal, block_q,
     out, lse = pl.pallas_call(
         functools.partial(_flash_fwd_offs_grid_kernel, sm_scale=sm_scale,
                           causal=causal, block_q=block_q, block_k=block_k),
+        name="mx_flash_fwd_offs_grid",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b * h, n_qb, n_kb),
@@ -831,6 +835,7 @@ def _flash_bwd_offs_grid_pallas(q, k, v, offs, do, dlse, out, lse,
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_grid_kernel, sm_scale=sm_scale,
                           causal=causal, block_q=block_q, block_k=block_k),
+        name="mx_flash_bwd_dq_offs_grid",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b * h, n_qb, n_kb),
@@ -854,6 +859,7 @@ def _flash_bwd_offs_grid_pallas(q, k, v, offs, do, dlse, out, lse,
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_grid_kernel, sm_scale=sm_scale,
                           causal=causal, block_q=block_q, block_k=block_k),
+        name="mx_flash_bwd_dkv_offs_grid",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b * h, n_kb, n_qb),
@@ -940,6 +946,7 @@ def _flash_fwd_pallas(q, k, v, sm_scale, causal, block_q, block_k,
                                causal=causal, block_k=block_k, kv_len=sk)
     out, lse = pl.pallas_call(
         kernel,
+        name="mx_flash_fwd",
         grid=(b * h, sq // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
@@ -1071,6 +1078,7 @@ def _flash_fwd_grid_pallas(q, k, v, sm_scale, causal, block_q, block_k,
             return (i, kb, 0)
     out, lse = pl.pallas_call(
         kernel,
+        name="mx_flash_fwd_grid",
         grid=(b * h, sq // block_q, sk // block_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j, kb: (i, j, 0)),

@@ -158,6 +158,7 @@ def _run_leaf_kernel(kernel, scal, arrays, n_out, interpret):
         aliases[k + 2] = k              # state k (after scal, p, g) -> out k
     out = pl.pallas_call(
         kernel,
+        name="mx_opt_update",
         grid=grid,
         in_specs=[scal_spec] + [tens_spec] * len(flat),
         out_specs=[tens_spec] * n_out,

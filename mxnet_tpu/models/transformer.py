@@ -319,6 +319,7 @@ def _decode_attn_prefill(q, ks, vs, start, cfg, use_pallas, interpret):
     return out[0].transpose(1, 0, 2)                    # (C, H, Dh)
 
 
+@jax.named_scope("decode.prefill")      # the trace's device-side name
 def transformer_decode_prefill(params, cfg, k_pages, v_pages, tokens,
                                start, length, table, *, use_pallas=False,
                                interpret=False):
@@ -347,26 +348,29 @@ def transformer_decode_prefill(params, cfg, k_pages, v_pages, tokens,
     slot = jnp.clip(pos, 0, T - 1) % bs
     lp_all = params["layers"]
     for l in range(L):
-        lp = {k: v[l] for k, v in lp_all.items()}
-        h = _layer_norm(x, lp["ln1_scale"], lp["ln1_bias"])
-        q = (h @ lp["wq"]).reshape(C, H, Dh)
-        kk = h @ lp["wk"]                               # (C, D)
-        vv = h @ lp["wv"]
-        k_pages = k_pages.at[blk, slot, l].set(kk)
-        v_pages = v_pages.at[blk, slot, l].set(vv)
-        ks = k_pages[table][:, :, l].reshape(T, H, Dh)
-        vs = v_pages[table][:, :, l].reshape(T, H, Dh)
-        a = _decode_attn_prefill(q, ks, vs, start, cfg, use_pallas,
-                                 interpret)
-        x = x + a.reshape(C, cfg.d_model) @ lp["wo"]
-        h = _layer_norm(x, lp["ln2_scale"], lp["ln2_bias"])
-        x = x + (jax.nn.gelu(h @ lp["w1"] + lp["b1"]) @ lp["w2"] + lp["b2"])
+        with jax.named_scope("layer"):
+            lp = {k: v[l] for k, v in lp_all.items()}
+            h = _layer_norm(x, lp["ln1_scale"], lp["ln1_bias"])
+            q = (h @ lp["wq"]).reshape(C, H, Dh)
+            kk = h @ lp["wk"]                               # (C, D)
+            vv = h @ lp["wv"]
+            k_pages = k_pages.at[blk, slot, l].set(kk)
+            v_pages = v_pages.at[blk, slot, l].set(vv)
+            ks = k_pages[table][:, :, l].reshape(T, H, Dh)
+            vs = v_pages[table][:, :, l].reshape(T, H, Dh)
+            a = _decode_attn_prefill(q, ks, vs, start, cfg, use_pallas,
+                                     interpret)
+            x = x + a.reshape(C, cfg.d_model) @ lp["wo"]
+            h = _layer_norm(x, lp["ln2_scale"], lp["ln2_bias"])
+            x = x + (jax.nn.gelu(h @ lp["w1"] + lp["b1"]) @ lp["w2"]
+                     + lp["b2"])
     x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
     x_last = jnp.take(x, jnp.clip(length - 1, 0, C - 1), axis=0)
     logits = x_last @ params["embed"].T.astype(cfg.dtype)
     return jnp.argmax(logits).astype(jnp.int32), k_pages, v_pages
 
 
+@jax.named_scope("decode.step")      # the trace's device-side name
 def transformer_decode_step(params, cfg, k_pages, v_pages, token_ids,
                             positions, tables, active):
     """Fixed-shape batched decode step: one token per active row.
@@ -395,22 +399,24 @@ def transformer_decode_step(params, cfg, k_pages, v_pages, token_ids,
     tpos = jnp.arange(T, dtype=jnp.int32)[None, None, :]
     lp_all = params["layers"]
     for l in range(L):
-        lp = {k: v[l] for k, v in lp_all.items()}
-        h = _layer_norm(x, lp["ln1_scale"], lp["ln1_bias"])
-        q = (h @ lp["wq"]).reshape(B, H, Dh)
-        kk = h @ lp["wk"]
-        vv = h @ lp["wv"]
-        k_pages = k_pages.at[blk, slot, l].set(kk)
-        v_pages = v_pages.at[blk, slot, l].set(vv)
-        ks = k_pages[tables][:, :, :, l].reshape(B, T, H, Dh)
-        vs = v_pages[tables][:, :, :, l].reshape(B, T, H, Dh)
-        scores = jnp.einsum("bhd,bthd->bht", q, ks) * sm
-        scores = jnp.where(tpos <= positions[:, None, None], scores, _NEG)
-        w = jax.nn.softmax(scores, axis=-1)
-        ctx = jnp.einsum("bht,bthd->bhd", w, vs).reshape(B, cfg.d_model)
-        x = x + ctx @ lp["wo"]
-        h = _layer_norm(x, lp["ln2_scale"], lp["ln2_bias"])
-        x = x + (jax.nn.gelu(h @ lp["w1"] + lp["b1"]) @ lp["w2"] + lp["b2"])
+        with jax.named_scope("layer"):
+            lp = {k: v[l] for k, v in lp_all.items()}
+            h = _layer_norm(x, lp["ln1_scale"], lp["ln1_bias"])
+            q = (h @ lp["wq"]).reshape(B, H, Dh)
+            kk = h @ lp["wk"]
+            vv = h @ lp["wv"]
+            k_pages = k_pages.at[blk, slot, l].set(kk)
+            v_pages = v_pages.at[blk, slot, l].set(vv)
+            ks = k_pages[tables][:, :, :, l].reshape(B, T, H, Dh)
+            vs = v_pages[tables][:, :, :, l].reshape(B, T, H, Dh)
+            scores = jnp.einsum("bhd,bthd->bht", q, ks) * sm
+            scores = jnp.where(tpos <= positions[:, None, None], scores, _NEG)
+            w = jax.nn.softmax(scores, axis=-1)
+            ctx = jnp.einsum("bht,bthd->bhd", w, vs).reshape(B, cfg.d_model)
+            x = x + ctx @ lp["wo"]
+            h = _layer_norm(x, lp["ln2_scale"], lp["ln2_bias"])
+            x = x + (jax.nn.gelu(h @ lp["w1"] + lp["b1"]) @ lp["w2"]
+                     + lp["b2"])
     x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
     logits = x @ params["embed"].T.astype(cfg.dtype)
     return jnp.argmax(logits, axis=-1).astype(jnp.int32), k_pages, v_pages

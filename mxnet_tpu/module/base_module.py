@@ -8,6 +8,7 @@ import numpy as _np
 
 from ..base import MXNetError
 from .. import metric as metric_mod
+from .. import profiler as _prof
 from ..model import BatchEndParam
 from ..initializer import Uniform
 from ..ndarray.ndarray import NDArray
@@ -307,7 +308,19 @@ class BaseModule:
             tic = time.time()
             eval_metric.reset()
             source = iter(train_data)
-            batch = next(source)
+            staged = getattr(train_data, "counters", None)
+
+            def next_batch():
+                # the step loop's wait for a batch; `hit` where the iterator
+                # counts stalls (io_device.DevicePrefetchIter)
+                with _prof.span("mx.fit.next_batch") as sp:
+                    stalls = staged["stalls"] if staged else 0
+                    out = next(source)
+                    if staged:
+                        sp.set_metadata(hit=staged["stalls"] == stalls)
+                return out
+
+            batch = next_batch()
             nbatch, last, epoch_values = 0, False, []
             while not last:
                 if monitor is not None:
@@ -318,11 +331,12 @@ class BaseModule:
                 # work is still in flight (the reference's double-buffer;
                 # here it overlaps host IO with the async dispatch)
                 try:
-                    upcoming = next(source)
+                    upcoming = next_batch()
                     self.prepare(upcoming, sparse_row_id_fn=sparse_row_id_fn)
                 except StopIteration:
                     upcoming, last = None, True
-                self.update_metric(eval_metric, batch.label)
+                with _prof.span("mx.fit.metric"):
+                    self.update_metric(eval_metric, batch.label)
                 if monitor is not None:
                     monitor.toc_print()
                 if last:
@@ -334,8 +348,9 @@ class BaseModule:
                     cb_params = BatchEndParam(epoch=epoch, nbatch=nbatch,
                                               eval_metric=eval_metric,
                                               locals=locals())
-                    for callback in _as_list(batch_end_callback):
-                        callback(cb_params)
+                    with _prof.span("mx.fit.callback"):
+                        for callback in _as_list(batch_end_callback):
+                            callback(cb_params)
                 nbatch += 1
                 batch = upcoming
 

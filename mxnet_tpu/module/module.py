@@ -500,15 +500,6 @@ class Module(BaseModule):
             # tpulint: allow-host-sync host-numpy fallback; device arrays take the _data branch
             return _np2.asarray(arr)
 
-        batch = {}
-        for desc, arr in zip(self._data_shapes, data_batch.data):
-            batch[desc.name] = _raw(arr)
-        for desc, arr in zip(self._label_shapes or [], data_batch.label or []):
-            batch[desc.name] = _raw(arr)
-        batch = {k: v for k, v in batch.items() if k in fused.arg_names}
-        # device-prefetched batches (io_device.DevicePrefetchIter) arrive
-        # already on the fused step's batch sharding and pass through
-        # zero-copy; anything else is staged by the step itself
         from .. import profiler as _prof
         from ..resilience import faults as _faults
         import time as _time
@@ -518,24 +509,34 @@ class Module(BaseModule):
         _faults.fault_point("train.step", step=self._fused_step_count)
         self._fused_step_count += 1
         sup = self._supervisor
-        _t0 = _time.perf_counter()
-        if sup is not None and fused.supervise:
-            # supervised step: the loss scale rides as a runtime arg and
-            # the in-graph all-finite verdict rides the output tuple
-            outs = fused(batch, lr=self._fused_lr(), scale=sup.step_scale())
-            flag = fused.last_flag
-        else:
-            outs = fused(batch, lr=self._fused_lr())
-            flag = None
-        # dispatch_ms is host enqueue time only — captured BEFORE any
-        # profiler block_until_ready, or it would absorb the whole step
-        _prof.record_pipeline_event(
-            steps=1, dispatch_ms=(_time.perf_counter() - _t0) * 1e3)
+        with _prof.span("mx.fit.step.dispatch",
+                        step=self._fused_step_count - 1):
+            batch = {}
+            for desc, arr in zip(self._data_shapes, data_batch.data):
+                batch[desc.name] = _raw(arr)
+            for desc, arr in zip(self._label_shapes or [],
+                                 data_batch.label or []):
+                batch[desc.name] = _raw(arr)
+            batch = {k: v for k, v in batch.items() if k in fused.arg_names}
+            # device-prefetched batches (io_device.DevicePrefetchIter)
+            # arrive already on the fused step's batch sharding and pass
+            # through zero-copy; anything else is staged by the step itself
+            _t0 = _time.perf_counter()
+            if sup is not None and fused.supervise:
+                # supervised step: the loss scale rides as a runtime arg and
+                # the in-graph all-finite verdict rides the output tuple
+                outs = fused(batch, lr=self._fused_lr(),
+                             scale=sup.step_scale())
+                flag = fused.last_flag
+            else:
+                outs = fused(batch, lr=self._fused_lr())
+                flag = None
+        # host enqueue time only: nothing here waits for the device, with
+        # the profiler on or off, so a profiled fit overlaps as any other
+        _dispatch_s = _time.perf_counter() - _t0
+        _prof.record_pipeline_event(steps=1, dispatch_ms=_dispatch_s * 1e3)
         if _prof.is_running():
-            import jax as _jax
-            _jax.block_until_ready(outs)
-            _prof.record_op_event("tpu_sync_fused_step",
-                                  _time.perf_counter() - _t0,
+            _prof.record_op_event("tpu_sync_fused_step", _dispatch_s,
                                   category="xla_graph_exec")
         from ..ndarray.ndarray import _new_from_jax
         self._fused_outputs = [_new_from_jax(o) for o in outs]
@@ -557,13 +558,14 @@ class Module(BaseModule):
         oldest, flag = self._inflight.popleft()
         _t1 = _time.perf_counter()
         sup = self._supervisor
-        if sup is not None and flag is not None:
-            # bounded readback (stall deadline) + verdict observation:
-            # NaN skip accounting, loss-scale backoff, NumericDivergence
-            sup.await_ready(oldest, flag)
-        else:
-            import jax as _jax
-            _jax.block_until_ready(oldest)
+        with _prof.span("mx.fit.step.retire"):
+            if sup is not None and flag is not None:
+                # bounded readback (stall deadline) + verdict observation:
+                # NaN skip accounting, loss-scale backoff, NumericDivergence
+                sup.await_ready(oldest, flag)
+            else:
+                import jax as _jax
+                _jax.block_until_ready(oldest)
         _prof.record_pipeline_event(
             readback_stall_ms=(_time.perf_counter() - _t1) * 1e3)
 

@@ -165,7 +165,9 @@ class ShardedTrainStep:
         kt_pallas, kt_interpret = resolve_kernel_tier()  # build-time knob
 
         def step(params, opt_state, batch):
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+            # device-side names for the trace (metadata only)
+            with jax.named_scope("fwd_bwd"):
+                loss, grads = jax.value_and_grad(loss_fn)(params, batch)
             if skip_nonfinite:
                 gsq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
                           for g in jax.tree_util.tree_leaves(grads))
@@ -186,30 +188,32 @@ class ShardedTrainStep:
                 # of every slot-carrying tensor per replica; the param
                 # out_shardings all-gather the fresh weights. Composes
                 # with tp: the grad keeps its tensor-parallel axes.
-                grads = jax.tree_util.tree_map(
-                    lambda g, s: jax.lax.with_sharding_constraint(
-                        g, NamedSharding(mesh, s)),
-                    grads, state_specs)
-            if fused_opt and not shard_update:
-                # fused kernel tier as a dp shard_map island: transient
-                # (dp, chunk) blocks, kernel per eligible chunk, fresh
-                # params/slots all-gathered — bitwise equal to
-                # apply_update by the shared-prologue construction
-                from .mesh_kernels import fused_update_mesh
-                new_params, new_state = fused_update_mesh(
-                    opt, hp, params, opt_state, grads, mesh, dp_axis,
-                    use_pallas=kt_pallas, interpret=kt_interpret)
-            elif fused_opt:
-                # annotation-sharded state (ZeRO layout) keeps its specs;
-                # one fused-lax sweep per leaf — the partitioner splits
-                # the elementwise update along the state layout
-                from ..kernels.opt_update import fused_update_step
-                new_params, new_state = fused_update_step(
-                    opt, hp, params, opt_state, grads, use_pallas=False)
-            else:
-                from .optim_update import apply_update
-                new_params, new_state = apply_update(opt, hp, params,
-                                                     opt_state, grads)
+                with jax.named_scope("grad_sync"):
+                    grads = jax.tree_util.tree_map(
+                        lambda g, s: jax.lax.with_sharding_constraint(
+                            g, NamedSharding(mesh, s)),
+                        grads, state_specs)
+            with jax.named_scope("update"):
+                if fused_opt and not shard_update:
+                    # fused kernel tier as a dp shard_map island: transient
+                    # (dp, chunk) blocks, kernel per eligible chunk, fresh
+                    # params/slots all-gathered — bitwise equal to
+                    # apply_update by the shared-prologue construction
+                    from .mesh_kernels import fused_update_mesh
+                    new_params, new_state = fused_update_mesh(
+                        opt, hp, params, opt_state, grads, mesh, dp_axis,
+                        use_pallas=kt_pallas, interpret=kt_interpret)
+                elif fused_opt:
+                    # annotation-sharded state (ZeRO layout) keeps its specs;
+                    # one fused-lax sweep per leaf — the partitioner splits
+                    # the elementwise update along the state layout
+                    from ..kernels.opt_update import fused_update_step
+                    new_params, new_state = fused_update_step(
+                        opt, hp, params, opt_state, grads, use_pallas=False)
+                else:
+                    from .optim_update import apply_update
+                    new_params, new_state = apply_update(opt, hp, params,
+                                                         opt_state, grads)
             if skip_nonfinite:
                 # carry the pre-step state through a bad update (the
                 # donation-safe skip idiom shared with tpu_step)

@@ -320,23 +320,28 @@ class DataParallelTrainStep:
                     aux_upd = {n: v.astype(jnp.float32)
                                for n, v in aux_upd.items()}
                 return outs, aux_upd
-            outs, vjp, aux_upd = jax.vjp(loss_fn, params, has_aux=True)
-            if supervise:
-                # loss-scaled backward: the cotangent seed IS the runtime
-                # scale (a power of two, so the cast and the unscale
-                # multiply below are exact in bf16/fp32 — scale 1.0 makes
-                # the math bitwise identical to the unscaled seed). Loss
-                # heads pick the seed up multiplicatively (ops/nn._loss_op);
-                # implicit mid-chain loss sites read the scope instead.
-                from ..ops.nn import loss_grad_scale_scope
-                s32 = jnp.asarray(scale, jnp.float32)
-                seeds = tuple(jnp.full(o.shape, s32.astype(o.dtype))
-                              for o in outs)
-                with loss_grad_scale_scope(s32):
+            # device-side names for the trace (metadata only): forward +
+            # backward here — on a mesh the partitioner's gradient
+            # all-reduce falls inside it — and `update` below
+            with jax.named_scope("fwd_bwd"):
+                outs, vjp, aux_upd = jax.vjp(loss_fn, params, has_aux=True)
+                if supervise:
+                    # loss-scaled backward: the cotangent seed IS the
+                    # runtime scale (a power of two, so the cast and the
+                    # unscale multiply below are exact in bf16/fp32 — scale
+                    # 1.0 makes the math bitwise identical to the unscaled
+                    # seed). Loss heads pick the seed up multiplicatively
+                    # (ops/nn._loss_op); implicit mid-chain loss sites read
+                    # the scope instead.
+                    from ..ops.nn import loss_grad_scale_scope
+                    s32 = jnp.asarray(scale, jnp.float32)
+                    seeds = tuple(jnp.full(o.shape, s32.astype(o.dtype))
+                                  for o in outs)
+                    with loss_grad_scale_scope(s32):
+                        grads = vjp(seeds)[0]
+                else:
+                    seeds = tuple(jnp.ones(o.shape, o.dtype) for o in outs)
                     grads = vjp(seeds)[0]
-            else:
-                seeds = tuple(jnp.ones(o.shape, o.dtype) for o in outs)
-                grads = vjp(seeds)[0]
             if supervise:
                 inv = jnp.float32(1.0) / s32
                 grads = {n: g * inv.astype(g.dtype)
@@ -361,49 +366,50 @@ class DataParallelTrainStep:
                 grads = {n: g.astype(jnp.float32)
                          for n, g in grads.items()}
             hp = dict(opt_hp, lr=lr)
-            if zero_layout is not None:
-                # ZeRO cross-replica sharded update (arxiv 2004.13336):
-                # a shard_map island where each replica slices its 1/dp
-                # (dp, chunk) block of the all-reduced grads and updates
-                # its shard of params + slots (fp32 masters included:
-                # the mp_sgd-style bf16->fp32 grad cast runs on the
-                # shards, inside the island's update loop), then the
-                # fresh params all-gather. Bit-parity with both paths
-                # below.
-                from .optim_update import apply_update_sharded
-                new_params, new_state = apply_update_sharded(
-                    optimizer, hp, params, opt_state, grads, zero_layout,
-                    mesh, rescale=rescale, clip=clip, wd=wd,
-                    fused=fused_opt,
-                    cast_grads=jnp.float32 if cdt is not None else None,
-                    use_pallas=kt_pallas, interpret=kt_interpret)
-            elif fused_opt:
-                # one fused sweep per param block (prologue + update in
-                # the kernel) — bit-parity with the tree-map path below.
-                # pallas_call is not auto-partitionable, so multi-device
-                # meshes route through the fused_update_mesh shard_map
-                # island (transient dp-sharded chunks, params/slots
-                # all-gathered back): inside the manual region the kernel
-                # is a plain per-device op, so the kernel tier engages on
-                # every mesh instead of silently lax-falling-back.
-                if single_dev:
-                    from ..kernels.opt_update import fused_update_step
-                    new_params, new_state = fused_update_step(
-                        optimizer, hp, params, opt_state, grads,
-                        rescale=rescale, clip=clip, wd=wd,
+            with jax.named_scope("update"):
+                if zero_layout is not None:
+                    # ZeRO cross-replica sharded update (arxiv 2004.13336):
+                    # a shard_map island where each replica slices its 1/dp
+                    # (dp, chunk) block of the all-reduced grads and updates
+                    # its shard of params + slots (fp32 masters included:
+                    # the mp_sgd-style bf16->fp32 grad cast runs on the
+                    # shards, inside the island's update loop), then the
+                    # fresh params all-gather. Bit-parity with both paths
+                    # below.
+                    from .optim_update import apply_update_sharded
+                    new_params, new_state = apply_update_sharded(
+                        optimizer, hp, params, opt_state, grads, zero_layout,
+                        mesh, rescale=rescale, clip=clip, wd=wd,
+                        fused=fused_opt,
+                        cast_grads=jnp.float32 if cdt is not None else None,
                         use_pallas=kt_pallas, interpret=kt_interpret)
+                elif fused_opt:
+                    # one fused sweep per param block (prologue + update in
+                    # the kernel) — bit-parity with the tree-map path below.
+                    # pallas_call is not auto-partitionable, so multi-device
+                    # meshes route through the fused_update_mesh shard_map
+                    # island (transient dp-sharded chunks, params/slots
+                    # all-gathered back): inside the manual region the kernel
+                    # is a plain per-device op, so the kernel tier engages on
+                    # every mesh instead of silently lax-falling-back.
+                    if single_dev:
+                        from ..kernels.opt_update import fused_update_step
+                        new_params, new_state = fused_update_step(
+                            optimizer, hp, params, opt_state, grads,
+                            rescale=rescale, clip=clip, wd=wd,
+                            use_pallas=kt_pallas, interpret=kt_interpret)
+                    else:
+                        from .mesh_kernels import fused_update_mesh
+                        new_params, new_state = fused_update_mesh(
+                            optimizer, hp, params, opt_state, grads, mesh,
+                            dp_axis, rescale=rescale, clip=clip, wd=wd,
+                            use_pallas=kt_pallas, interpret=kt_interpret)
                 else:
-                    from .mesh_kernels import fused_update_mesh
-                    new_params, new_state = fused_update_mesh(
-                        optimizer, hp, params, opt_state, grads, mesh,
-                        dp_axis, rescale=rescale, clip=clip, wd=wd,
-                        use_pallas=kt_pallas, interpret=kt_interpret)
-            else:
-                from .optim_update import apply_update, grad_prologue
-                grads = grad_prologue(params, grads, rescale=rescale,
-                                      clip=clip, wd=wd)
-                new_params, new_state = apply_update(
-                    optimizer, hp, params, opt_state, grads)
+                    from .optim_update import apply_update, grad_prologue
+                    grads = grad_prologue(params, grads, rescale=rescale,
+                                          clip=clip, wd=wd)
+                    new_params, new_state = apply_update(
+                        optimizer, hp, params, opt_state, grads)
             if fixed:
                 new_params = {n: (params[n] if n in fixed else v)
                               for n, v in new_params.items()}

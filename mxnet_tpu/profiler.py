@@ -14,6 +14,7 @@ import threading
 import jax
 
 __all__ = ["set_config", "set_state", "dump", "dumps", "pause", "resume",
+           "span",
            "record_pipeline_event", "pipeline_counters",
            "record_analysis_check", "record_analysis_finding",
            "analysis_counters", "record_kernel_roofline", "kernel_counters",
@@ -47,7 +48,13 @@ def set_state(state="stop", profile_process="worker"):
         if not _state["running"]:
             trace_dir = os.path.splitext(_state["filename"])[0] + "_jax_trace"
             try:
-                jax.profiler.start_trace(trace_dir)
+                # host spans (`span`) and the device planes, without the
+                # Python tracer: recording every Python call doubled the
+                # idle share of the decode loop it was asked to measure
+                # (PERF.md section 5, PR 26)
+                opts = jax.profiler.ProfileOptions()
+                opts.python_tracer_level = 0
+                jax.profiler.start_trace(trace_dir, profiler_options=opts)
                 _state["jax_trace_dir"] = trace_dir
             except Exception:
                 _state["jax_trace_dir"] = None
@@ -71,24 +78,17 @@ def resume(profile_process="worker"):
     set_state("run")
 
 
-class record_event:
-    """Chrome-tracing event recorder for host-side phases."""
-
-    def __init__(self, name, category="host"):
-        self.name = name
-        self.category = category
-
-    def __enter__(self):
-        self.t0 = time.time()
-        return self
-
-    def __exit__(self, *exc):
-        with _state["lock"]:
-            _state["events"].append({
-                "name": self.name, "cat": self.category, "ph": "X",
-                "ts": self.t0 * 1e6, "dur": (time.time() - self.t0) * 1e6,
-                "pid": 0, "tid": threading.get_ident() % 1000,
-            })
+def span(name, **attrs):
+    """A host span on the JAX profiler's own clock: a context manager
+    (``jax.profiler.TraceAnnotation``) that lands in the same
+    ``.xplane.pb`` as the device planes, whoever started the trace —
+    ``set_state("run")`` here or any ``jax.profiler.start_trace``. With no
+    trace session listening it records nothing: that is the off state.
+    Attributes go in as keyword arguments (``span("mx.x", n=3)``), or
+    later through the returned object's ``set_metadata(**kw)`` for what
+    is known only at the end; never format them into the name. The
+    ``mx.*`` spans of the program are listed in docs/faq/perf.md."""
+    return jax.profiler.TraceAnnotation(name, **attrs)
 
 
 def is_running():

@@ -508,7 +508,8 @@ class DecodeEngine:
             deadline_ms = self.default_deadline_ms
         deadline = (time.monotonic() + deadline_ms / 1e3
                     if deadline_ms is not None else None)
-        with self._cv:
+        with _prof.span("mx.decode.submit", prompt_len=len(prompt)) as sp, \
+                self._cv:
             if self._stop:
                 raise RuntimeError("decode engine %s is stopped" % self.name)
             self._rid_ctr += 1
@@ -520,6 +521,7 @@ class DecodeEngine:
             self._counters["submitted"] += 1
             self._waiting.append(stream)
             self._cv.notify_all()
+            sp.set_metadata(rid=stream.rid)
         _prof.record_decode_event(submitted=1)
         return stream
 
@@ -574,25 +576,42 @@ class DecodeEngine:
         hb = _watchdog().register("mx-decode-%s" % self.name,
                                   thread=threading.current_thread())
         try:
-            while True:
-                with self._cv:
-                    while (not self._stop and not self._waiting
-                           and not any(s is not None for s in self._slots)):
-                        hb.idle()
-                        self._cv.wait(0.05)
-                    if self._stop:
-                        return
-                    hb.beat()
-                    sheds, rejects, admitted = self._form_batch_locked()
-                for s in sheds:
-                    self._finish(s, s._shed_err)
-                for s in rejects:
-                    self._finish(s, s._shed_err)
-                for s in admitted:
-                    self._prefill_one(s)
-                self._decode_step()
+            n = 0
+            while self._iterate(hb, n):
+                n += 1
         finally:
             hb.close()
+
+    def _idle(self):
+        return not (self._stop or self._waiting
+                    or any(s is not None for s in self._slots))
+
+    def _iterate(self, hb, n):
+        """Pass ``n`` of the loop: idle wait, formation, the admitted
+        sequences' prefills, one step. False once the engine is stopped.
+        Every boundary is a ``profiler.span`` (docs/faq/perf.md): the
+        leaves tile the pass, so a device gap in a trace has a name."""
+        with _prof.span("mx.decode.iteration", n=n):
+            with _prof.span("mx.decode.admit") as sp:
+                with self._cv:
+                    if self._idle():
+                        with _prof.span("mx.decode.wait"):
+                            while self._idle():
+                                hb.idle()
+                                self._cv.wait(0.05)
+                    if self._stop:
+                        return False
+                    hb.beat()
+                    waiting = len(self._waiting)
+                    sheds, rejects, admitted = self._form_batch_locked()
+                for s in sheds + rejects:
+                    self._finish(s, s._shed_err)
+                sp.set_metadata(waiting=waiting, admitted=len(admitted),
+                                shed=len(sheds) + len(rejects))
+            for s in admitted:
+                self._prefill_one(s)
+            self._decode_step()
+        return True
 
     def _form_batch_locked(self):
         """The formation pass (EDF, generalizing the batcher): shed
@@ -659,6 +678,12 @@ class DecodeEngine:
                       for i in range(0, len(prompt), chunk)]
         else:
             pieces = [prompt]
+        with _prof.span("mx.decode.prefill", rid=stream.rid,
+                        prompt_len=len(prompt), pieces=len(pieces)):
+            self._prefill_pieces(stream, pieces)
+
+    def _prefill_pieces(self, stream, pieces):
+        prompt = stream.prompt
         table = _np.zeros((self._mb,), _np.int32)
         own = self._kv.table(stream.rid)
         table[:len(own)] = own
@@ -678,11 +703,14 @@ class DecodeEngine:
             _faults.fault_point("decode.step", model=self.name,
                                 kind="prefill", rid=stream.rid)
             try:
-                next_id, self._k_pages, self._v_pages = self._prefill_b(
-                    self._params, self._k_pages, self._v_pages, toks,
-                    _np.int32(start), _np.int32(len(piece)), table)
+                with _prof.span("mx.decode.prefill.dispatch", bucket=bucket):
+                    next_id, self._k_pages, self._v_pages = self._prefill_b(
+                        self._params, self._k_pages, self._v_pages, toks,
+                        _np.int32(start), _np.int32(len(piece)), table)
                 if last:
-                    tok = int(_np.asarray(next_id))  # tpulint: allow-host-sync sampled token feeds the next step and the reply stream; decode cannot proceed without it
+                    with _prof.span("mx.decode.prefill.readback",
+                                    bucket=bucket):
+                        tok = int(_np.asarray(next_id))  # tpulint: allow-host-sync sampled token feeds the next step and the reply stream; decode cannot proceed without it
             except Exception as e:
                 self._evict(stream, e if isinstance(e, DeadlineExceeded)
                             else RuntimeError(
@@ -715,78 +743,91 @@ class DecodeEngine:
             return True
         return False
 
+    def _live(self):
+        # _cached is None while a sequence's prefill is still in flight
+        # (chunked prefill steps the loop between pieces) — such rows
+        # must be invisible to the step: no deadline eviction (the
+        # prefill loop owns it), no growth, no step slot.
+        return [s for s in self._slots
+                if s is not None and s._cached is not None]
+
     def _decode_step(self):
         """One continuous-batching iteration over the active slots:
         per-token deadline enforcement, cache growth (typed shed on
         overflow), one fixed-shape step program call, distribution."""
-        now = time.monotonic()
-        # _cached is None while a sequence's prefill is still in flight
-        # (chunked prefill steps the loop between pieces) — such rows
-        # must be invisible here: no deadline eviction (the prefill loop
-        # owns it), no growth, no step slot.
-        for seq in [s for s in self._slots
-                    if s is not None and s._cached is not None]:
-            if seq.deadline is not None and now > seq.deadline:
-                self._evict(seq, DeadlineExceeded(
-                    "decode %s: deadline exceeded after %d tokens"
-                    % (seq.rid, len(seq.tokens))))
-        for seq in [s for s in self._slots
-                    if s is not None and s._cached is not None]:
+        with _prof.span("mx.decode.step") as step_sp:
+            with _prof.span("mx.decode.step.grow") as sp:
+                now = time.monotonic()
+                for seq in self._live():
+                    if seq.deadline is not None and now > seq.deadline:
+                        self._evict(seq, DeadlineExceeded(
+                            "decode %s: deadline exceeded after %d tokens"
+                            % (seq.rid, len(seq.tokens))))
+                for seq in self._live():
+                    try:
+                        # room for the token this step writes at _cached
+                        self._kv.extend(seq.rid, 1)
+                    except CacheOverflow as e:
+                        self._evict(seq, e)
+                active = self._live()
+                sp.set_metadata(rows=len(active))
+            step_sp.set_metadata(active=len(active))
+            if not active:
+                return
+            with _prof.span("mx.decode.step.pack"):
+                b, mb = self.batch_size, self._mb
+                token_ids = _np.zeros((b,), _np.int32)
+                positions = _np.zeros((b,), _np.int32)
+                tables = _np.zeros((b, mb), _np.int32)
+                mask = _np.zeros((b,), _np.bool_)
+                for seq in active:
+                    i = seq._slot
+                    token_ids[i] = seq.tokens[-1]
+                    positions[i] = seq._cached
+                    own = self._kv.table(seq.rid)
+                    tables[i, :len(own)] = own
+                    mask[i] = True
+            _faults.fault_point("decode.step", model=self.name, kind="step",
+                                batch=len(active))
+            t0 = time.monotonic()
             try:
-                # room for the token this step writes at position _cached
-                self._kv.extend(seq.rid, 1)
-            except CacheOverflow as e:
-                self._evict(seq, e)
-        active = [s for s in self._slots
-                  if s is not None and s._cached is not None]
-        if not active:
-            return
-        b, mb = self.batch_size, self._mb
-        token_ids = _np.zeros((b,), _np.int32)
-        positions = _np.zeros((b,), _np.int32)
-        tables = _np.zeros((b, mb), _np.int32)
-        mask = _np.zeros((b,), _np.bool_)
-        for seq in active:
-            i = seq._slot
-            token_ids[i] = seq.tokens[-1]
-            positions[i] = seq._cached
-            own = self._kv.table(seq.rid)
-            tables[i, :len(own)] = own
-            mask[i] = True
-        _faults.fault_point("decode.step", model=self.name, kind="step",
-                            batch=len(active))
-        t0 = time.monotonic()
-        try:
-            next_ids, self._k_pages, self._v_pages = self._step_b(
-                self._params, self._k_pages, self._v_pages, token_ids,
-                positions, tables, mask)
-            ids = _np.asarray(next_ids)  # tpulint: allow-host-sync sampled tokens feed the next step and the reply streams; decode cannot proceed without them
-        except Exception as e:
-            # step state is unknown after a failed dispatch: fail the
-            # whole active set (chaos tests drive this via decode.step)
-            err = e if isinstance(e, DeadlineExceeded) else RuntimeError(
-                "decode step failed: %s" % e)
-            for seq in active:
-                self._evict(seq, err)
-            return
-        now = time.monotonic()
-        step_ns = int((now - t0) * 1e9)
-        _prof.record_latency(self._lat_step, step_ns)
-        with self._cv:
-            self._counters["steps"] += 1
-            self._counters["tokens"] += len(active)
-        _prof.record_decode_event(steps=1, tokens=len(active),
-                                  slot_steps=len(active),
-                                  slot_capacity=self.batch_size)
-        for seq in active:
-            tok = int(ids[seq._slot])
-            seq._cached += 1
-            if seq.last_token_t is not None:
-                _prof.record_latency(
-                    self._lat_tok, int((now - seq.last_token_t) * 1e9))
-            seq.last_token_t = now
-            seq._emit(tok)
-            self._maybe_retire(seq, tok)
+                with _prof.span("mx.decode.step.dispatch"):
+                    next_ids, self._k_pages, self._v_pages = self._step_b(
+                        self._params, self._k_pages, self._v_pages,
+                        token_ids, positions, tables, mask)
+                with _prof.span("mx.decode.step.readback"):
+                    ids = _np.asarray(next_ids)  # tpulint: allow-host-sync sampled tokens feed the next step and the reply streams; decode cannot proceed without them
+            except Exception as e:
+                # step state is unknown after a failed dispatch: fail the
+                # whole active set (chaos tests drive this via decode.step)
+                err = e if isinstance(e, DeadlineExceeded) else RuntimeError(
+                    "decode step failed: %s" % e)
+                for seq in active:
+                    self._evict(seq, err)
+                return
+            with _prof.span("mx.decode.step.emit") as sp:
+                now = time.monotonic()
+                step_ns = int((now - t0) * 1e9)
+                _prof.record_latency(self._lat_step, step_ns)
+                with self._cv:
+                    self._counters["steps"] += 1
+                    self._counters["tokens"] += len(active)
+                _prof.record_decode_event(steps=1, tokens=len(active),
+                                          slot_steps=len(active),
+                                          slot_capacity=self.batch_size)
+                retired = []
+                for seq in active:
+                    tok = int(ids[seq._slot])
+                    seq._cached += 1
+                    if seq.last_token_t is not None:
+                        _prof.record_latency(
+                            self._lat_tok,
+                            int((now - seq.last_token_t) * 1e9))
+                    seq.last_token_t = now
+                    seq._emit(tok)
+                    if self._maybe_retire(seq, tok):
+                        retired.append(seq.rid)
+                sp.set_metadata(retired=len(retired), rid=",".join(retired))
 
     # ------------------------------------------------------------------
     def stats(self):

@@ -433,6 +433,16 @@ def test_profiler_demo():
     events = _json.load(open(trace))["traceEvents"]
     names = {e["name"] for e in events}
     assert "matmul-phase" in names and "dot" in names, sorted(names)[:10]
+    # the phases are `profiler.span`s too: in the XLA trace, on its clock
+    import glob
+    import jax
+    xplane = glob.glob(os.path.splitext(trace)[0] + "_jax_trace"
+                       "/plugins/profile/*/*.xplane.pb")
+    assert xplane, "no XLA trace beside %s" % trace
+    spans = {ev.name for plane in
+             jax.profiler.ProfileData.from_file(xplane[-1]).planes
+             for line in plane.lines for ev in line.events}
+    assert {"matmul-phase", "elemwise-phase"} <= spans
 
 
 def test_bayesian_sgld():
