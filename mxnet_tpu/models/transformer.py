@@ -267,10 +267,12 @@ def transformer_loss(params, tokens, targets, cfg, mesh=None, rng=None,
 # replacing the engine's built-in single-layer parity fixture with the
 # model family the parallel stack is designed around.
 #
-# KV page layout: ``(num_blocks, block_size, num_layers, d_model)`` for
-# each of K and V (heads folded into d_model, so tp-sharding the trailing
-# dim shards heads — `kvcache.page_sharding`). Per layer l, position p of
-# a sequence lives at ``pages[table[p // bs], p % bs, l]``.
+# KV page layout: layer-major, ``(num_layers, num_blocks, block_size,
+# d_model)`` for each of K and V, so ``pages[l]`` is one layer's
+# contiguous pool and a layer gathers only its own pages. Heads are folded
+# into d_model, so tp-sharding the trailing dim shards heads
+# (`kvcache.page_sharding`). Per layer l, position p of a sequence lives
+# at ``pages[l, table[p // bs], p % bs]``.
 #
 # Masking contract (shared with the built-in fixture): padding/inactive
 # writes scatter into the null block, and every read masks additively
@@ -333,7 +335,7 @@ def transformer_decode_prefill(params, cfg, k_pages, v_pages, tokens,
     SAME bucket program called repeatedly with advancing ``start`` —
     the program family stays at len(buckets)+1."""
     C = tokens.shape[0]
-    bs = k_pages.shape[1]
+    bs = k_pages.shape[2]
     mb = table.shape[0]
     L = cfg.num_layers
     H, Dh = cfg.num_heads, cfg.d_model // cfg.num_heads
@@ -354,10 +356,10 @@ def transformer_decode_prefill(params, cfg, k_pages, v_pages, tokens,
             q = (h @ lp["wq"]).reshape(C, H, Dh)
             kk = h @ lp["wk"]                               # (C, D)
             vv = h @ lp["wv"]
-            k_pages = k_pages.at[blk, slot, l].set(kk)
-            v_pages = v_pages.at[blk, slot, l].set(vv)
-            ks = k_pages[table][:, :, l].reshape(T, H, Dh)
-            vs = v_pages[table][:, :, l].reshape(T, H, Dh)
+            k_pages = k_pages.at[l, blk, slot].set(kk)
+            v_pages = v_pages.at[l, blk, slot].set(vv)
+            ks = k_pages[l, table].reshape(T, H, Dh)
+            vs = v_pages[l, table].reshape(T, H, Dh)
             a = _decode_attn_prefill(q, ks, vs, start, cfg, use_pallas,
                                      interpret)
             x = x + a.reshape(C, cfg.d_model) @ lp["wo"]
@@ -385,7 +387,7 @@ def transformer_decode_step(params, cfg, k_pages, v_pages, token_ids,
     scalar-prefetch offs — prefill is where the flash tier earns its
     keep."""
     B, mb = tables.shape
-    bs = k_pages.shape[1]
+    bs = k_pages.shape[2]
     L = cfg.num_layers
     H, Dh = cfg.num_heads, cfg.d_model // cfg.num_heads
     T = mb * bs
@@ -405,10 +407,10 @@ def transformer_decode_step(params, cfg, k_pages, v_pages, token_ids,
             q = (h @ lp["wq"]).reshape(B, H, Dh)
             kk = h @ lp["wk"]
             vv = h @ lp["wv"]
-            k_pages = k_pages.at[blk, slot, l].set(kk)
-            v_pages = v_pages.at[blk, slot, l].set(vv)
-            ks = k_pages[tables][:, :, :, l].reshape(B, T, H, Dh)
-            vs = v_pages[tables][:, :, :, l].reshape(B, T, H, Dh)
+            k_pages = k_pages.at[l, blk, slot].set(kk)
+            v_pages = v_pages.at[l, blk, slot].set(vv)
+            ks = k_pages[l, tables].reshape(B, T, H, Dh)
+            vs = v_pages[l, tables].reshape(B, T, H, Dh)
             scores = jnp.einsum("bhd,bthd->bht", q, ks) * sm
             scores = jnp.where(tpos <= positions[:, None, None], scores, _NEG)
             w = jax.nn.softmax(scores, axis=-1)
@@ -428,9 +430,7 @@ class TransformerDecodeModel:
 
     >>> model = TransformerDecodeModel(TransformerConfig(vocab_size=256,
     ...     num_layers=2, num_heads=4, d_model=64, max_len=128))
-    >>> eng = DecodeEngine(model.params, kv_shape=model.kv_shape,
-    ...                    prefill_fn=model.prefill_fn,
-    ...                    step_fn=model.step_fn, max_seq_len=128)
+    >>> eng = DecodeEngine(**model.engine_kwargs(), max_seq_len=128)
 
     ``flash`` picks the prefill attention tier (the step body is always
     lax — see transformer_decode_step): None reads
@@ -452,10 +452,11 @@ class TransformerDecodeModel:
         self.use_pallas, self.interpret = resolve_kernel_tier(mode)
         self.flash_engaged = bool(self.use_pallas or self.interpret)
 
-    @property
-    def kv_shape(self):
-        """Trailing page dims: (num_layers, d_model)."""
-        return (self.cfg.num_layers, self.cfg.d_model)
+    def page_shape(self, num_blocks, block_size):
+        """Shape of each of the K and V pools: layer-major, so a layer
+        reads and writes only ``pages[l]``."""
+        return (self.cfg.num_layers, num_blocks, block_size,
+                self.cfg.d_model)
 
     def prefill_fn(self, params, k_pages, v_pages, tokens, start, length,
                    table):
@@ -470,5 +471,5 @@ class TransformerDecodeModel:
 
     def engine_kwargs(self):
         """kwargs bundle for DecodeEngine(**model.engine_kwargs(), ...)."""
-        return {"params": self.params, "kv_shape": self.kv_shape,
+        return {"params": self.params, "page_shape": self.page_shape,
                 "prefill_fn": self.prefill_fn, "step_fn": self.step_fn}

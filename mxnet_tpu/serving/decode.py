@@ -37,7 +37,8 @@ statement about the cache/batching machinery. Custom models plug in via
 ``prefill_fn``/``step_fn`` with the same signatures — the real
 multi-layer multi-head transformer family lives in
 :class:`~..models.transformer.TransformerDecodeModel` (flash-kernel
-prefill over the paged cache, ``kv_shape=(num_layers, d_model)``).
+prefill over the paged cache; its ``page_shape`` makes the pools
+layer-major, ``(num_layers, num_blocks, block_size, d_model)``).
 
 **Chunked prefill** (``prefill_chunk`` /
 ``MXNET_SERVING_DECODE_PREFILL_CHUNK``): a long prompt runs as
@@ -292,10 +293,13 @@ class DecodeEngine:
     default_deadline_ms : float or None
         Deadline applied when ``submit`` passes none
         (``MXNET_SERVING_DECODE_DEADLINE_MS``; unset/0 = no deadline).
-    kv_shape : tuple of int or None
-        Trailing page dims beyond ``(num_blocks, block_size)``; default
-        ``(model_dim,)``. The transformer family uses
-        ``(num_layers, d_model)``.
+    page_shape : callable or None
+        ``page_shape(num_blocks, block_size)`` returns the full shape of
+        each of the K and V pools: the model states the layout, the
+        engine builds, places, donates and AOT-describes whatever it is.
+        Default ``(num_blocks, block_size, model_dim)``, the built-in
+        LM's. The transformer family is layer-major:
+        ``(num_layers, num_blocks, block_size, d_model)``.
     prefill_chunk : int or None
         Chunked-prefill piece size
         (``MXNET_SERVING_DECODE_PREFILL_CHUNK``; 0 disables). Resolved
@@ -315,7 +319,7 @@ class DecodeEngine:
                  block_size=None, num_blocks=None, batch_size=None,
                  max_seq_len=None, prefill_buckets=None,
                  default_deadline_ms=_MISSING, default_max_new=None,
-                 prefill_fn=None, step_fn=None, kv_shape=None,
+                 prefill_fn=None, step_fn=None, page_shape=None,
                  prefill_chunk=None, mesh=None, kv_shard_axis="tp",
                  warmup=True, autostart=True):
         import jax
@@ -364,20 +368,22 @@ class DecodeEngine:
 
         self._kv = PagedKVCache(num_blocks, block_size)
         self._mb = self._kv.blocks_for(self.max_seq_len)  # table width
-        if kv_shape is None:
+        if page_shape is None:
             dim = int(params["emb"].shape[1]) if "emb" in params else int(
                 next(iter(params.values())).shape[-1])
-            kv_shape = (dim,)
+            shape = (self._kv.num_blocks, self._kv.block_size, dim)
+        else:
+            shape = page_shape(self._kv.num_blocks, self._kv.block_size)
         self._params = jax.device_put(
             jax.tree_util.tree_map(jnp.asarray, params))
-        self._k_pages = jnp.zeros(
-            (self._kv.num_blocks, self._kv.block_size)
-            + tuple(int(d) for d in kv_shape), jnp.float32)
+        self._k_pages = jnp.zeros(tuple(int(d) for d in shape),
+                                  jnp.float32)
         self._v_pages = jnp.zeros_like(self._k_pages)
         # tp-shardable KV pages: place the pools (and replicate params)
         # on the mesh; the trailing model dim shards across kv_shard_axis
         # when divisible (kvcache.page_sharding), so multi-head K/V —
-        # heads folded into the trailing dim — shards by head.
+        # heads folded into the trailing dim — shards by head, whatever
+        # axes the model's page_shape puts in front of it.
         self._page_sharding = None
         self._kv_shard_axis = str(kv_shard_axis)
         if mesh is not None:

@@ -13,7 +13,10 @@ worst case, and the accounting counters below prove it.
 Layout contract (shared with serving/decode.py's programs):
 
 - token at absolute position ``p`` of a sequence lives at
-  ``pages[table[p // block_size], p % block_size]``;
+  ``pages[table[p // block_size], p % block_size]``; a model with
+  several layers keeps one such pool per layer, stacked layer-major
+  (``pages[l, table[p // block_size], p % block_size]``), so a layer's
+  read gathers only its own pages;
 - **block 0 is the null block**: never allocated, never owned. Device
   programs route every *inactive* or *padding* write to block 0 and
   real reads never touch it (attention masks by sequence length), so a
@@ -45,11 +48,11 @@ def page_sharding(mesh, page_shape, axis_name="tp"):
     device, and divides the dim — else fully replicated.
 
     The transformer page layout folds heads into the trailing
-    ``d_model`` dim (``(num_blocks, block_size, num_layers, d_model)``),
+    ``d_model`` dim (``(num_layers, num_blocks, block_size, d_model)``),
     so tp-sharding the trailing dim is head sharding: each tp shard
     holds every sequence's block table but only its own heads' K/V —
     the standard tensor-parallel attention split, with block tables and
-    the blocks/slots axes replicated so host-side paging stays
+    the layers/blocks/slots axes replicated so host-side paging stays
     tier-agnostic."""
     from jax.sharding import NamedSharding, PartitionSpec
     spec = PartitionSpec()

@@ -238,11 +238,11 @@ class TestDecodeEngine:
 # transformer decode body (models/transformer.py, ISSUE 19)
 # ---------------------------------------------------------------------------
 
-def _tf_model(flash="off"):
+def _tf_model(flash="off", num_layers=2):
     from mxnet_tpu.models.transformer import (TransformerConfig,
                                               TransformerDecodeModel)
-    cfg = TransformerConfig(vocab_size=64, num_layers=2, num_heads=4,
-                            d_model=32, max_len=64, block_k=16)
+    cfg = TransformerConfig(vocab_size=64, num_layers=num_layers,
+                            num_heads=4, d_model=32, max_len=64, block_k=16)
     return TransformerDecodeModel(cfg, flash=flash)
 
 
@@ -251,9 +251,20 @@ def _tf_engine(model, name, **kw):
     kw.setdefault("batch_size", 3)
     kw.setdefault("max_seq_len", 64)
     kw.setdefault("prefill_buckets", (8, 16))
-    return DecodeEngine(model.params, name=name, kv_shape=model.kv_shape,
-                        prefill_fn=model.prefill_fn,
-                        step_fn=model.step_fn, **kw)
+    return DecodeEngine(**model.engine_kwargs(), name=name, **kw)
+
+
+def _gather_out_sizes(jaxpr):
+    """Element count of every `gather` equation's output, sub-jaxprs
+    (pjit, custom calls) included."""
+    import jax
+    sizes = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            sizes.extend(int(np.prod(v.aval.shape)) for v in eqn.outvars)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            sizes.extend(_gather_out_sizes(sub))
+    return sizes
 
 
 class TestTransformerDecode:
@@ -317,6 +328,58 @@ class TestTransformerDecode:
         fe.stop()
         assert out == ref, "flash-tier transformer decode diverged"
 
+    @pytest.mark.parametrize("num_layers", [1, 2, 4])
+    def test_no_gather_carries_a_layer_axis(self, num_layers):
+        """Each layer reads only its own pages: traced on the engine's
+        own argument shapes, no gather of the step or of a prefill
+        bucket is larger than ONE layer's view of the block table."""
+        import jax
+        model = _tf_model(num_layers=num_layers)
+        eng = _tf_engine(model, "tfg%d" % num_layers, warmup=False,
+                         autostart=False)
+        sd = jax.ShapeDtypeStruct
+        pages = sd(eng._k_pages.shape, eng._k_pages.dtype)
+        b, mb, bs = eng.batch_size, eng._mb, eng._kv.block_size
+        i32 = np.int32
+        # (program, its arguments after the pages, one layer's view)
+        programs = [(model.step_fn, (sd((b,), i32), sd((b,), i32),
+                                     sd((b, mb), i32), sd((b,), np.bool_)),
+                     b * mb * bs * model.cfg.d_model)]
+        programs += [(model.prefill_fn, (sd((bucket,), i32), sd((), i32),
+                                         sd((), i32), sd((mb,), i32)),
+                      mb * bs * model.cfg.d_model)
+                     for bucket in eng.prefill_buckets]
+        for fn, args, one_layer in programs:
+            sizes = _gather_out_sizes(
+                jax.make_jaxpr(fn)(eng._params, pages, pages, *args).jaxpr)
+            assert sizes.count(one_layer) == 2 * num_layers     # K and V
+            assert max(sizes) == one_layer
+
+    def test_pool_is_layer_major_and_layers_write_their_own_pages(self):
+        """The model states the pool's shape and the engine builds it:
+        (num_layers, num_blocks, block_size, d_model). After serving,
+        layer l's K/V rows sit under pages[l] at the positions the
+        sequences' tables name, the same slots for every layer, and
+        nowhere else (block 0 takes the padding writes)."""
+        model = _tf_model(num_layers=3)
+        eng = _tf_engine(model, "tflm", num_blocks=32, block_size=4)
+        assert eng._k_pages.shape == eng._v_pages.shape == (3, 32, 4, 32)
+        outs = [eng.generate(p, max_new_tokens=m)
+                for p, m in (([3, 1, 4], 2), ([1, 5, 9, 2, 6, 5, 3, 5, 8], 4))]
+        assert [len(o) for o in outs] == [2, 4]
+        eng.stop()
+        # the longer sequence holds positions 0..11 (the last token
+        # emitted is never written), the shorter one 0..3, in a block
+        # that the allocator may have handed out again
+        for pages in (np.asarray(eng._k_pages), np.asarray(eng._v_pages)):
+            written = np.abs(pages[:, 1:]).sum(axis=-1) > 0    # (L, N-1, bs)
+            assert 12 <= written[0].sum() <= 16
+            for l in range(3):
+                assert (written[l] == written[0]).all()
+            # layers hold different values at the same slots
+            assert not np.allclose(pages[0], pages[1])
+            assert not np.allclose(pages[1], pages[2])
+
     def test_mesh_placed_pages_do_not_change_tokens(self):
         """tp-sharded KV pages (kvcache.page_sharding): placement is a
         layout choice, not a numeric one."""
@@ -324,10 +387,10 @@ class TestTransformerDecode:
         from mxnet_tpu.serving.kvcache import page_sharding
         model = _tf_model()
         mesh = get_mesh(dp=2, tp=4)
-        ps = page_sharding(mesh, (64, 16, 2, 32), "tp")
+        ps = page_sharding(mesh, (2, 64, 16, 32), "tp")
         assert ps.spec[-1] == "tp"      # d_model (heads) sharded
         # indivisible trailing dim stays replicated
-        assert page_sharding(mesh, (64, 16, 2, 30), "tp").spec == \
+        assert page_sharding(mesh, (2, 64, 16, 30), "tp").spec == \
             type(ps.spec)()
         plain = _tf_engine(model, "tfpl")
         ref = [plain.generate(p, max_new_tokens=6) for p in self.PROMPTS[:3]]
