@@ -3,12 +3,15 @@
 
 One process, one chip: ResNet-50 through ``Module.fit(kvstore='tpu_sync')``,
 the same network behind ``serving.ModelServer``, a GPT-2-small-width
-``TransformerDecodeModel`` behind ``DecodeEngine``, and the Pallas kernels
+``TransformerDecodeModel`` and a ``MoEMLADecodeModel`` (latent attention,
+held experts; published widths, two layers) behind ``DecodeEngine``, and the
+Pallas kernels
 compiled (not interpreted) — every phase checked against a plain reference
 on seeded data. It starts no child process and it refuses any platform but
 "tpu"; a failed assertion or exception in any phase is a non-zero exit.
 
-    python chip_smoke.py              # one chip: train, serve, decode, kernels
+    python chip_smoke.py              # one chip: train, serve, decode (both
+                                      # families), kernels
     python chip_smoke.py --chips 4    # four chips: ONLY the cross-chip path
                                       # (dp ResNet-50, dp x tp transformer step)
     python chip_smoke.py --rehearse   # the same phases at tiny sizes, kernels
@@ -41,6 +44,21 @@ FULL = {
                    max_len=1024, buckets=(128, 256), chunk=256,
                    prompts=(100, 230, 450, 700), new_tokens=32,
                    block_size=16, num_blocks=256),
+    # openPangu-Ultra-MoE's published widths; one dense + one expert layer,
+    # 16 of 256 experts held, an eighth of the vocabulary (3.5 GB in bf16)
+    "decode_moe": dict(
+        config=dict(hidden_size=7680, num_hidden_layers=2,
+                    first_k_dense_replace=1, num_attention_heads=128,
+                    q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+                    qk_rope_head_dim=64, v_head_dim=128,
+                    intermediate_size=18432, moe_intermediate_size=2048,
+                    n_routed_experts=256, n_shared_experts=1,
+                    num_experts_per_tok=8, routed_scaling_factor=2.5,
+                    rms_norm_eps=1e-5, rope_theta=25.6e6, vocab_size=19200,
+                    experts_held=(0, 16)),
+        dtype="bfloat16", max_len=1024, buckets=(128, 256), chunk=256,
+        prompts=(100, 230, 450, 700), new_tokens=16, block_size=16,
+        num_blocks=256),
     "flash": dict(shape=(4, 8, 4096, 128), dtype="bfloat16",
                   blocks={"stream": (1024, 512), "grid": (512, 512)}),
     "dp4": dict(batch=128, steps=3, lr=0.01),
@@ -54,6 +72,20 @@ TINY = {
                    max_len=128, buckets=(16, 32), chunk=32,
                    prompts=(10, 25, 45, 70), new_tokens=8,
                    block_size=8, num_blocks=64),
+    "decode_moe": dict(
+        config=dict(hidden_size=64, num_hidden_layers=3,
+                    first_k_dense_replace=1, num_attention_heads=4,
+                    q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
+                    qk_rope_head_dim=8, v_head_dim=16, intermediate_size=160,
+                    moe_intermediate_size=48, n_routed_experts=8,
+                    n_shared_experts=1, num_experts_per_tok=2,
+                    routed_scaling_factor=2.5, rms_norm_eps=1e-5,
+                    rope_theta=25.6e6, vocab_size=128, experts_held=(2, 4),
+                    initializer_range=0.2, block_k=16, step_row_block=2,
+                    step_col_blocks=2),
+        dtype="float32", max_len=128, buckets=(16, 32), chunk=32,
+        prompts=(10, 25, 45, 70), new_tokens=8, block_size=8,
+        num_blocks=64),
     "flash": dict(shape=(1, 2, 256, 64), dtype="bfloat16",
                   blocks={"stream": (128, 128), "grid": (128, 128)}),
     "dp4": dict(batch=16, steps=3, lr=0.01),
@@ -402,10 +434,9 @@ def phase_decode(run):
         assert stats["prefill_chunks"] >= 2, "chunked prefill never ran"
         if run.platform == "tpu":
             sd = jax.ShapeDtypeStruct
-            pages = sd(eng._k_pages.shape, eng._k_pages.dtype)
             i32 = np.int32
             text = eng._prefill_b.aot(
-                eng._params, pages, pages, sd((d["buckets"][-1],), i32),
+                eng._params, eng._cache_spec, sd((d["buckets"][-1],), i32),
                 sd((), i32), sd((), i32), sd((eng._mb,), i32)).as_text()
             assert "tpu_custom_call" in text, \
                 "no Pallas kernel in the compiled prefill program"
@@ -464,6 +495,85 @@ def phase_decode(run):
              tokens_equal=int((got == best).sum()), tokens=int(got.size),
              margin_tolerance=round(margin_tol, 5),
              divergences=divergences[:4])
+
+
+def phase_decode_moe(run):
+    """The latent-attention expert family (models/moe_mla.py) through the
+    same DecodeEngine: flash prefill over the latent pool, the absorbed
+    step, the held experts' grouped product; greedy tokens teacher-forced
+    against the lax-tier full forward on the same weights in float32."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from mxnet_tpu import profiler
+    from mxnet_tpu.models.moe_mla import (MoEMLAConfig, MoEMLADecodeModel,
+                                          init_moe_mla, moe_mla_forward)
+    from mxnet_tpu.serving.decode import DecodeEngine
+    t0, c0 = time.time(), profiler.compile_counters()
+    d = run.sizes["decode_moe"]
+    cfg = MoEMLAConfig(**d["config"])
+    params = init_moe_mla(cfg, jax.random.PRNGKey(run.seed),
+                          jnp.dtype(d["dtype"]))
+    model = MoEMLADecodeModel(cfg, params=params, flash=run.kernel_tier)
+    assert model.flash_engaged, "decode prefill resolved to the lax tier"
+    rng = np.random.RandomState(run.seed)
+    prompts = [rng.randint(0, cfg.vocab_size, (n,)).astype(np.int32)
+               for n in d["prompts"]]
+    eng = DecodeEngine(**model.engine_kwargs(), name="smoke_moe",
+                       block_size=d["block_size"],
+                       num_blocks=d["num_blocks"],
+                       batch_size=len(prompts), max_seq_len=d["max_len"],
+                       prefill_buckets=d["buckets"],
+                       prefill_chunk=d["chunk"], default_deadline_ms=None)
+    try:
+        family = len(d["buckets"]) + 1
+        streams = [eng.submit(p, max_new_tokens=d["new_tokens"])
+                   for p in prompts]
+        outs = [s.result_wait(900.0) for s in streams]
+        stats = eng.stats()
+        assert sum(eng.program_counts()) == family, \
+            "decode compiled while serving: %s" % (eng.program_counts(),)
+        assert stats["served"] == len(prompts) and stats["failed"] == 0, stats
+        assert stats["prefill_chunks"] >= 2, "chunked prefill never ran"
+        m = stats["model"]
+        assert m["moe_layer_steps"] == stats["steps"] * cfg.num_expert_layers
+        assert 0 < m["moe_assignments"] <= (
+            (stats["tokens"] - stats["prefills"]) * cfg.num_expert_layers
+            * cfg.num_experts_per_tok), m
+        assert stats["kv"]["pool_bytes"] == (
+            cfg.num_hidden_layers * d["num_blocks"] * d["block_size"]
+            * cfg.cache_row_width * jnp.dtype(d["dtype"]).itemsize)
+    finally:
+        eng.stop()
+
+    n_new = d["new_tokens"]
+    p32 = jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), params)
+    ref = []
+    with jax.default_matmul_precision("highest"):
+        fwd = jax.jit(lambda p, t: moe_mla_forward(p, cfg, t))
+        for p, o in zip(prompts, outs):
+            toks = np.concatenate([p, np.asarray(o[:-1], np.int32)])[None]
+            ref.append(np.asarray(fwd(p32, toks))[0, len(p) - 1:])
+    ref = np.stack(ref)                                  # [n, new, vocab]
+    margin_tol = 2 * TOL_BF16 * float(np.abs(ref).max())
+    got = np.stack([np.asarray(o, np.int64) for o in outs])
+    best = ref.argmax(axis=-1)
+    margins = [float(ref[i, j, best[i, j]] - ref[i, j, got[i, j]])
+               for i, j in zip(*np.nonzero(got != best))]
+    assert all(m <= margin_tol for m in margins), (margins, margin_tol)
+    run.emit("decode_moe", t0, c0, programs=list(eng.program_counts()),
+             prompts=list(d["prompts"]), new_tokens=n_new,
+             prefill_chunks=stats["prefill_chunks"],
+             flash_engaged=model.flash_engaged, model=stats["model"],
+             pool_bytes=stats["kv"]["pool_bytes"],
+             compared="DecodeEngine greedy tokens vs the lax-tier "
+                      "moe_mla_forward (f32, highest precision), "
+                      "teacher-forced: the reference's margin of its own "
+                      "best over a served token that differs",
+             max_diff=round(max(margins, default=0.0), 6),
+             tolerance=round(margin_tol, 5),
+             tokens_equal=int((got == best).sum()), tokens=int(got.size),
+             divergent_margins=[round(m, 5) for m in margins[:4]])
 
 
 # --------------------------------------------------------------------------
@@ -789,7 +899,8 @@ def phase_tp(run):
              sharded_step_compiles=built)
 
 
-PHASES = {1: (phase_train, phase_serve, phase_decode, phase_kernels),
+PHASES = {1: (phase_train, phase_serve, phase_decode, phase_decode_moe,
+              phase_kernels),
           4: (phase_dp4, phase_tp)}
 
 

@@ -788,15 +788,15 @@ def _build_decode():
                        prefill_chunk=0, warmup=True, autostart=False)
     sd = jax.ShapeDtypeStruct
     i32 = _np.int32
-    pages = sd(eng._k_pages.shape, eng._k_pages.dtype)
+    cache = eng._cache_spec
     params = jax.tree_util.tree_map(
         lambda x: sd(tuple(x.shape), x.dtype), eng._params)
     mb = eng._mb
     plans = eng.comm_plan()
-    prefill_args = (params, pages, pages, sd((8,), i32), sd((), i32),
+    prefill_args = (params, cache, sd((8,), i32), sd((), i32),
                     sd((), i32), sd((mb,), i32))
     b = eng.batch_size
-    step_args = (params, pages, pages, sd((b,), i32), sd((b,), i32),
+    step_args = (params, cache, sd((b,), i32), sd((b,), i32),
                  sd((b, mb), i32), sd((b,), _np.bool_))
     return [AuditUnit("prefill", eng._prefill_b, prefill_args,
                       plan=plans["prefill"]),

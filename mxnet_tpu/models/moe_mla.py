@@ -1,0 +1,604 @@
+"""A second decoder family: latent attention (MLA) over a latent paged cache,
+sparse experts beside a shared one, assembled from a published config.
+
+What the GPT-2-style family of `models/transformer.py` does not have, each
+built from the config's own keys (`MoEMLAConfig.from_dict`): RMSNorm and
+sandwich norms, rotary positions on part of the head, multi-head latent
+attention, a gated SiLU MLP, an untied head, leading dense layers and then
+expert layers (`parallel/moe.py::routed_experts`: top-k over the router's
+full width, the experts held HERE computed through a grouped matrix product,
+a shared expert beside them).
+
+**Two attention paths, one mathematics.** The cache holds ``[c | k_rope]``
+for every token and layer: ``kv_lora_rank + qk_rope_head_dim`` numbers, not a
+key and a value for every head. *Prefill* (``moe_mla_decode_prefill``, and the
+full-sequence ``moe_mla_forward``) EXPANDS keys and values from the latent
+rows, ``[k_nope | v]_h = c W_kvb``, and attends through the repo's flash
+kernel or its blockwise tier; the full score matrix is never materialised.
+The *decode step* (``moe_mla_decode_step``) ABSORBS the up-projections:
+``q'_h = q_nope_h W_kvb,k,h^T`` is scored against the latent rows themselves,
+``u_h = sum_j p c(j)`` is a sum of latent rows, and ``o_h = u_h W_kvb,v,h``.
+All heads share one ``rkv + dr``-wide key a token, so a row's scores are one
+``[H, rkv + dr] x [rkv + dr, T]`` matrix product.
+
+**Precision.** Matrix products take operands in the parameters' dtype
+(bfloat16 as served) and accumulate in float32; norms, router scores, softmax
+and rotary run in float32. Parameters keep the dtype they arrive in and the
+cache takes it too, so the float32 tests run the same code.
+
+**Parameter layout** (shared with the benchmark's plain reference, which
+makes the weights): ``{"embed" [V, d], "head" [d, V], "norm_f" [d],
+"layers": [one dict a layer]}``; a layer holds ``norm_attn_in/out``,
+``norm_ffn_in/out``, ``wq_a``, ``norm_q``, ``wq_b``, ``wkv_a``, ``norm_kv``,
+``wkv_b``, ``wo`` and either ``w_gate/w_up/w_down`` (dense) or ``router``,
+``shared_gate/up/down``, ``experts_gate/up/down`` (leading axis: the experts
+held). Layers are separate leaves: nothing slices a stacked 6 GB array.
+
+Device-side names (`jax.named_scope`): ``mla``, ``moe.route``,
+``moe.experts``, ``moe.shared``, ``mlp`` inside ``decode.step/layer`` and
+``decode.prefill/layer``.
+"""
+from __future__ import annotations
+
+import dataclasses
+
+import numpy as _np
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ..parallel.moe import routed_experts
+
+__all__ = ["MoEMLAConfig", "init_moe_mla", "moe_mla_forward",
+           "moe_mla_decode_prefill", "moe_mla_decode_step",
+           "MoEMLADecodeModel"]
+
+_NEG = -1e30            # additive mask: exp(-1e30 - m) is exactly 0 in f32
+_LANES = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class MoEMLAConfig:
+    """The published keys by their own names, plus ``experts_held``
+    ``(first, count)``: the routed experts whose weights live here."""
+    hidden_size: int
+    num_hidden_layers: int
+    first_k_dense_replace: int
+    num_attention_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    intermediate_size: int
+    moe_intermediate_size: int
+    n_routed_experts: int
+    n_shared_experts: int
+    num_experts_per_tok: int
+    routed_scaling_factor: float
+    rms_norm_eps: float
+    rope_theta: float
+    vocab_size: int
+    experts_held: tuple = None
+    initializer_range: float = 0.02
+    # decode-path knobs (not the model's): flash tile; rows, and blocks of
+    # their tables, that a step's attention holds live at once
+    block_k: int = 512
+    step_row_block: int = 32
+    step_col_blocks: int = 32
+
+    def __post_init__(self):
+        held = self.experts_held
+        if held is None:
+            held = (0, self.n_routed_experts)
+        elif isinstance(held, dict):
+            held = (held["first"], held["count"])
+        held = (int(held[0]), int(held[1]))
+        if not (0 <= held[0] and held[0] + held[1] <= self.n_routed_experts
+                and held[1] >= 1):
+            raise ValueError("experts_held %r lies outside the %d routed "
+                             "experts" % (held, self.n_routed_experts))
+        object.__setattr__(self, "experts_held", held)
+
+    @classmethod
+    def from_dict(cls, config, **overrides):
+        """From a ``config.json`` as published (unknown keys ignored)."""
+        names = {f.name for f in dataclasses.fields(cls)}
+        kw = {k: v for k, v in config.items() if k in names}
+        kw.update(overrides)
+        return cls(**kw)
+
+    @property
+    def latent_width(self):
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def cache_row_width(self):
+        """A pool row: ``[c | k_rope]`` zero-padded to whole lanes. The
+        TPU's tiled layout pads the trailing axis to a multiple of 128 in
+        memory either way; left to itself it instead picks a layout with
+        the BLOCK axis innermost for a 576-wide row and transposes the whole
+        pool on the way in and out of every program (counted for a
+        described v5e, PERF.md PR 28). Stating the padded width keeps
+        logical and physical layout the same: layout only, the pad is
+        never read as a number."""
+        return -(-self.latent_width // _LANES) * _LANES
+
+    def is_dense(self, layer):
+        return layer < self.first_k_dense_replace
+
+    @property
+    def num_expert_layers(self):
+        return self.num_hidden_layers - min(self.first_k_dense_replace,
+                                            self.num_hidden_layers)
+
+
+def _layer_shapes(cfg, dense):
+    d, H = cfg.hidden_size, cfg.num_attention_heads
+    out = {
+        "norm_attn_in": (d,), "norm_attn_out": (d,),
+        "norm_ffn_in": (d,), "norm_ffn_out": (d,),
+        "wq_a": (d, cfg.q_lora_rank), "norm_q": (cfg.q_lora_rank,),
+        "wq_b": (cfg.q_lora_rank,
+                 H * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)),
+        "wkv_a": (d, cfg.latent_width), "norm_kv": (cfg.kv_lora_rank,),
+        "wkv_b": (cfg.kv_lora_rank,
+                  H * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+        "wo": (H * cfg.v_head_dim, d),
+    }
+    if dense:
+        i = cfg.intermediate_size
+        out.update({"w_gate": (d, i), "w_up": (d, i), "w_down": (i, d)})
+    else:
+        f, n = cfg.moe_intermediate_size, cfg.experts_held[1]
+        fs = f * cfg.n_shared_experts
+        out.update({"router": (d, cfg.n_routed_experts),
+                    "shared_gate": (d, fs), "shared_up": (d, fs),
+                    "shared_down": (fs, d),
+                    "experts_gate": (n, d, f), "experts_up": (n, d, f),
+                    "experts_down": (n, f, d)})
+    return out
+
+
+def init_moe_mla(cfg, key, dtype=jnp.float32):
+    """Seeded parameters in ONE jitted call, every leaf made in ``dtype``
+    directly: normal(0, ``initializer_range``) matrices, norm gains 1."""
+    shapes = {"embed": (cfg.vocab_size, cfg.hidden_size),
+              "head": (cfg.hidden_size, cfg.vocab_size),
+              "norm_f": (cfg.hidden_size,),
+              "layers": [_layer_shapes(cfg, cfg.is_dense(l))
+                         for l in range(cfg.num_hidden_layers)]}
+    is_shape = lambda s: isinstance(s, tuple)           # noqa: E731
+    leaves, tree = jax.tree_util.tree_flatten_with_path(shapes,
+                                                        is_leaf=is_shape)
+    dt = jnp.dtype(dtype)
+
+    @jax.jit
+    def make(key):
+        keys = jax.random.split(key, len(leaves))
+        out = []
+        for k, (path, shape) in zip(keys, leaves):
+            if str(path[-1].key).startswith("norm_"):
+                out.append(jnp.ones(shape, dt))
+            else:
+                out.append((jax.random.normal(k, shape, jnp.float32)
+                            * cfg.initializer_range).astype(dt))
+        return jax.tree_util.tree_unflatten(tree, out)
+
+    return make(key)
+
+
+# ---------------------------------------------------------------------------
+# pieces
+# ---------------------------------------------------------------------------
+def _mm(a, b, out=jnp.float32):
+    """``a @ b`` with operands in the parameters' dtype, accumulated in
+    float32 and handed back in ``out`` (float32 unless the result is only
+    ever another product's operand)."""
+    return jnp.matmul(a.astype(b.dtype), b, preferred_element_type=out)
+
+
+def _rms(x, g, eps):
+    x = x.astype(jnp.float32)
+    return x * lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) \
+        * g.astype(jnp.float32)
+
+
+def _rope(x, pos, theta):
+    """Half-split rotary in float32. ``x`` ``[N, ..., dr]``, ``pos`` ``[N]``:
+    row ``n`` is rotated to position ``pos[n]``."""
+    half = x.shape[-1] // 2
+    inv = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    ang = pos.astype(jnp.float32)[:, None] * inv[None, :]
+    ang = ang.reshape((x.shape[0],) + (1,) * (x.ndim - 2) + (half,))
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x = x.astype(jnp.float32)
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
+
+
+def _gated_mlp(x, wg, wu, wd):
+    return _mm(jax.nn.silu(_mm(x, wg)) * _mm(x, wu), wd)
+
+
+def _mla_project(cfg, lp, h, pos):
+    """Normed activations ``h`` ``[N, d]`` at positions ``pos`` ``[N]`` ->
+    ``(q_nope [N, H, dn], q_rope [N, H, dr] rotated, rows [N, rkv + dr])``:
+    ``rows`` is what the cache holds, ``[c | k_rope]``, float32 here."""
+    H, dn, dr = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                 cfg.qk_rope_head_dim)
+    N = h.shape[0]
+    c_q = _rms(_mm(h, lp["wq_a"]), lp["norm_q"], cfg.rms_norm_eps)
+    # only ever an operand again (the rotary part after its float32 turn)
+    q = _mm(c_q, lp["wq_b"], lp["wq_b"].dtype).reshape(N, H, dn + dr)
+    kv = _mm(h, lp["wkv_a"])
+    c = _rms(kv[:, :cfg.kv_lora_rank], lp["norm_kv"], cfg.rms_norm_eps)
+    k_rope = _rope(kv[:, cfg.kv_lora_rank:], pos, cfg.rope_theta)
+    q_rope = _rope(q[..., dn:], pos, cfg.rope_theta)
+    return q[..., :dn], q_rope, jnp.concatenate([c, k_rope], -1)
+
+
+def _cache_rows(rows, pool):
+    """``[c | k_rope]`` rows as the pool stores them: its dtype, zero-padded
+    to its row width."""
+    return jnp.pad(rows.astype(pool.dtype),
+                   ((0, 0), (0, pool.shape[-1] - rows.shape[-1])))
+
+
+def _expansion_weights(cfg, lp, k_width=None, v_width=None):
+    """The up-projection as two matrices that expand latent rows straight
+    into the attention kernels' layout: ``w_k`` ``[rkv + dr, H, k_width]``
+    gives a key ``[k_nope | k_rope | 0]`` (an identity block carries the
+    row's rotary part, one for all heads, into its columns: a product with
+    1.0, exact), ``w_v`` ``[rkv, H, v_width]`` a value ``[v | 0]``. The
+    widths default to the published ``dn + dr`` and ``dv``. Splitting,
+    broadcasting, concatenating and padding the product's RESULT instead
+    costs four more passes over the keys and values (PERF.md, PR 28)."""
+    H, dn, dr, dv, rkv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                          cfg.qk_rope_head_dim, cfg.v_head_dim,
+                          cfg.kv_lora_rank)
+    k_width = dn + dr if k_width is None else k_width
+    v_width = dv if v_width is None else v_width
+    dt = lp["wkv_b"].dtype          # operands of the attention products
+    w_kvb = lp["wkv_b"].reshape(rkv, H, dn + dv)
+    carry = jnp.pad(jnp.broadcast_to(jnp.eye(dr, dtype=dt)[:, None, :],
+                                     (dr, H, dr)),
+                    ((0, 0), (0, 0), (dn, k_width - dn - dr)))
+    w_k = jnp.concatenate(
+        [jnp.pad(w_kvb[..., :dn], ((0, 0), (0, 0), (0, k_width - dn))),
+         carry], axis=0)
+    w_v = jnp.pad(w_kvb[..., dn:], ((0, 0), (0, 0), (0, v_width - dv)))
+    return w_k, w_v
+
+
+def _mla_expand(cfg, rows, w_k, w_v):
+    """Latent rows ``[T, >= rkv + dr]`` (pool rows keep their pad) -> keys
+    and values ``[H, T, .]``, head-major, one matrix product each."""
+    dt = w_k.dtype
+    rows = rows.astype(dt)
+    k = jnp.einsum("tc,chx->htx", rows[:, :cfg.latent_width], w_k,
+                   preferred_element_type=dt)
+    v = jnp.einsum("tr,rhx->htx", rows[:, :cfg.kv_lora_rank], w_v,
+                   preferred_element_type=dt)
+    return k, v
+
+
+def _attend_expanded(cfg, lp, q, rows, start, use_pallas, interpret):
+    """Causal attention of queries ``q`` ``[C, H, dn + dr]`` at global
+    positions ``start + i`` over the keys and values that the latent
+    ``rows`` ``[T, .]`` at positions ``0..T-1`` expand to, through the flash
+    kernel (``use_pallas`` / ``interpret``) or the blockwise lax tier. The
+    kernels want ONE head width, a multiple of the lane count: q and k (192
+    wide as published) and v (128) are zero-padded to it and the output is
+    cut back: layout only, a zero column adds nothing to a score or to a
+    value. (Expanding only the live positions, a group at a time into
+    buffers carried from layer to layer, was tried and lost: the loop's
+    buffers take another layout than the kernel's and are copied into it,
+    PERF.md PR 28.)"""
+    from ..kernels.flash_attention import (blockwise_attention,
+                                           flash_attention_with_lse)
+    C, H, dqk = q.shape
+    T, dv = rows.shape[0], cfg.v_head_dim
+    sm = 1.0 / _np.sqrt(dqk)
+    q = q.transpose(1, 0, 2)
+    bq = C if C % min(cfg.block_k, C) else min(cfg.block_k, C)
+    bk = T if T % min(cfg.block_k, T) else min(cfg.block_k, T)
+    if use_pallas or interpret:
+        w = -(-max(dqk, dv) // _LANES) * _LANES if use_pallas \
+            else max(dqk, dv)
+        k, v = _mla_expand(cfg, rows, *_expansion_weights(cfg, lp, w, w))
+        q = jnp.pad(q.astype(k.dtype), ((0, 0), (0, 0), (0, w - dqk)))
+        offs = jnp.stack([jnp.asarray(start, jnp.int32), jnp.int32(0)])
+        out, _ = flash_attention_with_lse(q[None], k[None], v[None], offs,
+                                          sm, True, bq, bk, interpret,
+                                          "grid")
+        out = out[..., :dv]
+    else:
+        # the lax tier keeps its online softmax in its operands' dtype:
+        # hand it float32 (the kernels accumulate in float32 themselves)
+        k, v = _mla_expand(cfg, rows, *_expansion_weights(cfg, lp))
+        lay = lambda t: t.astype(jnp.float32)[None]             # noqa: E731
+        out, _ = blockwise_attention(lay(q), lay(k), lay(v), causal=True,
+                                     sm_scale=sm, block_k=bk,
+                                     q_offset=start, k_offset=0)
+    return out[0].transpose(1, 0, 2)                    # [C, H, dv]
+
+
+def _ffn(cfg, lp, h, valid=None):
+    """The layer's feed-forward over normed ``h`` ``[N, d]``: the dense gated
+    MLP, or shared expert + the held routed experts' part. Returns ``(out,
+    counts)``; ``counts`` (``[held]`` int32) is ``None`` for a dense layer."""
+    if "w_gate" in lp:
+        with jax.named_scope("mlp"):
+            return _gated_mlp(h, lp["w_gate"], lp["w_up"], lp["w_down"]), None
+    hp = h.astype(lp["experts_gate"].dtype)
+    with jax.named_scope("moe.shared"):
+        shared = _gated_mlp(hp, lp["shared_gate"], lp["shared_up"],
+                            lp["shared_down"])
+    routed, counts = routed_experts(
+        lp, hp, held=cfg.experts_held, top_k=cfg.num_experts_per_tok,
+        scale=cfg.routed_scaling_factor, valid=valid)
+    return shared + routed, counts
+
+
+def _block(cfg, lp, x, attend, valid=None):
+    """One sandwich-normed block. ``attend(h)`` maps the normed input to the
+    attention output BEFORE ``W_o`` (``[N, H * dv]``): the three paths
+    (full sequence, prefill chunk, absorbed step) differ only there."""
+    eps = cfg.rms_norm_eps
+    with jax.named_scope("mla"):
+        a = _mm(attend(_rms(x, lp["norm_attn_in"], eps)), lp["wo"])
+    h = x + _rms(a, lp["norm_attn_out"], eps)
+    f, counts = _ffn(cfg, lp, _rms(h, lp["norm_ffn_in"], eps), valid)
+    return h + _rms(f, lp["norm_ffn_out"], eps), counts
+
+
+def _aux(cfg, all_counts, prefix=""):
+    """The expert layers' counts of one call as the engine's ``aux``."""
+    if not all_counts:
+        return {}
+    c = jnp.stack(all_counts)                           # [layers, held]
+    return {prefix + "moe_assignments": jnp.sum(c),
+            prefix + "moe_busiest": jnp.sum(jnp.max(c, axis=1)),
+            prefix + "moe_experts_touched": jnp.sum(c > 0),
+            prefix + "moe_layer_steps": jnp.int32(len(all_counts))}
+
+
+def _logits(cfg, params, x):
+    return _mm(_rms(x, params["norm_f"], cfg.rms_norm_eps), params["head"])
+
+
+# ---------------------------------------------------------------------------
+# full sequence (tests; the expanded path, no cache)
+# ---------------------------------------------------------------------------
+def moe_mla_forward(params, cfg, tokens, *, use_pallas=False,
+                    interpret=False):
+    """Logits ``[B, S, V]`` (float32) of a full causal forward over
+    ``tokens`` ``[B, S]``: the expanded attention path with no cache, the
+    same block the prefill runs."""
+    def one(toks):
+        S = toks.shape[0]
+        pos = jnp.arange(S, dtype=jnp.int32)
+        x = params["embed"][toks].astype(jnp.float32)
+        for lp in params["layers"]:
+            def attend(h, lp=lp):
+                q_nope, q_rope, rows = _mla_project(cfg, lp, h, pos)
+                q = jnp.concatenate([q_nope, q_rope], -1)
+                o = _attend_expanded(cfg, lp, q, rows, 0, use_pallas,
+                                     interpret)
+                return o.reshape(S, -1)
+            x, _ = _block(cfg, lp, x, attend)
+        return _logits(cfg, params, x)
+    return lax.map(one, tokens)     # a sequence at a time (no vmap of the
+    #                                 grouped product, no batch of scores)
+
+
+# ---------------------------------------------------------------------------
+# the DecodeEngine seam
+# ---------------------------------------------------------------------------
+@jax.named_scope("decode.prefill")      # the trace's device-side name
+def moe_mla_decode_prefill(params, cfg, cache, tokens, start, length, table,
+                           *, use_pallas=False, interpret=False,
+                           with_logits=False):
+    """Bucketed batch-1 prefill chunk: write the latent rows of global
+    positions ``start .. start+length-1`` into ``cache["latent"]``, expand
+    keys and values of the sequence's whole table from the latent rows,
+    attend causally, return the greedy next token after the chunk's last
+    real position. The DecodeEngine prefill seam ``(params, cache, tokens,
+    start, length, table) -> (next_id, cache, aux)``; ``with_logits``
+    (tests) appends that position's float32 logits."""
+    pool = cache["latent"]                  # [L, blocks, bs, rkv + dr]
+    C = tokens.shape[0]
+    bs, mb = pool.shape[2], table.shape[0]
+    T = mb * bs
+    idx = jnp.arange(C, dtype=jnp.int32)
+    pos = start + idx
+    valid = idx < length
+    blk = jnp.where(valid, table[jnp.clip(pos, 0, T - 1) // bs], 0)
+    slot = jnp.clip(pos, 0, T - 1) % bs
+    x = params["embed"][tokens].astype(jnp.float32)
+    all_counts = []
+    for l, lp in enumerate(params["layers"]):
+        with jax.named_scope("layer"):
+            def attend(h, l=l, lp=lp):
+                nonlocal pool
+                q_nope, q_rope, rows = _mla_project(cfg, lp, h, pos)
+                pool = pool.at[l, blk, slot].set(_cache_rows(rows, pool))
+                q = jnp.concatenate([q_nope, q_rope], -1)
+                o = _attend_expanded(cfg, lp, q,
+                                     pool[l, table].reshape(T, -1), start,
+                                     use_pallas, interpret)
+                return o.reshape(C, -1)
+            x, counts = _block(cfg, lp, x, attend, valid)
+            if counts is not None:
+                all_counts.append(counts)
+    x_last = jnp.take(x, jnp.clip(length - 1, 0, C - 1), axis=0)
+    logits = _logits(cfg, params, x_last)
+    out = (jnp.argmax(logits).astype(jnp.int32), {"latent": pool},
+           _aux(cfg, all_counts, "prefill_"))
+    return out + (logits,) if with_logits else out
+
+
+def _absorbed_attention(cfg, lp, q_nope, q_rope, pool, l, tables, positions):
+    """The decode step's attention in the latent space. ``q_nope`` ``[B, H,
+    dn]``, ``q_rope`` ``[B, H, dr]``, ``pool`` ``[L, blocks, bs, row]`` read
+    at layer ``l`` (``pool[l, tables]``, never ``pool[l][tables]``: the
+    second copies the layer's pool before the gather, PERF.md PR 27).
+
+    Only LIVE positions are read. The rows are sorted by length and taken
+    ``step_row_block`` at a time; a block walks its rows' tables
+    ``step_col_blocks`` blocks at a time, as far as its longest row reaches
+    and no further (a loop with a traced trip count: static shapes, one
+    program), folding each piece in with a running softmax. What is live at
+    once is a piece's gathered latent rows and scores. Sorting keeps a
+    block's rows about equally long, so little of a piece is masked."""
+    H, dn, dv, rkv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                      cfg.v_head_dim, cfg.kv_lora_rank)
+    B, mb = tables.shape
+    bs, width = pool.shape[2], pool.shape[3]
+    dt = pool.dtype
+    w_kvb = lp["wkv_b"].reshape(rkv, H, dn + dv)
+    w_k, w_v = w_kvb[..., :dn], w_kvb[..., dn:]
+    # q'_h = q_nope_h W_kvb,k,h^T: the up-projection moves onto the query
+    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope.astype(dt), w_k,
+                       preferred_element_type=jnp.float32)
+    qq = jnp.concatenate([q_lat, q_rope], -1).astype(dt)    # [B, H, rkv+dr]
+    qq = jnp.pad(qq, ((0, 0), (0, 0), (0, width - qq.shape[-1])))
+    sm = 1.0 / _np.sqrt(dn + cfg.qk_rope_head_dim)
+    cb = cfg.step_col_blocks if mb % cfg.step_col_blocks == 0 else mb
+    span = cb * bs                              # positions a piece covers
+
+    def rows_block(args):
+        qq_b, tables_b, pos_b = args            # [rb, H, .], [rb, mb], [rb]
+        rb = qq_b.shape[0]
+
+        def piece(j, carry):
+            m, den, acc = carry
+            tab = lax.dynamic_slice_in_dim(tables_b, j * cb, cb, axis=1)
+            lat = pool[l, tab].reshape(rb, span, width)
+            s = jnp.einsum("bhc,btc->bht", qq_b, lat,
+                           preferred_element_type=jnp.float32) * sm
+            tpos = j * span + jnp.arange(span, dtype=jnp.int32)
+            s = jnp.where(tpos[None, None, :] <= pos_b[:, None, None], s,
+                          _NEG)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            p = jnp.exp(s - m_new[..., None])
+            alpha = jnp.exp(m - m_new)
+            acc = acc * alpha[..., None] + jnp.einsum(
+                "bht,btr->bhr", p.astype(dt), lat[..., :rkv],
+                preferred_element_type=jnp.float32)
+            return m_new, den * alpha + jnp.sum(p, axis=-1), acc
+
+        # position 0 is live for every row, so the first piece leaves a
+        # finite running maximum and a masked piece after it adds exact 0
+        carry = (jnp.full((rb, H), _NEG, jnp.float32),
+                 jnp.zeros((rb, H), jnp.float32),
+                 jnp.zeros((rb, H, rkv), jnp.float32))
+        _, den, acc = lax.fori_loop(0, jnp.max(pos_b) // span + 1, piece,
+                                    carry)
+        return acc / den[..., None]
+
+    rb = cfg.step_row_block
+    if B % rb or B <= rb:
+        u = rows_block((qq, tables, positions))
+    else:
+        order = jnp.argsort(positions)
+        split = lambda t: jnp.take(t, order, axis=0).reshape(   # noqa: E731
+            (B // rb, rb) + t.shape[1:])
+        u = lax.map(rows_block, (split(qq), split(tables), split(positions)))
+        u = jnp.take(u.reshape(B, H, rkv), jnp.argsort(order), axis=0)
+    o = jnp.einsum("bhr,rhv->bhv", u.astype(dt), w_v,
+                   preferred_element_type=jnp.float32)
+    return o.reshape(B, H * dv)
+
+
+@jax.named_scope("decode.step")      # the trace's device-side name
+def moe_mla_decode_step(params, cfg, cache, token_ids, positions, tables,
+                        active, *, with_logits=False):
+    """Fixed-shape batched decode step, one token per active row, attention
+    absorbed into the latent space. The DecodeEngine step seam ``(params,
+    cache, token_ids, positions, tables, active) -> (next_ids, cache, aux)``.
+    A row contracts only over its own gathered blocks; an inactive row
+    writes to the null block, is routed to no expert and counted nowhere.
+    ``with_logits`` (tests) appends the rows' float32 logits."""
+    pool = cache["latent"]
+    bs = pool.shape[2]
+    blk = jnp.take_along_axis(tables, (positions // bs)[:, None], axis=1)
+    blk = jnp.where(active, blk[:, 0], 0)
+    slot = positions % bs
+    x = params["embed"][token_ids].astype(jnp.float32)
+    all_counts = []
+    for l, lp in enumerate(params["layers"]):
+        with jax.named_scope("layer"):
+            def attend(h, l=l, lp=lp):
+                nonlocal pool
+                q_nope, q_rope, rows = _mla_project(cfg, lp, h, positions)
+                pool = pool.at[l, blk, slot].set(_cache_rows(rows, pool))
+                return _absorbed_attention(cfg, lp, q_nope, q_rope, pool, l,
+                                           tables, positions)
+            x, counts = _block(cfg, lp, x, attend, active)
+            if counts is not None:
+                all_counts.append(counts)
+    logits = _logits(cfg, params, x)
+    aux = _aux(cfg, all_counts)
+    # cached tokens this step attended over, the active rows together
+    aux["kv_live_tokens"] = jnp.sum(jnp.where(active, positions + 1, 0))
+    out = (jnp.argmax(logits, axis=-1).astype(jnp.int32), {"latent": pool},
+           aux)
+    return out + (logits,) if with_logits else out
+
+
+class MoEMLADecodeModel:
+    """Adapter: a `MoEMLAConfig` wired for the DecodeEngine seam.
+
+    >>> model = MoEMLADecodeModel(cfg, params=params)       # or seed=
+    >>> eng = DecodeEngine(**model.engine_kwargs(), max_seq_len=4096, ...)
+
+    ``flash`` picks the prefill attention tier as in
+    `TransformerDecodeModel` (None reads ``MXNET_SERVING_DECODE_FLASH``:
+    auto | 1/on | 0/off | interpret). The cache is ONE pool of latent rows
+    in the parameters' dtype; with a ``mesh`` it is stated replicated: a
+    latent row has no head axis to shard."""
+
+    def __init__(self, cfg, params=None, seed=0, dtype=jnp.bfloat16,
+                 flash=None, mesh=None):
+        from ..parallel.mesh_kernels import resolve_kernel_tier
+        self.cfg = cfg
+        if params is None:
+            params = init_moe_mla(cfg, jax.random.PRNGKey(seed), dtype)
+        self.params = params
+        self.cache_dtype = params["embed"].dtype
+        self.mesh = mesh
+        mode = flash
+        if mode is None:
+            import os
+            mode = os.environ.get("MXNET_SERVING_DECODE_FLASH", "auto")
+        self.use_pallas, self.interpret = resolve_kernel_tier(mode)
+        self.flash_engaged = bool(self.use_pallas or self.interpret)
+
+    def cache_spec(self, num_blocks, block_size):
+        """One pool: ``(layers, blocks, block_size, cache_row_width)`` in
+        the parameters' dtype; a row is ``[c | k_rope]``, ``kv_lora_rank +
+        qk_rope_head_dim`` numbers, padded to whole lanes."""
+        sharding = None
+        if self.mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+            sharding = NamedSharding(self.mesh, PartitionSpec())
+        return {"latent": jax.ShapeDtypeStruct(
+            (self.cfg.num_hidden_layers, num_blocks, block_size,
+             self.cfg.cache_row_width), self.cache_dtype,
+            sharding=sharding)}
+
+    def prefill_fn(self, params, cache, tokens, start, length, table):
+        return moe_mla_decode_prefill(
+            params, self.cfg, cache, tokens, start, length, table,
+            use_pallas=self.use_pallas, interpret=self.interpret)
+
+    def step_fn(self, params, cache, token_ids, positions, tables, active):
+        return moe_mla_decode_step(params, self.cfg, cache, token_ids,
+                                   positions, tables, active)
+
+    def engine_kwargs(self):
+        """kwargs bundle for DecodeEngine(**model.engine_kwargs(), ...)."""
+        return {"params": self.params, "cache_spec": self.cache_spec,
+                "prefill_fn": self.prefill_fn, "step_fn": self.step_fn}

@@ -322,7 +322,7 @@ def _decode_attn_prefill(q, ks, vs, start, cfg, use_pallas, interpret):
 
 
 @jax.named_scope("decode.prefill")      # the trace's device-side name
-def transformer_decode_prefill(params, cfg, k_pages, v_pages, tokens,
+def transformer_decode_prefill(params, cfg, cache, tokens,
                                start, length, table, *, use_pallas=False,
                                interpret=False):
     """Bucketed batch-1 prefill chunk: write K/V for global positions
@@ -330,10 +330,12 @@ def transformer_decode_prefill(params, cfg, k_pages, v_pages, tokens,
     next token after the chunk's last real position.
 
     Matches the DecodeEngine prefill seam
-    ``(params, k_pages, v_pages, tokens, start, length, table)``.
+    ``(params, cache, tokens, start, length, table) -> (next_id, cache,
+    aux)``; the cache is ``{"k": pages, "v": pages}``, ``aux`` empty.
     Whole-prompt prefill is the ``start=0`` call; chunked prefill is the
     SAME bucket program called repeatedly with advancing ``start`` —
     the program family stays at len(buckets)+1."""
+    k_pages, v_pages = cache["k"], cache["v"]
     C = tokens.shape[0]
     bs = k_pages.shape[2]
     mb = table.shape[0]
@@ -369,16 +371,17 @@ def transformer_decode_prefill(params, cfg, k_pages, v_pages, tokens,
     x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
     x_last = jnp.take(x, jnp.clip(length - 1, 0, C - 1), axis=0)
     logits = x_last @ params["embed"].T.astype(cfg.dtype)
-    return jnp.argmax(logits).astype(jnp.int32), k_pages, v_pages
+    return (jnp.argmax(logits).astype(jnp.int32),
+            {"k": k_pages, "v": v_pages}, {})
 
 
 @jax.named_scope("decode.step")      # the trace's device-side name
-def transformer_decode_step(params, cfg, k_pages, v_pages, token_ids,
+def transformer_decode_step(params, cfg, cache, token_ids,
                             positions, tables, active):
     """Fixed-shape batched decode step: one token per active row.
 
-    Matches the DecodeEngine step seam ``(params, k_pages, v_pages,
-    token_ids, positions, tables, active)``. Every per-row contraction
+    Matches the DecodeEngine step seam ``(params, cache, token_ids,
+    positions, tables, active) -> (next_ids, cache, aux)``. Every per-row contraction
     runs only over that row's own gathered blocks (einsum batch dim),
     so rows cannot observe each other — batched decode stays
     bit-identical to solo decode, layer count notwithstanding. The lax
@@ -386,6 +389,7 @@ def transformer_decode_step(params, cfg, k_pages, v_pages, token_ids,
     carry different lengths, which cannot share the flash kernels'
     scalar-prefetch offs — prefill is where the flash tier earns its
     keep."""
+    k_pages, v_pages = cache["k"], cache["v"]
     B, mb = tables.shape
     bs = k_pages.shape[2]
     L = cfg.num_layers
@@ -421,7 +425,8 @@ def transformer_decode_step(params, cfg, k_pages, v_pages, token_ids,
                      + lp["b2"])
     x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
     logits = x @ params["embed"].T.astype(cfg.dtype)
-    return jnp.argmax(logits, axis=-1).astype(jnp.int32), k_pages, v_pages
+    return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
+            {"k": k_pages, "v": v_pages}, {})
 
 
 class TransformerDecodeModel:
@@ -452,24 +457,24 @@ class TransformerDecodeModel:
         self.use_pallas, self.interpret = resolve_kernel_tier(mode)
         self.flash_engaged = bool(self.use_pallas or self.interpret)
 
-    def page_shape(self, num_blocks, block_size):
-        """Shape of each of the K and V pools: layer-major, so a layer
-        reads and writes only ``pages[l]``."""
-        return (self.cfg.num_layers, num_blocks, block_size,
-                self.cfg.d_model)
+    def cache_spec(self, num_blocks, block_size):
+        """The cache: twin float32 K and V pools, layer-major, so a
+        layer reads and writes only ``pages[l]``."""
+        pool = jax.ShapeDtypeStruct(
+            (self.cfg.num_layers, num_blocks, block_size, self.cfg.d_model),
+            jnp.float32)
+        return {"k": pool, "v": pool}
 
-    def prefill_fn(self, params, k_pages, v_pages, tokens, start, length,
-                   table):
+    def prefill_fn(self, params, cache, tokens, start, length, table):
         return transformer_decode_prefill(
-            params, self.cfg, k_pages, v_pages, tokens, start, length,
+            params, self.cfg, cache, tokens, start, length,
             table, use_pallas=self.use_pallas, interpret=self.interpret)
 
-    def step_fn(self, params, k_pages, v_pages, token_ids, positions,
-                tables, active):
-        return transformer_decode_step(params, self.cfg, k_pages, v_pages,
+    def step_fn(self, params, cache, token_ids, positions, tables, active):
+        return transformer_decode_step(params, self.cfg, cache,
                                        token_ids, positions, tables, active)
 
     def engine_kwargs(self):
         """kwargs bundle for DecodeEngine(**model.engine_kwargs(), ...)."""
-        return {"params": self.params, "page_shape": self.page_shape,
+        return {"params": self.params, "cache_spec": self.cache_spec,
                 "prefill_fn": self.prefill_fn, "step_fn": self.step_fn}

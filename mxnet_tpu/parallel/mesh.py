@@ -2,7 +2,8 @@
 
 The TPU-native scaling model (SURVEY.md §2.8): pick a `jax.sharding.Mesh`,
 annotate shardings, let XLA insert collectives over ICI. Axes follow the
-standard recipe: dp (data), tp (tensor/model), pp (pipeline), sp (sequence).
+standard recipe: dp (data), tp (tensor/model), pp (pipeline), sp (sequence),
+and ep (experts: `parallel/moe.py::routed_experts` sums its shares over it).
 """
 from __future__ import annotations
 
@@ -21,14 +22,14 @@ def data_parallel_mesh(devices=None):
     return Mesh(_np.asarray(devices), ("dp",))  # tpulint: allow-host-sync device handle list, not a device array
 
 
-def get_mesh(dp=1, tp=1, pp=1, sp=1, devices=None):
-    """Build an (dp, tp, pp, sp) mesh; trailing unit axes are kept for uniform specs."""
+def get_mesh(dp=1, tp=1, pp=1, sp=1, ep=1, devices=None):
+    """Build an (dp, tp, pp, sp, ep) mesh; trailing unit axes are kept for uniform specs."""
     devices = devices if devices is not None else jax.devices()
-    n = dp * tp * pp * sp
+    n = dp * tp * pp * sp * ep
     if n != len(devices):
         raise ValueError("mesh size %d != device count %d" % (n, len(devices)))
-    arr = _np.asarray(devices).reshape(dp, tp, pp, sp)  # tpulint: allow-host-sync device handle list, not a device array
-    return Mesh(arr, ("dp", "tp", "pp", "sp"))
+    arr = _np.asarray(devices).reshape(dp, tp, pp, sp, ep)  # tpulint: allow-host-sync device handle list, not a device array
+    return Mesh(arr, ("dp", "tp", "pp", "sp", "ep"))
 
 
 class ShardingConfig:

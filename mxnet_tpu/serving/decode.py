@@ -37,8 +37,21 @@ statement about the cache/batching machinery. Custom models plug in via
 ``prefill_fn``/``step_fn`` with the same signatures — the real
 multi-layer multi-head transformer family lives in
 :class:`~..models.transformer.TransformerDecodeModel` (flash-kernel
-prefill over the paged cache; its ``page_shape`` makes the pools
-layer-major, ``(num_layers, num_blocks, block_size, d_model)``).
+prefill over the paged cache; its ``cache_spec`` states two layer-major
+float32 pools, ``(num_layers, num_blocks, block_size, d_model)``) and the
+latent-attention expert family in
+:class:`~..models.moe_mla.MoEMLADecodeModel` (one bfloat16 pool of
+latent rows).
+
+**The cache seam.** The model states its cache as a pytree of
+``jax.ShapeDtypeStruct`` (``cache_spec(num_blocks, block_size)``: one
+leaf per pool, any shape and dtype); the engine allocates it, places it
+on the mesh, donates it, describes it to ``aot_info`` and passes it
+WHOLE: ``prefill_fn(params, cache, tokens, start, length, table) ->
+(next_id, cache, aux)`` and ``step_fn(params, cache, token_ids,
+positions, tables, active) -> (next_ids, cache, aux)``. ``aux`` is a
+dict of small integer arrays (may be empty) that comes back in the same
+read-back as the ids and is summed into ``stats()["model"]``.
 
 **Chunked prefill** (``prefill_chunk`` /
 ``MXNET_SERVING_DECODE_PREFILL_CHUNK``): a long prompt runs as
@@ -105,7 +118,19 @@ def tiny_lm_params(vocab=32, dim=16, seed=0):
     }
 
 
-def _lm_prefill(params, k_pages, v_pages, tokens, start, length, table):
+def _lm_cache_spec(dim):
+    """``cache_spec`` of the built-in LM: twin float32 pools of width
+    ``dim``, ``{"k": ..., "v": ...}``."""
+    def cache_spec(num_blocks, block_size):
+        import jax
+        import jax.numpy as jnp
+        pool = jax.ShapeDtypeStruct((num_blocks, block_size, dim),
+                                    jnp.float32)
+        return {"k": pool, "v": pool}
+    return cache_spec
+
+
+def _lm_prefill(params, cache, tokens, start, length, table):
     """Built-in prefill body (batch 1, bucketed prompt chunk).
 
     ``tokens (L,) i32`` bucket-padded prompt chunk; ``start () i32``
@@ -114,7 +139,7 @@ def _lm_prefill(params, k_pages, v_pages, tokens, start, length, table):
     padded with the null block. Writes K/V for global positions
     ``start..start+length-1`` (padding rows scatter into the null
     block), attends the chunk's last real token over
-    ``pos < start + length``, returns ``(next_id, k_pages, v_pages)``.
+    ``pos < start + length``, returns ``(next_id, cache, aux)``.
     Whole-prompt prefill is the ``start=0`` call; chunked prefill calls
     the SAME bucket program with advancing ``start`` — bit-identical
     because masked lanes contribute exactly 0 and every attended
@@ -123,6 +148,7 @@ def _lm_prefill(params, k_pages, v_pages, tokens, start, length, table):
     import jax.numpy as jnp
     emb, w_k, w_v, w_out = (params["emb"], params["w_k"],
                             params["w_v"], params["w_out"])
+    k_pages, v_pages = cache["k"], cache["v"]
     bs = k_pages.shape[1]
     dim = emb.shape[1]
     mb = table.shape[0]
@@ -142,10 +168,10 @@ def _lm_prefill(params, k_pages, v_pages, tokens, start, length, table):
     scores = jnp.where(tpos < start + length, scores, _MASKED)
     ctx = jax.nn.softmax(scores) @ vs
     next_id = jnp.argmax(ctx @ w_out).astype(jnp.int32)
-    return next_id, k_pages, v_pages
+    return next_id, {"k": k_pages, "v": v_pages}, {}
 
 
-def _lm_step(params, k_pages, v_pages, token_ids, positions, tables, active):
+def _lm_step(params, cache, token_ids, positions, tables, active):
     """Built-in decode-step body (fixed batch shape, one program total).
 
     ``token_ids (B,) i32`` last emitted token per row; ``positions (B,)
@@ -159,6 +185,7 @@ def _lm_step(params, k_pages, v_pages, token_ids, positions, tables, active):
     import jax.numpy as jnp
     emb, w_k, w_v, w_out = (params["emb"], params["w_k"],
                             params["w_v"], params["w_out"])
+    k_pages, v_pages = cache["k"], cache["v"]
     bs = k_pages.shape[1]
     dim = emb.shape[1]
     b, mb = tables.shape
@@ -176,7 +203,7 @@ def _lm_step(params, k_pages, v_pages, token_ids, positions, tables, active):
     scores = jnp.where(tpos <= positions[:, None], scores, _MASKED)
     ctx = jnp.einsum("bt,btd->bd", jax.nn.softmax(scores, axis=-1), vs)
     next_ids = jnp.argmax(ctx @ w_out, axis=-1).astype(jnp.int32)
-    return next_ids, k_pages, v_pages
+    return next_ids, {"k": k_pages, "v": v_pages}, {}
 
 
 class DecodeStream:
@@ -293,22 +320,27 @@ class DecodeEngine:
     default_deadline_ms : float or None
         Deadline applied when ``submit`` passes none
         (``MXNET_SERVING_DECODE_DEADLINE_MS``; unset/0 = no deadline).
-    page_shape : callable or None
-        ``page_shape(num_blocks, block_size)`` returns the full shape of
-        each of the K and V pools: the model states the layout, the
-        engine builds, places, donates and AOT-describes whatever it is.
-        Default ``(num_blocks, block_size, model_dim)``, the built-in
-        LM's. The transformer family is layer-major:
-        ``(num_layers, num_blocks, block_size, d_model)``.
+    cache_spec : callable or None
+        ``cache_spec(num_blocks, block_size)`` returns the cache as a
+        pytree of ``jax.ShapeDtypeStruct``, one leaf per pool: the model
+        states shapes and dtypes, the engine builds, places, donates and
+        AOT-describes whatever it is and hands it to the bodies whole.
+        Default: the built-in LM's twin float32 pools ``{"k", "v"}`` of
+        ``(num_blocks, block_size, model_dim)``. The transformer family
+        is layer-major, ``(num_layers, num_blocks, block_size,
+        d_model)``; the latent-attention family holds one bfloat16 pool
+        of latent rows, ``(num_layers, num_blocks, block_size, 640)``.
     prefill_chunk : int or None
         Chunked-prefill piece size
         (``MXNET_SERVING_DECODE_PREFILL_CHUNK``; 0 disables). Resolved
         DOWN to a prefill bucket so chunk programs reuse the family.
     mesh / kv_shard_axis : jax.sharding.Mesh or None / str
-        When given, K/V pools are placed with
+        When given, every pool is placed with the sharding its leaf
+        of ``cache_spec`` carries, else with
         :func:`~.kvcache.page_sharding` (trailing model dim sharded
         over ``kv_shard_axis`` when divisible — heads, for the
-        transformer layout) and params are replicated on the mesh.
+        transformer layout), and params are replicated on the mesh.
+        Params keep the dtype they arrive in.
 
     All env vars are read once here — never per step (zero-overhead
     contract). ``warmup=True`` AOT-compiles the full program family at
@@ -319,7 +351,7 @@ class DecodeEngine:
                  block_size=None, num_blocks=None, batch_size=None,
                  max_seq_len=None, prefill_buckets=None,
                  default_deadline_ms=_MISSING, default_max_new=None,
-                 prefill_fn=None, step_fn=None, page_shape=None,
+                 prefill_fn=None, step_fn=None, cache_spec=None,
                  prefill_chunk=None, mesh=None, kv_shard_axis="tp",
                  warmup=True, autostart=True):
         import jax
@@ -368,38 +400,46 @@ class DecodeEngine:
 
         self._kv = PagedKVCache(num_blocks, block_size)
         self._mb = self._kv.blocks_for(self.max_seq_len)  # table width
-        if page_shape is None:
+        if cache_spec is None:
             dim = int(params["emb"].shape[1]) if "emb" in params else int(
                 next(iter(params.values())).shape[-1])
-            shape = (self._kv.num_blocks, self._kv.block_size, dim)
-        else:
-            shape = page_shape(self._kv.num_blocks, self._kv.block_size)
+            cache_spec = _lm_cache_spec(dim)
+        spec = cache_spec(self._kv.num_blocks, self._kv.block_size)
         self._params = jax.device_put(
             jax.tree_util.tree_map(jnp.asarray, params))
-        self._k_pages = jnp.zeros(tuple(int(d) for d in shape),
-                                  jnp.float32)
-        self._v_pages = jnp.zeros_like(self._k_pages)
-        # tp-shardable KV pages: place the pools (and replicate params)
-        # on the mesh; the trailing model dim shards across kv_shard_axis
-        # when divisible (kvcache.page_sharding), so multi-head K/V —
-        # heads folded into the trailing dim — shards by head, whatever
-        # axes the model's page_shape puts in front of it.
-        self._page_sharding = None
+        # tp-shardable pools: a leaf that carries its own sharding keeps
+        # it (a latent row has no head axis to shard); any other gets
+        # kvcache.page_sharding — the trailing model dim over
+        # kv_shard_axis when divisible, so multi-head K/V, heads folded
+        # into the trailing dim, shards by head whatever axes the model
+        # puts in front of it. Params are replicated on the mesh.
         self._kv_shard_axis = str(kv_shard_axis)
+        self._meshed = mesh is not None
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
             from .kvcache import page_sharding
-            self._page_sharding = page_sharding(
-                mesh, self._k_pages.shape, kv_shard_axis)
+            spec = jax.tree_util.tree_map(
+                lambda p: jax.ShapeDtypeStruct(
+                    p.shape, p.dtype,
+                    sharding=p.sharding or page_sharding(
+                        mesh, p.shape, kv_shard_axis)), spec)
             self._params = jax.device_put(
                 self._params, NamedSharding(mesh, PartitionSpec()))
-            self._k_pages = jax.device_put(self._k_pages,
-                                           self._page_sharding)
-            self._v_pages = jax.device_put(self._v_pages,
-                                           self._page_sharding)
-        # pages are consumed and replaced every call — donate them back
+        self._cache_spec = spec
+        self._kv.pool_bytes = sum(
+            math.prod(p.shape) * jnp.dtype(p.dtype).itemsize
+            for p in jax.tree_util.tree_leaves(spec))
+        # the pools are born on the device in their own dtype and
+        # sharding: one program, no host copy, no float32 twin
+        shardings = jax.tree_util.tree_map(lambda p: p.sharding, spec) \
+            if self._meshed else None
+        self._cache = jax.jit(
+            lambda: jax.tree_util.tree_map(
+                lambda p: jnp.zeros(p.shape, p.dtype), spec),
+            out_shardings=shardings)()
+        # the cache is consumed and replaced every call — donate it back
         # to XLA where the backend supports it (not host CPU)
-        donate = (1, 2) if _donate_supported() else ()
+        donate = (1,) if _donate_supported() else ()
         self._prefill_b = ProgramBuilder(
             prefill_fn or _lm_prefill, site="decode.prefill.%s" % name,
             donate_argnums=donate)
@@ -415,6 +455,8 @@ class DecodeEngine:
         self._counters = {"submitted": 0, "served": 0, "shed": 0,
                           "failed": 0, "tokens": 0, "prefills": 0,
                           "prefill_chunks": 0, "steps": 0, "cache_oom": 0}
+        self._model = {}                # the bodies' aux, summed
+        self._device_get = jax.device_get
         self._lat_step = "decode.%s.step" % name
         self._lat_ttft = "decode.%s.ttft" % name
         self._lat_tok = "decode.%s.intertoken" % name
@@ -435,18 +477,14 @@ class DecodeEngine:
         import numpy as np
         i32 = np.int32
         sd = jax.ShapeDtypeStruct
-        if self._page_sharding is not None:
-            pages = sd(self._k_pages.shape, self._k_pages.dtype,
-                       sharding=self._page_sharding)
-        else:
-            pages = sd(self._k_pages.shape, self._k_pages.dtype)
+        cache = self._cache_spec
         for bucket in self.prefill_buckets:
             self._prefill_b.aot_info(
-                self._params, pages, pages, sd((bucket,), i32),
+                self._params, cache, sd((bucket,), i32),
                 sd((), i32), sd((), i32), sd((self._mb,), i32), mode="aot")
         b, mb = self.batch_size, self._mb
         self._step_b.aot_info(
-            self._params, pages, pages, sd((b,), i32), sd((b,), i32),
+            self._params, cache, sd((b,), i32), sd((b,), i32),
             sd((b, mb), i32), sd((b,), np.bool_), mode="aot")
 
     def program_counts(self):
@@ -465,7 +503,7 @@ class DecodeEngine:
         ``program_counts`` asserts."""
         from ..analysis.program_audit import CommPlan
         allowed = ()
-        if self._page_sharding is not None:
+        if self._meshed:
             allowed = (("all-reduce", self._kv_shard_axis, None),
                        ("all-gather", self._kv_shard_axis, None))
         return {
@@ -695,6 +733,7 @@ class DecodeEngine:
         table[:len(own)] = own
         start = 0
         tok = None
+        auxes = []                      # every piece's, read with the id
         for pi, piece in enumerate(pieces):
             last = pi == len(pieces) - 1
             if pi and stream.deadline is not None \
@@ -710,13 +749,15 @@ class DecodeEngine:
                                 kind="prefill", rid=stream.rid)
             try:
                 with _prof.span("mx.decode.prefill.dispatch", bucket=bucket):
-                    next_id, self._k_pages, self._v_pages = self._prefill_b(
-                        self._params, self._k_pages, self._v_pages, toks,
+                    next_id, self._cache, aux = self._prefill_b(
+                        self._params, self._cache, toks,
                         _np.int32(start), _np.int32(len(piece)), table)
+                auxes.append(aux)
                 if last:
                     with _prof.span("mx.decode.prefill.readback",
                                     bucket=bucket):
-                        tok = int(_np.asarray(next_id))  # tpulint: allow-host-sync sampled token feeds the next step and the reply stream; decode cannot proceed without it
+                        next_id, auxes = self._device_get((next_id, auxes))  # tpulint: allow-host-sync sampled token feeds the next step and the reply stream; decode cannot proceed without it
+                        tok = int(next_id)
             except Exception as e:
                 self._evict(stream, e if isinstance(e, DeadlineExceeded)
                             else RuntimeError(
@@ -735,9 +776,17 @@ class DecodeEngine:
             self._counters["tokens"] += 1
             if len(pieces) > 1:
                 self._counters["prefill_chunks"] += len(pieces)
+            for aux in auxes:
+                self._count_aux_locked(aux)
         _prof.record_decode_event(prefills=1, tokens=1)
         stream._emit(tok)
         self._maybe_retire(stream, tok)
+
+    def _count_aux_locked(self, aux):
+        """Sum one call's ``aux`` (host integers by now) into the
+        ``stats()["model"]`` counters. Runs under ``_cv``."""
+        for k, v in aux.items():
+            self._model[k] = self._model.get(k, 0) + int(v)
 
     def _maybe_retire(self, stream, last_tok):
         """Retire on EOS or token budget; returns True when retired."""
@@ -798,11 +847,11 @@ class DecodeEngine:
             t0 = time.monotonic()
             try:
                 with _prof.span("mx.decode.step.dispatch"):
-                    next_ids, self._k_pages, self._v_pages = self._step_b(
-                        self._params, self._k_pages, self._v_pages,
+                    next_ids, self._cache, aux = self._step_b(
+                        self._params, self._cache,
                         token_ids, positions, tables, mask)
                 with _prof.span("mx.decode.step.readback"):
-                    ids = _np.asarray(next_ids)  # tpulint: allow-host-sync sampled tokens feed the next step and the reply streams; decode cannot proceed without them
+                    ids, aux = self._device_get((next_ids, aux))  # tpulint: allow-host-sync sampled tokens feed the next step and the reply streams; decode cannot proceed without them
             except Exception as e:
                 # step state is unknown after a failed dispatch: fail the
                 # whole active set (chaos tests drive this via decode.step)
@@ -818,6 +867,7 @@ class DecodeEngine:
                 with self._cv:
                     self._counters["steps"] += 1
                     self._counters["tokens"] += len(active)
+                    self._count_aux_locked(aux)
                 _prof.record_decode_event(steps=1, tokens=len(active),
                                           slot_steps=len(active),
                                           slot_capacity=self.batch_size)
@@ -837,11 +887,13 @@ class DecodeEngine:
 
     # ------------------------------------------------------------------
     def stats(self):
-        """Counters + cache occupancy + program family sizes."""
+        """Counters + cache occupancy + program family sizes; ``model``
+        holds the bodies' ``aux`` summed over every call so far."""
         with self._cv:
             out = dict(self._counters)
             out["waiting"] = len(self._waiting)
             out["active"] = sum(1 for s in self._slots if s is not None)
+            out["model"] = dict(self._model)
         out["kv"] = self._kv.stats()
         pf, st = self.program_counts()
         out["programs"] = {"prefill": pf, "step": st}

@@ -5,7 +5,8 @@ The naive KV cache for autoregressive decode reserves
 because most sequences finish early and the batch is rarely full. This
 module is the vLLM-style alternative: the cache is a fixed pool of
 fixed-size **token blocks** (``(num_blocks, block_size, dim)`` per
-layer-side), a sequence owns a **block table** (list of block ids, one
+layer-side; what a row holds — K and V, or one latent row — is the
+model's ``cache_spec`` to say), a sequence owns a **block table** (list of block ids, one
 per ``block_size`` tokens of its history), and blocks come from a
 free-list allocator. HBM then scales with *live tokens*, not with the
 worst case, and the accounting counters below prove it.
@@ -45,7 +46,11 @@ NULL_BLOCK = 0
 def page_sharding(mesh, page_shape, axis_name="tp"):
     """NamedSharding for a KV page pool on ``mesh``: shard the trailing
     model dim over ``axis_name`` when the axis exists, is wider than one
-    device, and divides the dim — else fully replicated.
+    device, and divides the dim — else fully replicated. This is the
+    DEFAULT for a pool whose trailing dim is heads folded together; a
+    pool with no head axis (a latent row,
+    :class:`~..models.moe_mla.MoEMLADecodeModel`) states its own
+    sharding on its ``cache_spec`` leaf and never comes here.
 
     The transformer page layout folds heads into the trailing
     ``d_model`` dim (``(num_layers, num_blocks, block_size, d_model)``),
@@ -96,6 +101,10 @@ class PagedKVCache:
         self._frees = 0
         self._alloc_failures = 0
         self._high_water = 0        # max blocks simultaneously live
+        # bytes of the device pools these blocks index, all of them
+        # together: whoever builds the pools (DecodeEngine, from the
+        # model's cache_spec) writes it here; the manager only reports it
+        self.pool_bytes = 0
 
     # -- capacity queries ------------------------------------------------
     @property
@@ -217,5 +226,6 @@ class PagedKVCache:
                 "blocks_high_water": self._high_water,
                 "sequences": len(self._tables),
                 "tokens_live": sum(self._lengths.values()),
+                "pool_bytes": int(self.pool_bytes),
                 "allocs": self._allocs, "frees": self._frees,
                 "alloc_failures": self._alloc_failures}

@@ -338,10 +338,9 @@ class TestTransformerDecode:
         eng = _tf_engine(model, "tfg%d" % num_layers, warmup=False,
                          autostart=False)
         sd = jax.ShapeDtypeStruct
-        pages = sd(eng._k_pages.shape, eng._k_pages.dtype)
         b, mb, bs = eng.batch_size, eng._mb, eng._kv.block_size
         i32 = np.int32
-        # (program, its arguments after the pages, one layer's view)
+        # (program, its arguments after the cache, one layer's view)
         programs = [(model.step_fn, (sd((b,), i32), sd((b,), i32),
                                      sd((b, mb), i32), sd((b,), np.bool_)),
                      b * mb * bs * model.cfg.d_model)]
@@ -351,19 +350,23 @@ class TestTransformerDecode:
                      for bucket in eng.prefill_buckets]
         for fn, args, one_layer in programs:
             sizes = _gather_out_sizes(
-                jax.make_jaxpr(fn)(eng._params, pages, pages, *args).jaxpr)
+                jax.make_jaxpr(fn)(eng._params, eng._cache_spec,
+                                   *args).jaxpr)
             assert sizes.count(one_layer) == 2 * num_layers     # K and V
             assert max(sizes) == one_layer
 
     def test_pool_is_layer_major_and_layers_write_their_own_pages(self):
-        """The model states the pool's shape and the engine builds it:
-        (num_layers, num_blocks, block_size, d_model). After serving,
+        """The model states the pools (``cache_spec``) and the engine
+        builds them: (num_layers, num_blocks, block_size, d_model). After serving,
         layer l's K/V rows sit under pages[l] at the positions the
         sequences' tables name, the same slots for every layer, and
         nowhere else (block 0 takes the padding writes)."""
         model = _tf_model(num_layers=3)
         eng = _tf_engine(model, "tflm", num_blocks=32, block_size=4)
-        assert eng._k_pages.shape == eng._v_pages.shape == (3, 32, 4, 32)
+        assert sorted(eng._cache) == ["k", "v"]
+        assert eng._cache["k"].shape == eng._cache["v"].shape \
+            == (3, 32, 4, 32)
+        assert eng.stats()["kv"]["pool_bytes"] == 2 * 3 * 32 * 4 * 32 * 4
         outs = [eng.generate(p, max_new_tokens=m)
                 for p, m in (([3, 1, 4], 2), ([1, 5, 9, 2, 6, 5, 3, 5, 8], 4))]
         assert [len(o) for o in outs] == [2, 4]
@@ -371,7 +374,8 @@ class TestTransformerDecode:
         # the longer sequence holds positions 0..11 (the last token
         # emitted is never written), the shorter one 0..3, in a block
         # that the allocator may have handed out again
-        for pages in (np.asarray(eng._k_pages), np.asarray(eng._v_pages)):
+        for pages in (np.asarray(eng._cache["k"]),
+                      np.asarray(eng._cache["v"])):
             written = np.abs(pages[:, 1:]).sum(axis=-1) > 0    # (L, N-1, bs)
             assert 12 <= written[0].sum() <= 16
             for l in range(3):

@@ -150,7 +150,7 @@ def test_chip_smoke_refuses_the_cpu():
 
 
 def test_chip_smoke_rehearsal_runs_every_phase(tmp_path):
-    """--rehearse drives the same four phases at tiny sizes with the kernels
+    """--rehearse drives the same five phases at tiny sizes with the kernels
     interpreted, keeps its compile cache where JAX_COMPILATION_CACHE_DIR
     says, and still prints no result line."""
     import json
@@ -164,7 +164,7 @@ def test_chip_smoke_rehearsal_runs_every_phase(tmp_path):
              if l.startswith("{")]
     assert lines[0]["compile_cache"] == str(tmp_path)
     assert [l["phase"] for l in lines if "phase" in l] == [
-        "start", "train", "serve", "decode", "kernels"]
+        "start", "train", "serve", "decode", "decode_moe", "kernels"]
     assert lines[-1] == {"rehearsal": "passed", "platform": "cpu",
                          "count": 1}
     for l in lines[1:-1]:

@@ -158,3 +158,43 @@ def test_grid_compiles_at_16k(one_chip):
     q = jax.ShapeDtypeStruct((1, 8, 16384, 128), jnp.bfloat16,
                              sharding=one_chip)
     _compile(_flash_fwd("grid"), q, q, q)
+
+
+def test_latent_prefill_attention_compiles_at_published_widths(one_chip):
+    """models/moe_mla.py's prefill attention at openPangu-Ultra-MoE's head
+    widths: 128 heads of 192 (keys) / 128 (values), zero-padded to 256
+    lanes, a 1,024-token chunk against the 4,096 positions of a sequence's
+    table (keys and values expanded from its latent rows), through the
+    grid-variant offset kernel."""
+    from mxnet_tpu.models.moe_mla import MoEMLAConfig, _attend_expanded
+    cfg = MoEMLAConfig(
+        hidden_size=7680, num_hidden_layers=1, first_k_dense_replace=1,
+        num_attention_heads=128, q_lora_rank=1536, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        intermediate_size=18432, moe_intermediate_size=2048,
+        n_routed_experts=256, n_shared_experts=1, num_experts_per_tok=8,
+        routed_scaling_factor=2.5, rms_norm_eps=1e-5, rope_theta=25.6e6,
+        vocab_size=19200)
+    sd = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16,      # noqa: E731
+                                         sharding=one_chip)
+    start = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    text = _compile(
+        lambda w, q, rows, s: _attend_expanded(cfg, {"wkv_b": w}, q, rows, s,
+                                               True, False),
+        sd(512, 128 * 256), sd(1024, 128, 192), sd(4096, 640), start)
+    assert "mx_flash_fwd_offs_grid" in text
+
+
+def test_grouped_expert_product_compiles_at_published_widths(one_chip):
+    """parallel/moe.py::routed_experts for 16 held experts of 7680 x 2048
+    at a decode step's 256 tokens x top-8: the grouped product reaches the
+    TPU's own ragged kernel (no dense [T, E, C] dispatch)."""
+    from mxnet_tpu.parallel.moe import routed_experts
+    sd = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16,      # noqa: E731
+                                         sharding=one_chip)
+    params = {"router": sd(7680, 256), "experts_gate": sd(16, 7680, 2048),
+              "experts_up": sd(16, 7680, 2048),
+              "experts_down": sd(16, 2048, 7680)}
+    text = _compile(lambda p, x: routed_experts(
+        p, x, held=(0, 16), top_k=8, scale=2.5), params, sd(256, 7680))
+    assert "ragged" in text.lower()

@@ -203,6 +203,52 @@ def main():
     tf_eng.stop()
     ref_eng.stop()
 
+    # --- the latent-attention expert family (ISSUE 28) ------------------
+    # models/moe_mla.py through the SAME engine and cache seam: one latent
+    # pool (kept whole on the mesh: a latent row has no head axis), the
+    # flash tier engaged for prefill, the absorbed step, four of eight
+    # experts held; the mesh-placed flash engine must stream the tokens of
+    # the lax-tier solo engine, and the model's counters must add up.
+    from mxnet_tpu.models.moe_mla import MoEMLAConfig, MoEMLADecodeModel
+    mcfg = MoEMLAConfig(
+        hidden_size=64, num_hidden_layers=3, first_k_dense_replace=1,
+        num_attention_heads=4, q_lora_rank=32, kv_lora_rank=16,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        intermediate_size=160, moe_intermediate_size=48, n_routed_experts=8,
+        n_shared_experts=1, num_experts_per_tok=2, routed_scaling_factor=2.5,
+        rms_norm_eps=1e-5, rope_theta=25.6e6, vocab_size=64,
+        experts_held=(2, 4), initializer_range=0.2, block_k=16,
+        step_row_block=2, step_col_blocks=2)
+    moe_flash = MoEMLADecodeModel(mcfg, seed=0, dtype="float32",
+                                  flash="interpret", mesh=mesh)
+    assert moe_flash.flash_engaged
+    moe_lax = MoEMLADecodeModel(mcfg, params=moe_flash.params, flash="off")
+    moe_eng = DecodeEngine(name="moe", num_blocks=64, batch_size=3,
+                           max_seq_len=64, prefill_buckets=(8, 16),
+                           prefill_chunk=8, mesh=mesh,
+                           **moe_flash.engine_kwargs())
+    moe_ref = DecodeEngine(name="moe_ref", num_blocks=64, batch_size=3,
+                           max_seq_len=64, prefill_buckets=(8, 16),
+                           prefill_chunk=8, **moe_lax.engine_kwargs())
+    sts = [moe_eng.submit(p, max_new_tokens=6) for p in tf_prompts]
+    moe_outs = [s.result_wait(180.0) for s in sts]
+    for p, got in zip(tf_prompts, moe_outs):
+        want = moe_ref.generate(p, max_new_tokens=6, timeout=180.0)
+        assert got == want, \
+            "moe_mla flash-tier mesh engine diverged from lax solo: %r -> " \
+            "%r != %r" % (p, got, want)
+    st_moe = moe_eng.stats()
+    assert st_moe["programs"] == {"prefill": 2, "step": 1}, st_moe
+    assert st_moe["kv"]["blocks_live"] == 0, st_moe["kv"]
+    mm = st_moe["model"]
+    assert mm["moe_layer_steps"] == st_moe["steps"] * mcfg.num_expert_layers
+    assert 0 < mm["moe_assignments"] <= (
+        (st_moe["tokens"] - st_moe["prefills"]) * mcfg.num_expert_layers
+        * mcfg.num_experts_per_tok), mm
+    assert st_moe["kv"]["pool_bytes"] == 3 * 64 * 16 * 128 * 4, st_moe["kv"]
+    moe_eng.stop()
+    moe_ref.stop()
+
     summary = {
         "clients": reports,
         "transformer": {"flash_engaged": True,
@@ -210,6 +256,10 @@ def main():
                         "programs": st_tf["programs"],
                         "mesh": {"dp": 2, "tp": 4},
                         "sequences": len(tf_prompts)},
+        "moe_mla": {"flash_engaged": True, "programs": st_moe["programs"],
+                    "prefill_chunks": st_moe["prefill_chunks"],
+                    "model": mm, "pool_bytes": st_moe["kv"]["pool_bytes"],
+                    "experts_held": list(mcfg.experts_held)},
         "frontdoor": {k: v for k, v in fs.items() if v},
         "lm": {"counters": {k: v for k, v in st_lm.items()
                             if isinstance(v, int) and v},
