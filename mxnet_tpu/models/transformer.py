@@ -375,58 +375,165 @@ def transformer_decode_prefill(params, cfg, cache, tokens,
             {"k": k_pages, "v": v_pages}, {})
 
 
+# The decode step's walk over the live positions: how many rows a block
+# of the walk holds and how many positions one piece of it covers. The
+# trade is walked-but-masked positions (larger blocks and pieces: a block
+# walks as far as its LONGEST row, rounded up to a piece) against loop
+# iterations (smaller ones: layers x row blocks x pieces, each with a
+# fixed cost). Settled on the chip at 64 rows over 64 blocks of 16
+# (PERF.md, PR 30): 8 x 128 reads 7.2 ms a step, 8 x 32 and 2 x 128 9.5,
+# 32 x 256 14.
+_WALK_ROWS = 8
+_WALK_SPAN = 128
+
+
+def _walk_sizes(B, mb, bs):
+    """``(rows per block, table blocks per piece)`` of the step's walk for
+    ``B`` rows over tables of ``mb`` blocks of ``bs`` positions. One block
+    over all rows where ``B`` does not divide into several; one piece over
+    the whole table where ``mb`` does not."""
+    rb = _WALK_ROWS if B > _WALK_ROWS and B % _WALK_ROWS == 0 else B
+    cb = max(1, _WALK_SPAN // bs)
+    return rb, (mb if mb % cb else cb)
+
+
+def _walk_plan(positions, tables, bs):
+    """What every layer's walk shares: the rows sorted by length (and the
+    way back) and split into blocks, each block's tables and positions,
+    and the number of pieces it walks: as far as its longest row reaches
+    and no further. With it, the positions one layer's walk covers."""
+    B, mb = tables.shape
+    rb, cb = _walk_sizes(B, mb, bs)
+    order = jnp.argsort(positions)
+    pos_s = jnp.take(positions, order).reshape(B // rb, rb)
+    tables_s = jnp.take(tables, order, axis=0).reshape(B // rb, rb, mb)
+    pieces = jnp.max(pos_s, axis=1) // (cb * bs) + 1
+    return ((order, jnp.argsort(order), pos_s, tables_s, pieces, cb),
+            jnp.sum(pieces) * (rb * cb * bs))
+
+
+def _live_attention(q, k_pages, v_pages, l, plan, num_heads):
+    """One layer's decode attention over the LIVE positions only, heads
+    kept in the lanes. ``q`` ``(B, d_model)``; pools ``(L, blocks, bs,
+    d_model)`` read at layer ``l`` (``pages[l, tab]``, never
+    ``pages[l][tab]``: the second copies the layer's pool first).
+
+    A block of rows walks its tables a piece at a time (`_walk_plan`: a
+    loop with a traced trip count, static shapes, one program) and folds
+    each piece in with a running softmax in float32. A gathered piece stays
+    ``(rows, span, d_model)``. The query is laid out block-diagonally,
+    ``(rows, d_model, heads)`` with head ``h``'s 64 numbers in column ``h``,
+    so the scores are ``piece @ q_bd`` and the context ``p^T @ piece``, of
+    which head ``h`` keeps its own lanes: no positions-sized array ever
+    has ``(heads, head_dim)`` as its minor pair (the (8,128) tile pads
+    that pair from 768 lanes to 2,048). Both contractions carry
+    ``HIGHEST`` precision: no key, value, score or weight is rounded on
+    the way through the matrix unit.
+
+    A masked position contributes exact 0 and a piece past a row's end
+    leaves that row's carry bit-for-bit (``alpha = 1``, ``p = 0``), so a
+    row's result does not depend on which rows share its block."""
+    order, inverse, pos_s, tables_s, pieces, cb = plan
+    B, D = q.shape
+    nb, rb, _ = tables_s.shape
+    span = cb * k_pages.shape[2]
+    dh = D // num_heads
+    sm = 1.0 / _np.sqrt(dh)
+    hi = lax.Precision.HIGHEST
+    # ind[d, h] = 1 where lane d belongs to head h
+    ind = (jnp.arange(D)[:, None] // dh
+           == jnp.arange(num_heads)[None, :]).astype(jnp.float32)
+
+    def rows_block(args):
+        q_b, tables_b, pos_b, n = args          # (rb, D), (rb, mb), (rb,), ()
+        q_bd = q_b[:, :, None] * ind            # (rb, D, H), block-diagonal
+
+        def piece(j, carry):
+            m, den, acc = carry                 # (rb, H), (rb, H), (rb, H, D)
+            tab = lax.dynamic_slice_in_dim(tables_b, j * cb, cb, axis=1)
+            kp = k_pages[l, tab].reshape(rb, span, D)
+            vp = v_pages[l, tab].reshape(rb, span, D)
+            s = jnp.einsum("bsd,bdh->bsh", kp, q_bd, precision=hi) * sm
+            tpos = j * span + jnp.arange(span, dtype=jnp.int32)
+            s = jnp.where(tpos[None, :, None] <= pos_b[:, None, None], s,
+                          _NEG)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1))
+            p = jnp.exp(s - m_new[:, None, :])
+            alpha = jnp.exp(m - m_new)
+            return (m_new, den * alpha + jnp.sum(p, axis=1),
+                    acc * alpha[..., None]
+                    + jnp.einsum("bsh,bsd->bhd", p, vp, precision=hi))
+
+        # position 0 is live for every row, so the first piece leaves a
+        # finite running maximum and a masked piece after it adds exact 0
+        carry = (jnp.full((rb, num_heads), _NEG, jnp.float32),
+                 jnp.zeros((rb, num_heads), jnp.float32),
+                 jnp.zeros((rb, num_heads, D), jnp.float32))
+        _, den, acc = lax.fori_loop(0, n, piece, carry)
+        # head h keeps its own lanes of row h of the (H, D) accumulator
+        return jnp.sum(acc / den[..., None] * ind.T, axis=1)
+
+    q_s = jnp.take(q, order, axis=0).reshape(nb, rb, D)
+    ctx = lax.map(rows_block, (q_s, tables_s, pos_s, pieces))
+    return jnp.take(ctx.reshape(B, D), inverse, axis=0)
+
+
 @jax.named_scope("decode.step")      # the trace's device-side name
 def transformer_decode_step(params, cfg, cache, token_ids,
                             positions, tables, active):
     """Fixed-shape batched decode step: one token per active row.
 
     Matches the DecodeEngine step seam ``(params, cache, token_ids,
-    positions, tables, active) -> (next_ids, cache, aux)``. Every per-row contraction
-    runs only over that row's own gathered blocks (einsum batch dim),
-    so rows cannot observe each other — batched decode stays
-    bit-identical to solo decode, layer count notwithstanding. The lax
-    tier is deliberate here: a 1-token query has no MXU win and rows
-    carry different lengths, which cannot share the flash kernels'
-    scalar-prefetch offs — prefill is where the flash tier earns its
-    keep."""
+    positions, tables, active) -> (next_ids, cache, aux)``. Attention reads
+    and contracts only the LIVE positions of each row's table
+    (`_live_attention`): the rows are sorted by length, a block of rows
+    walks its tables a piece at a time as far as its longest row reaches,
+    and the 768-wide row of the pool stays in the lane dimension
+    throughout. One program whatever the lengths (the walk's trip counts
+    are traced values). A row contracts only over its own gathered blocks
+    and a walked-but-masked position adds exact 0, so rows cannot observe
+    each other: batched decode stays bit-identical to solo decode. The
+    model's products run at the default matmul precision; the attention's
+    own contractions round nothing (float32, ``HIGHEST`` where they use
+    the matrix unit). Prefill is where the flash tier earns its keep.
+
+    ``aux`` counts what the walk did, summed into ``stats()["model"]``:
+    ``kv_live_tokens`` (cached tokens the active rows attended over,
+    ``positions + 1`` each) and ``kv_walked_tokens`` (positions gathered
+    and contracted: rows x span x pieces over the row blocks, one layer's
+    count); their ratio is the walk's efficiency."""
     k_pages, v_pages = cache["k"], cache["v"]
-    B, mb = tables.shape
     bs = k_pages.shape[2]
     L = cfg.num_layers
-    H, Dh = cfg.num_heads, cfg.d_model // cfg.num_heads
-    T = mb * bs
-    sm = 1.0 / _np.sqrt(Dh)
     x = params["embed"][token_ids].astype(cfg.dtype)
     x = x + params["pos_embed"][jnp.clip(positions, 0, cfg.max_len - 1)] \
         .astype(cfg.dtype)
     blk = jnp.take_along_axis(tables, (positions // bs)[:, None], axis=1)
     blk = jnp.where(active, blk[:, 0], 0)
     slot = positions % bs
-    tpos = jnp.arange(T, dtype=jnp.int32)[None, None, :]
+    plan, walked = _walk_plan(positions, tables, bs)
     lp_all = params["layers"]
     for l in range(L):
         with jax.named_scope("layer"):
             lp = {k: v[l] for k, v in lp_all.items()}
             h = _layer_norm(x, lp["ln1_scale"], lp["ln1_bias"])
-            q = (h @ lp["wq"]).reshape(B, H, Dh)
+            q = h @ lp["wq"]
             kk = h @ lp["wk"]
             vv = h @ lp["wv"]
             k_pages = k_pages.at[l, blk, slot].set(kk)
             v_pages = v_pages.at[l, blk, slot].set(vv)
-            ks = k_pages[l, tables].reshape(B, T, H, Dh)
-            vs = v_pages[l, tables].reshape(B, T, H, Dh)
-            scores = jnp.einsum("bhd,bthd->bht", q, ks) * sm
-            scores = jnp.where(tpos <= positions[:, None, None], scores, _NEG)
-            w = jax.nn.softmax(scores, axis=-1)
-            ctx = jnp.einsum("bht,bthd->bhd", w, vs).reshape(B, cfg.d_model)
+            ctx = _live_attention(q, k_pages, v_pages, l, plan,
+                                  cfg.num_heads)
             x = x + ctx @ lp["wo"]
             h = _layer_norm(x, lp["ln2_scale"], lp["ln2_bias"])
             x = x + (jax.nn.gelu(h @ lp["w1"] + lp["b1"]) @ lp["w2"]
                      + lp["b2"])
     x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
     logits = x @ params["embed"].T.astype(cfg.dtype)
+    aux = {"kv_live_tokens": jnp.sum(jnp.where(active, positions + 1, 0)),
+           "kv_walked_tokens": walked}
     return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
-            {"k": k_pages, "v": v_pages}, {})
+            {"k": k_pages, "v": v_pages}, aux)
 
 
 class TransformerDecodeModel:
@@ -437,8 +544,8 @@ class TransformerDecodeModel:
     ...     num_layers=2, num_heads=4, d_model=64, max_len=128))
     >>> eng = DecodeEngine(**model.engine_kwargs(), max_seq_len=128)
 
-    ``flash`` picks the prefill attention tier (the step body is always
-    lax — see transformer_decode_step): None reads
+    ``flash`` picks the prefill attention tier (the step body runs no
+    kernel: see `transformer_decode_step`): None reads
     ``MXNET_SERVING_DECODE_FLASH`` (auto | 1/on | 0/off | interpret,
     the `resolve_kernel_tier` vocabulary). Params default to
     `init_transformer` from a seeded key, so every process (engine,

@@ -238,11 +238,12 @@ class TestDecodeEngine:
 # transformer decode body (models/transformer.py, ISSUE 19)
 # ---------------------------------------------------------------------------
 
-def _tf_model(flash="off", num_layers=2):
+def _tf_model(flash="off", num_layers=2, max_len=64):
     from mxnet_tpu.models.transformer import (TransformerConfig,
                                               TransformerDecodeModel)
     cfg = TransformerConfig(vocab_size=64, num_layers=num_layers,
-                            num_heads=4, d_model=32, max_len=64, block_k=16)
+                            num_heads=4, d_model=32, max_len=max_len,
+                            block_k=16)
     return TransformerDecodeModel(cfg, flash=flash)
 
 
@@ -254,16 +255,18 @@ def _tf_engine(model, name, **kw):
     return DecodeEngine(**model.engine_kwargs(), name=name, **kw)
 
 
-def _gather_out_sizes(jaxpr):
+def _gather_out_sizes(jaxpr, operand_ndim=None):
     """Element count of every `gather` equation's output, sub-jaxprs
-    (pjit, custom calls) included."""
+    (pjit, loops, custom calls) included; with ``operand_ndim`` only of
+    the gathers that read an operand of that rank (4: a page pool)."""
     import jax
     sizes = []
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "gather":
+        if eqn.primitive.name == "gather" and operand_ndim in (
+                None, eqn.invars[0].aval.ndim):
             sizes.extend(int(np.prod(v.aval.shape)) for v in eqn.outvars)
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            sizes.extend(_gather_out_sizes(sub))
+            sizes.extend(_gather_out_sizes(sub, operand_ndim))
     return sizes
 
 
@@ -331,29 +334,246 @@ class TestTransformerDecode:
     @pytest.mark.parametrize("num_layers", [1, 2, 4])
     def test_no_gather_carries_a_layer_axis(self, num_layers):
         """Each layer reads only its own pages: traced on the engine's
-        own argument shapes, no gather of the step or of a prefill
-        bucket is larger than ONE layer's view of the block table."""
+        own argument shapes, no gather of a prefill bucket is larger than
+        ONE layer's view of the block table, and no gather of the step is
+        larger than one PIECE of the walk (rows per block x span)."""
         import jax
+        from mxnet_tpu.models.transformer import _WALK_ROWS, _walk_sizes
         model = _tf_model(num_layers=num_layers)
         eng = _tf_engine(model, "tfg%d" % num_layers, warmup=False,
                          autostart=False)
         sd = jax.ShapeDtypeStruct
         b, mb, bs = eng.batch_size, eng._mb, eng._kv.block_size
+        d_model = model.cfg.d_model
         i32 = np.int32
-        # (program, its arguments after the cache, one layer's view)
-        programs = [(model.step_fn, (sd((b,), i32), sd((b,), i32),
-                                     sd((b, mb), i32), sd((b,), np.bool_)),
-                     b * mb * bs * model.cfg.d_model)]
-        programs += [(model.prefill_fn, (sd((bucket,), i32), sd((), i32),
-                                         sd((), i32), sd((mb,), i32)),
-                      mb * bs * model.cfg.d_model)
-                     for bucket in eng.prefill_buckets]
-        for fn, args, one_layer in programs:
-            sizes = _gather_out_sizes(
-                jax.make_jaxpr(fn)(eng._params, eng._cache_spec,
-                                   *args).jaxpr)
+
+        def gathers(fn, *args, **kw):
+            return _gather_out_sizes(jax.make_jaxpr(fn)(
+                eng._params, eng._cache_spec, *args).jaxpr, **kw)
+
+        for bucket in eng.prefill_buckets:
+            sizes = gathers(model.prefill_fn, sd((bucket,), i32),
+                            sd((), i32), sd((), i32), sd((mb,), i32))
+            one_layer = mb * bs * d_model
             assert sizes.count(one_layer) == 2 * num_layers     # K and V
             assert max(sizes) == one_layer
+        # the step at the engine's shapes, and at shapes that split both
+        # ways (several row blocks, several pieces a table)
+        for rows, blocks in ((b, mb), (4 * _WALK_ROWS, 1024 // bs)):
+            rb, cb = _walk_sizes(rows, blocks, bs)
+            if rows > b:
+                assert rb < rows and cb < blocks
+            # the reads of the pools: K and V of a layer each gather one
+            # piece inside the walk's loop body, which the jaxpr holds
+            # once a layer
+            sizes = gathers(model.step_fn, sd((rows,), i32),
+                            sd((rows,), i32), sd((rows, blocks), i32),
+                            sd((rows,), np.bool_), operand_ndim=4)
+            assert sizes == [rb * cb * bs * d_model] * (2 * num_layers)
+
+    @staticmethod
+    def _walk_case(rows, seed=0, blocks=64, bs=16):
+        """A step's arguments at ``rows`` rows over tables of ``blocks``
+        blocks: every row its own blocks of a random pool, lengths from 1
+        to the table's end, two rows inactive."""
+        import jax.numpy as jnp
+        model = _tf_model(max_len=blocks * bs)
+        cfg = model.cfg
+        rng = np.random.RandomState(seed)
+        pool = (cfg.num_layers, 1 + rows * blocks, bs, cfg.d_model)
+        cache = {k: jnp.asarray(rng.standard_normal(pool), jnp.float32)
+                 for k in ("k", "v")}
+        tables = 1 + np.arange(rows * blocks, dtype=np.int32) \
+            .reshape(rows, blocks)
+        positions = rng.randint(0, blocks * bs, rows).astype(np.int32)
+        positions[:3] = [0, blocks * bs - 1, 255]
+        active = np.ones(rows, np.bool_)
+        active[[5, rows - 2]] = False
+        positions[~active] = 0
+        tables[~active] = 0
+        tokens = rng.randint(0, cfg.vocab_size, rows).astype(np.int32)
+        return model, cache, tokens, positions, tables, active
+
+    @staticmethod
+    def _whole_table_step(params, cfg, cache, token_ids, positions, tables,
+                          active):
+        """The step as it was before the walk: every row attends over its
+        table's full ``mb * bs`` positions through a head-split view. Kept
+        here as the plain formula the walk is held against."""
+        import jax
+        import jax.numpy as jnp
+        from mxnet_tpu.models.transformer import _layer_norm
+        k_pages, v_pages = cache["k"], cache["v"]
+        B, mb = tables.shape
+        bs = k_pages.shape[2]
+        H, Dh = cfg.num_heads, cfg.d_model // cfg.num_heads
+        T = mb * bs
+        x = params["embed"][token_ids] + params["pos_embed"][positions]
+        blk = jnp.take_along_axis(tables, (positions // bs)[:, None], axis=1)
+        blk = jnp.where(active, blk[:, 0], 0)
+        slot = positions % bs
+        tpos = jnp.arange(T, dtype=jnp.int32)[None, None, :]
+        ctxs = []
+        for l in range(cfg.num_layers):
+            lp = {k: v[l] for k, v in params["layers"].items()}
+            h = _layer_norm(x, lp["ln1_scale"], lp["ln1_bias"])
+            q = (h @ lp["wq"]).reshape(B, H, Dh)
+            k_pages = k_pages.at[l, blk, slot].set(h @ lp["wk"])
+            v_pages = v_pages.at[l, blk, slot].set(h @ lp["wv"])
+            ks = k_pages[l, tables].reshape(B, T, H, Dh)
+            vs = v_pages[l, tables].reshape(B, T, H, Dh)
+            scores = jnp.einsum("bhd,bthd->bht", q, ks) / np.sqrt(Dh)
+            scores = jnp.where(tpos <= positions[:, None, None], scores,
+                               -1e30)
+            w = jax.nn.softmax(scores, axis=-1)
+            ctx = jnp.einsum("bht,bthd->bhd", w, vs).reshape(B, cfg.d_model)
+            ctxs.append(ctx)
+            x = x + ctx @ lp["wo"]
+            h = _layer_norm(x, lp["ln2_scale"], lp["ln2_bias"])
+            x = x + (jax.nn.gelu(h @ lp["w1"] + lp["b1"]) @ lp["w2"]
+                     + lp["b2"])
+        x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
+        return x @ params["embed"].T, ctxs
+
+    @pytest.mark.parametrize("row_blocks", [1, 2, 4])
+    def test_live_walk_matches_whole_table_attention(self, row_blocks):
+        """The walk over live positions against the whole-table formula
+        it replaced: greedy ids equal, a layer's contexts to float32
+        tolerance; several row blocks, several pieces a table, lengths
+        from 1 to the table's end, inactive rows included."""
+        import jax
+        from mxnet_tpu.models import transformer as tf
+        rows = tf._WALK_ROWS * row_blocks
+        model, cache, *args = self._walk_case(rows)
+        rb, cb = tf._walk_sizes(rows, args[2].shape[1], 16)
+        assert rows // rb == row_blocks and args[2].shape[1] // cb >= 2
+        ids, new_cache, _ = jax.jit(model.step_fn)(model.params, cache,
+                                                   *args)
+        logits, ctxs = jax.jit(
+            lambda *a: self._whole_table_step(a[0], model.cfg, *a[1:]))(
+            model.params, cache, *args)
+        logits = np.asarray(logits)
+        best = logits.argmax(-1)
+        ids = np.asarray(ids)
+        # greedy ids equal (a tie within float32 rounding aside: none of
+        # these rows has one)
+        top2 = np.sort(logits, axis=-1)[:, -2:]
+        assert (top2[:, 1] - top2[:, 0]).min() > 1e-4
+        assert (ids == best).all()
+        # layer contexts: the walk's own attention against the formula's
+        plan, _ = tf._walk_plan(args[1], args[2], 16)
+        lp0 = {k: v[0] for k, v in model.params["layers"].items()}
+        x = model.params["embed"][args[0]] + model.params["pos_embed"][args[1]]
+        q = tf._layer_norm(x, lp0["ln1_scale"], lp0["ln1_bias"]) @ lp0["wq"]
+        got = tf._live_attention(q, new_cache["k"], new_cache["v"], 0, plan,
+                                 model.cfg.num_heads)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ctxs[0]),
+                                   rtol=2e-5, atol=2e-6)
+
+    def test_row_result_does_not_depend_on_its_block_mates(self):
+        """A row's context is bit-identical whichever rows share its
+        block and however far they make the block walk: shuffle and
+        redraw the OTHER rows' lengths, keep three rows as they are."""
+        import jax
+        from mxnet_tpu.models import transformer as tf
+        rows = 2 * tf._WALK_ROWS
+        model, cache, tokens, positions, tables, active = \
+            self._walk_case(rows)
+        keep = [3, 7, rows - 1]
+        q = np.random.RandomState(1).standard_normal(
+            (rows, model.cfg.d_model)).astype(np.float32)
+
+        @jax.jit
+        def attend(q, positions, tables):
+            plan, _ = tf._walk_plan(positions, tables, 16)
+            return tf._live_attention(q, cache["k"], cache["v"], 1, plan,
+                                      model.cfg.num_heads)
+
+        def contexts_of(positions, tables, slots):
+            q2 = np.zeros_like(q)
+            q2[slots] = q[keep]
+            return np.asarray(attend(q2, positions, tables))[slots]
+
+        ref = contexts_of(positions, tables, keep)
+        assert np.abs(ref).min() > 0
+        rng = np.random.RandomState(5)
+        for trial in range(3):
+            perm = rng.permutation(rows)
+            pos2 = rng.randint(0, 1024, rows).astype(np.int32)
+            tab2 = tables[perm].copy()      # other rows: other tables
+            slots = [int(np.where(perm == k)[0][0]) for k in keep]
+            pos2[slots] = positions[keep]
+            if trial == 2:                  # short mates: a short walk
+                others = np.setdiff1d(np.arange(rows), slots)
+                pos2[others] = rng.randint(0, 16, len(others))
+            got = contexts_of(pos2, tab2, slots)
+            assert (got == ref).all(), "trial %d" % trial
+
+    def test_walk_counters_reach_stats(self):
+        """``kv_live_tokens`` is the sum of ``positions + 1`` over active
+        rows; ``kv_walked_tokens`` lies between it and rows x table; both
+        ride the step's read-back into ``stats()["model"]``."""
+        import jax
+        from mxnet_tpu.models import transformer as tf
+        rows = 2 * tf._WALK_ROWS
+        model, cache, tokens, positions, tables, active = \
+            self._walk_case(rows)
+        _, _, aux = jax.jit(model.step_fn)(model.params, cache, tokens,
+                                           positions, tables, active)
+        live, walked = int(aux["kv_live_tokens"]), int(aux["kv_walked_tokens"])
+        assert live == int((positions[active] + 1).sum())
+        assert live <= walked <= rows * tables.shape[1] * 16
+        assert walked < rows * tables.shape[1] * 16     # the walk stops short
+        model = _tf_model()
+        eng = _tf_engine(model, "tfaux")
+        outs = [eng.generate(p, max_new_tokens=m)
+                for p, m in zip(self.PROMPTS[:3], self.BUDGETS[:3])]
+        st = eng.stats()
+        eng.stop()
+        # a step of a sequence with c cached tokens attends over c + 1
+        want = sum(sum(len(p) + i + 1 for i in range(len(o) - 1))
+                   for p, o in zip(self.PROMPTS, outs))
+        assert st["model"]["kv_live_tokens"] == want
+        assert st["model"]["kv_walked_tokens"] >= want
+        assert st["model"]["kv_walked_tokens"] == st["steps"] * 3 * 64
+
+    def test_no_head_split_buffer_at_positions_size(self):
+        """From the step's jaxpr at the cell's shapes (64 rows, 64 x 16
+        table, 12 heads of 64): no intermediate whose two minor
+        dimensions are ``(num_heads, head_dim)`` holds more than one
+        piece's positions, and none holds rows x table of them: that
+        pair is what the (8,128) tile pads from 768 lanes to 2,048."""
+        import jax
+        from mxnet_tpu.models.transformer import (
+            TransformerConfig, TransformerDecodeModel, _walk_sizes)
+        cfg = TransformerConfig(vocab_size=128, num_layers=2, num_heads=12,
+                                d_model=768, d_ff=64, max_len=1024)
+        sd = jax.ShapeDtypeStruct
+        params = jax.eval_shape(
+            lambda: TransformerDecodeModel(cfg, flash="off").params)
+        model = TransformerDecodeModel(cfg, params=params, flash="off")
+        B, mb, bs = 64, 64, 16
+        rb, cb = _walk_sizes(B, mb, bs)
+        assert rb < B and cb < mb
+        i32 = np.int32
+        jaxpr = jax.make_jaxpr(model.step_fn)(
+            params, model.cache_spec(1729, bs), sd((B,), i32),
+            sd((B,), i32), sd((B, mb), i32), sd((B,), np.bool_)).jaxpr
+
+        def shapes(jaxpr):
+            for eqn in jaxpr.eqns:
+                for v in eqn.outvars:
+                    yield tuple(v.aval.shape)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from shapes(sub)
+
+        seen = set(shapes(jaxpr))
+        assert (rb, cb * bs, 768) in seen           # a piece, rows in lanes
+        for s in (s for s in seen if s[-2:] == (12, 64)):
+            assert int(np.prod(s[:-2])) <= rb * cb * bs, s
+        assert not [s for s in seen
+                    if int(np.prod(s)) >= B * mb * bs * 768], \
+            "a whole-table buffer survives"
 
     def test_pool_is_layer_major_and_layers_write_their_own_pages(self):
         """The model states the pools (``cache_spec``) and the engine

@@ -198,3 +198,37 @@ def test_grouped_expert_product_compiles_at_published_widths(one_chip):
     text = _compile(lambda p, x: routed_experts(
         p, x, held=(0, 16), top_k=8, scale=2.5), params, sd(256, 7680))
     assert "ragged" in text.lower()
+
+
+def test_gpt2_decode_step_keeps_heads_in_lanes(one_chip):
+    """models/transformer.py's decode step at the benchmark cell's shapes
+    (64 rows, tables of 64 blocks of 16, 12 heads of 64, two layers): the
+    compiled program holds no head-split view of a table's positions (the
+    (8,128) tile pads a minor ``(12, 64)`` pair from 768 lanes to 2,048)
+    and no whole-table buffer: its temporaries stay far under one row
+    block's gathered table. No Pallas kernel: the walk is plain XLA."""
+    import re
+    from mxnet_tpu.models.transformer import (
+        TransformerConfig, TransformerDecodeModel, _walk_sizes)
+    cfg = TransformerConfig(vocab_size=1024, num_layers=2, num_heads=12,
+                            d_model=768, d_ff=3072, max_len=1024)
+    B, mb, bs = 64, 64, 16
+    rb, cb = _walk_sizes(B, mb, bs)
+    on_chip = lambda a: jax.ShapeDtypeStruct(                  # noqa: E731
+        a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree_util.tree_map(on_chip, jax.eval_shape(
+        lambda: TransformerDecodeModel(cfg, flash="off").params))
+    model = TransformerDecodeModel(cfg, params=params, flash="off")
+    cache = jax.tree_util.tree_map(on_chip, model.cache_spec(1729, bs))
+    sd = lambda s, d: jax.ShapeDtypeStruct(s, d,               # noqa: E731
+                                           sharding=one_chip)
+    compiled = jax.jit(model.step_fn, donate_argnums=(1,)).lower(
+        params, cache, sd((B,), jnp.int32), sd((B,), jnp.int32),
+        sd((B, mb), jnp.int32), sd((B,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "f32[%d,%d,768]" % (rb, cb * bs) in text      # a piece, as gathered
+    for dims in re.findall(r"f32\[([0-9,]+),12,64\]", text):
+        positions = int(np.prod([int(d) for d in dims.split(",")]))
+        assert positions < rb * cb * bs, "head-split buffer f32[%s,12,64]" % dims
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < rb * mb * bs * 768 * 4
