@@ -1611,14 +1611,14 @@ def _phase_decode():
     import numpy as np
     import jax
     from mxnet_tpu import profiler
+    from mxnet_tpu.models.tiny_lm import TinyLMDecodeModel
     from mxnet_tpu.serving import (ModelServer, ServingFrontDoor,
-                                   ServingClient, DecodeEngine,
-                                   tiny_lm_params)
+                                   ServingClient, DecodeEngine)
     platform = jax.devices()[0].platform
     vocab, dim = 256, 64
-    params = tiny_lm_params(vocab=vocab, dim=dim)
+    lm = TinyLMDecodeModel(vocab=vocab, dim=dim).engine_kwargs()
     batch = 4
-    eng = DecodeEngine(params, name="bench", num_blocks=256,
+    eng = DecodeEngine(**lm, name="bench", num_blocks=256,
                        batch_size=batch, max_seq_len=128,
                        prefill_buckets=(16,))
     rng = np.random.RandomState(0)

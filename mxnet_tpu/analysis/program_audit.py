@@ -782,8 +782,10 @@ def _build_serving_buckets():
 
 def _build_decode():
     import jax
-    from ..serving.decode import DecodeEngine, tiny_lm_params
-    eng = DecodeEngine(tiny_lm_params(), name="audit", num_blocks=32,
+    from ..models.tiny_lm import TinyLMDecodeModel
+    from ..serving.decode import DecodeEngine
+    eng = DecodeEngine(**TinyLMDecodeModel().engine_kwargs(),
+                       name="audit", num_blocks=32,
                        batch_size=2, max_seq_len=32, prefill_buckets=(8,),
                        prefill_chunk=0, warmup=True, autostart=False)
     sd = jax.ShapeDtypeStruct

@@ -47,13 +47,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..kernels import paged_attention as paged
 from ..parallel.moe import routed_experts
+from .decode_model import DecodeModel
 
 __all__ = ["MoEMLAConfig", "init_moe_mla", "moe_mla_forward",
            "moe_mla_decode_prefill", "moe_mla_decode_step",
            "MoEMLADecodeModel"]
 
-_NEG = -1e30            # additive mask: exp(-1e30 - m) is exactly 0 in f32
 _LANES = 128
 
 
@@ -286,42 +287,30 @@ def _mla_expand(cfg, rows, w_k, w_v):
 def _attend_expanded(cfg, lp, q, rows, start, use_pallas, interpret):
     """Causal attention of queries ``q`` ``[C, H, dn + dr]`` at global
     positions ``start + i`` over the keys and values that the latent
-    ``rows`` ``[T, .]`` at positions ``0..T-1`` expand to, through the flash
-    kernel (``use_pallas`` / ``interpret``) or the blockwise lax tier. The
-    kernels want ONE head width, a multiple of the lane count: q and k (192
-    wide as published) and v (128) are zero-padded to it and the output is
-    cut back: layout only, a zero column adds nothing to a score or to a
-    value. (Expanding only the live positions, a group at a time into
-    buffers carried from layer to layer, was tried and lost: the loop's
-    buffers take another layout than the kernel's and are copied into it,
-    PERF.md PR 28.)"""
-    from ..kernels.flash_attention import (blockwise_attention,
-                                           flash_attention_with_lse)
-    C, H, dqk = q.shape
-    T, dv = rows.shape[0], cfg.v_head_dim
-    sm = 1.0 / _np.sqrt(dqk)
+    ``rows`` ``[T, .]`` at positions ``0..T-1`` expand to
+    (`paged_attention.chunk_attention`: the flash kernel or the blockwise
+    lax tier). The kernels want ONE head width, a multiple of the lane
+    count: q and k (192 wide as published) and v (128) are zero-padded to
+    it and the output is cut back: layout only, a zero column adds nothing
+    to a score or to a value. (Expanding only the live positions, a group
+    at a time into buffers carried from layer to layer, was tried and lost:
+    the loop's buffers take another layout than the kernel's and are copied
+    into it, PERF.md PR 28.)"""
+    dqk, dv = q.shape[-1], cfg.v_head_dim
     q = q.transpose(1, 0, 2)
-    bq = C if C % min(cfg.block_k, C) else min(cfg.block_k, C)
-    bk = T if T % min(cfg.block_k, T) else min(cfg.block_k, T)
     if use_pallas or interpret:
         w = -(-max(dqk, dv) // _LANES) * _LANES if use_pallas \
             else max(dqk, dv)
         k, v = _mla_expand(cfg, rows, *_expansion_weights(cfg, lp, w, w))
         q = jnp.pad(q.astype(k.dtype), ((0, 0), (0, 0), (0, w - dqk)))
-        offs = jnp.stack([jnp.asarray(start, jnp.int32), jnp.int32(0)])
-        out, _ = flash_attention_with_lse(q[None], k[None], v[None], offs,
-                                          sm, True, bq, bk, interpret,
-                                          "grid")
-        out = out[..., :dv]
     else:
         # the lax tier keeps its online softmax in its operands' dtype:
         # hand it float32 (the kernels accumulate in float32 themselves)
         k, v = _mla_expand(cfg, rows, *_expansion_weights(cfg, lp))
-        lay = lambda t: t.astype(jnp.float32)[None]             # noqa: E731
-        out, _ = blockwise_attention(lay(q), lay(k), lay(v), causal=True,
-                                     sm_scale=sm, block_k=bk,
-                                     q_offset=start, k_offset=0)
-    return out[0].transpose(1, 0, 2)                    # [C, H, dv]
+        q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
+    out = paged.chunk_attention(q, k, v, start, 1.0 / _np.sqrt(dqk),
+                                cfg.block_k, use_pallas, interpret)
+    return out[..., :dv].transpose(1, 0, 2)             # [C, H, dv]
 
 
 def _ffn(cfg, lp, h, valid=None):
@@ -408,14 +397,9 @@ def moe_mla_decode_prefill(params, cfg, cache, tokens, start, length, table,
     start, length, table) -> (next_id, cache, aux)``; ``with_logits``
     (tests) appends that position's float32 logits."""
     pool = cache["latent"]                  # [L, blocks, bs, rkv + dr]
-    C = tokens.shape[0]
-    bs, mb = pool.shape[2], table.shape[0]
-    T = mb * bs
-    idx = jnp.arange(C, dtype=jnp.int32)
-    pos = start + idx
-    valid = idx < length
-    blk = jnp.where(valid, table[jnp.clip(pos, 0, T - 1) // bs], 0)
-    slot = jnp.clip(pos, 0, T - 1) % bs
+    C, width = tokens.shape[0], pool.shape[3]
+    pos, valid, blk, slot = paged.chunk_addresses(table, start, length, C,
+                                                  pool.shape[2])
     x = params["embed"][tokens].astype(jnp.float32)
     all_counts = []
     for l, lp in enumerate(params["layers"]):
@@ -425,9 +409,9 @@ def moe_mla_decode_prefill(params, cfg, cache, tokens, start, length, table,
                 q_nope, q_rope, rows = _mla_project(cfg, lp, h, pos)
                 pool = pool.at[l, blk, slot].set(_cache_rows(rows, pool))
                 q = jnp.concatenate([q_nope, q_rope], -1)
-                o = _attend_expanded(cfg, lp, q,
-                                     pool[l, table].reshape(T, -1), start,
-                                     use_pallas, interpret)
+                seen = paged.gather_pages(pool, l, table).reshape(-1, width)
+                o = _attend_expanded(cfg, lp, q, seen, start, use_pallas,
+                                     interpret)
                 return o.reshape(C, -1)
             x, counts = _block(cfg, lp, x, attend, valid)
             if counts is not None:
@@ -439,23 +423,14 @@ def moe_mla_decode_prefill(params, cfg, cache, tokens, start, length, table,
     return out + (logits,) if with_logits else out
 
 
-def _absorbed_attention(cfg, lp, q_nope, q_rope, pool, l, tables, positions):
-    """The decode step's attention in the latent space. ``q_nope`` ``[B, H,
-    dn]``, ``q_rope`` ``[B, H, dr]``, ``pool`` ``[L, blocks, bs, row]`` read
-    at layer ``l`` (``pool[l, tables]``, never ``pool[l][tables]``: the
-    second copies the layer's pool before the gather, PERF.md PR 27).
-
-    Only LIVE positions are read. The rows are sorted by length and taken
-    ``step_row_block`` at a time; a block walks its rows' tables
-    ``step_col_blocks`` blocks at a time, as far as its longest row reaches
-    and no further (a loop with a traced trip count: static shapes, one
-    program), folding each piece in with a running softmax. What is live at
-    once is a piece's gathered latent rows and scores. Sorting keeps a
-    block's rows about equally long, so little of a piece is masked."""
+def _absorbed_attention(cfg, lp, q_nope, q_rope, pool, l, plan):
+    """The decode step's attention in the latent space, over the live
+    positions only (`paged_attention.live_walk`, ``plan`` the step's).
+    ``q_nope`` ``[B, H, dn]``, ``q_rope`` ``[B, H, dr]``, ``pool`` ``[L,
+    blocks, bs, row]`` read at layer ``l``. What is live at once is a
+    piece's gathered latent rows and scores."""
     H, dn, dv, rkv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                       cfg.v_head_dim, cfg.kv_lora_rank)
-    B, mb = tables.shape
-    bs, width = pool.shape[2], pool.shape[3]
     dt = pool.dtype
     w_kvb = lp["wkv_b"].reshape(rkv, H, dn + dv)
     w_k, w_v = w_kvb[..., :dn], w_kvb[..., dn:]
@@ -463,53 +438,27 @@ def _absorbed_attention(cfg, lp, q_nope, q_rope, pool, l, tables, positions):
     q_lat = jnp.einsum("bhn,rhn->bhr", q_nope.astype(dt), w_k,
                        preferred_element_type=jnp.float32)
     qq = jnp.concatenate([q_lat, q_rope], -1).astype(dt)    # [B, H, rkv+dr]
-    qq = jnp.pad(qq, ((0, 0), (0, 0), (0, width - qq.shape[-1])))
+    qq = jnp.pad(qq, ((0, 0), (0, 0), (0, pool.shape[3] - qq.shape[-1])))
     sm = 1.0 / _np.sqrt(dn + cfg.qk_rope_head_dim)
-    cb = cfg.step_col_blocks if mb % cfg.step_col_blocks == 0 else mb
-    span = cb * bs                              # positions a piece covers
 
-    def rows_block(args):
-        qq_b, tables_b, pos_b = args            # [rb, H, .], [rb, mb], [rb]
-        rb = qq_b.shape[0]
-
-        def piece(j, carry):
-            m, den, acc = carry
-            tab = lax.dynamic_slice_in_dim(tables_b, j * cb, cb, axis=1)
-            lat = pool[l, tab].reshape(rb, span, width)
+    def rows_block(qq_b, pos_b, walk):
+        def fold(carry, pieces, tpos):
+            lat, = pieces                           # [rb, span, row]
             s = jnp.einsum("bhc,btc->bht", qq_b, lat,
                            preferred_element_type=jnp.float32) * sm
-            tpos = j * span + jnp.arange(span, dtype=jnp.int32)
-            s = jnp.where(tpos[None, None, :] <= pos_b[:, None, None], s,
-                          _NEG)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-            p = jnp.exp(s - m_new[..., None])
-            alpha = jnp.exp(m - m_new)
-            acc = acc * alpha[..., None] + jnp.einsum(
-                "bht,btr->bhr", p.astype(dt), lat[..., :rkv],
-                preferred_element_type=jnp.float32)
-            return m_new, den * alpha + jnp.sum(p, axis=-1), acc
+            return paged.softmax_fold(
+                carry, s, tpos, pos_b, 2,
+                lambda p: jnp.einsum("bht,btr->bhr", p.astype(dt),
+                                     lat[..., :rkv],
+                                     preferred_element_type=jnp.float32))
 
-        # position 0 is live for every row, so the first piece leaves a
-        # finite running maximum and a masked piece after it adds exact 0
-        carry = (jnp.full((rb, H), _NEG, jnp.float32),
-                 jnp.zeros((rb, H), jnp.float32),
-                 jnp.zeros((rb, H, rkv), jnp.float32))
-        _, den, acc = lax.fori_loop(0, jnp.max(pos_b) // span + 1, piece,
-                                    carry)
+        _, den, acc = walk(fold, (qq_b.shape[0], H), rkv)
         return acc / den[..., None]
 
-    rb = cfg.step_row_block
-    if B % rb or B <= rb:
-        u = rows_block((qq, tables, positions))
-    else:
-        order = jnp.argsort(positions)
-        split = lambda t: jnp.take(t, order, axis=0).reshape(   # noqa: E731
-            (B // rb, rb) + t.shape[1:])
-        u = lax.map(rows_block, (split(qq), split(tables), split(positions)))
-        u = jnp.take(u.reshape(B, H, rkv), jnp.argsort(order), axis=0)
+    u = paged.live_walk(plan, (pool,), l, qq, rows_block)
     o = jnp.einsum("bhr,rhv->bhv", u.astype(dt), w_v,
                    preferred_element_type=jnp.float32)
-    return o.reshape(B, H * dv)
+    return o.reshape(-1, H * dv)
 
 
 @jax.named_scope("decode.step")      # the trace's device-side name
@@ -523,9 +472,9 @@ def moe_mla_decode_step(params, cfg, cache, token_ids, positions, tables,
     ``with_logits`` (tests) appends the rows' float32 logits."""
     pool = cache["latent"]
     bs = pool.shape[2]
-    blk = jnp.take_along_axis(tables, (positions // bs)[:, None], axis=1)
-    blk = jnp.where(active, blk[:, 0], 0)
-    slot = positions % bs
+    blk, slot = paged.step_addresses(tables, positions, active, bs)
+    plan = paged.walk_plan(positions, tables, bs, cfg.step_row_block,
+                           cfg.step_col_blocks * bs)
     x = params["embed"][token_ids].astype(jnp.float32)
     all_counts = []
     for l, lp in enumerate(params["layers"]):
@@ -535,7 +484,7 @@ def moe_mla_decode_step(params, cfg, cache, token_ids, positions, tables,
                 q_nope, q_rope, rows = _mla_project(cfg, lp, h, positions)
                 pool = pool.at[l, blk, slot].set(_cache_rows(rows, pool))
                 return _absorbed_attention(cfg, lp, q_nope, q_rope, pool, l,
-                                           tables, positions)
+                                           plan)
             x, counts = _block(cfg, lp, x, attend, active)
             if counts is not None:
                 all_counts.append(counts)
@@ -548,33 +497,26 @@ def moe_mla_decode_step(params, cfg, cache, token_ids, positions, tables,
     return out + (logits,) if with_logits else out
 
 
-class MoEMLADecodeModel:
+class MoEMLADecodeModel(DecodeModel):
     """Adapter: a `MoEMLAConfig` wired for the DecodeEngine seam.
 
     >>> model = MoEMLADecodeModel(cfg, params=params)       # or seed=
     >>> eng = DecodeEngine(**model.engine_kwargs(), max_seq_len=4096, ...)
 
-    ``flash`` picks the prefill attention tier as in
-    `TransformerDecodeModel` (None reads ``MXNET_SERVING_DECODE_FLASH``:
-    auto | 1/on | 0/off | interpret). The cache is ONE pool of latent rows
-    in the parameters' dtype; with a ``mesh`` it is stated replicated: a
+    ``flash`` picks the prefill attention tier
+    (`DecodeModel.resolve_flash`). The cache is ONE pool of latent rows in
+    the parameters' dtype; with a ``mesh`` it is stated replicated: a
     latent row has no head axis to shard."""
 
     def __init__(self, cfg, params=None, seed=0, dtype=jnp.bfloat16,
                  flash=None, mesh=None):
-        from ..parallel.mesh_kernels import resolve_kernel_tier
         self.cfg = cfg
         if params is None:
             params = init_moe_mla(cfg, jax.random.PRNGKey(seed), dtype)
         self.params = params
         self.cache_dtype = params["embed"].dtype
         self.mesh = mesh
-        mode = flash
-        if mode is None:
-            import os
-            mode = os.environ.get("MXNET_SERVING_DECODE_FLASH", "auto")
-        self.use_pallas, self.interpret = resolve_kernel_tier(mode)
-        self.flash_engaged = bool(self.use_pallas or self.interpret)
+        self.resolve_flash(flash)
 
     def cache_spec(self, num_blocks, block_size):
         """One pool: ``(layers, blocks, block_size, cache_row_width)`` in
@@ -597,8 +539,3 @@ class MoEMLADecodeModel:
     def step_fn(self, params, cache, token_ids, positions, tables, active):
         return moe_mla_decode_step(params, self.cfg, cache, token_ids,
                                    positions, tables, active)
-
-    def engine_kwargs(self):
-        """kwargs bundle for DecodeEngine(**model.engine_kwargs(), ...)."""
-        return {"params": self.params, "cache_spec": self.cache_spec,
-                "prefill_fn": self.prefill_fn, "step_fn": self.step_fn}

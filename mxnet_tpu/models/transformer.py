@@ -26,8 +26,10 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..kernels.flash_attention import flash_attention
+from ..kernels import paged_attention as paged
 from ..parallel.collectives import shard_map
 from ..parallel.ring_attention import sequence_parallel_attention
+from .decode_model import DecodeModel
 
 __all__ = ["TransformerConfig", "init_transformer", "transformer_forward",
            "transformer_loss", "transformer_sharding_rules",
@@ -258,67 +260,21 @@ def transformer_loss(params, tokens, targets, cfg, mesh=None, rng=None,
 
 
 # ---------------------------------------------------------------------------
-# Paged-KV decode bodies (serving/decode.py program family)
+# Paged-KV decode bodies (the DecodeEngine seam, models/decode_model.py)
 # ---------------------------------------------------------------------------
-# The serving DecodeEngine is model-agnostic: it owns the paged KV pool,
-# block tables and continuous batching, and calls a bucketed batch-1
-# prefill program plus one fixed-shape batched step program. These are
-# the real multi-layer multi-head transformer bodies for that seam —
-# replacing the engine's built-in single-layer parity fixture with the
-# model family the parallel stack is designed around.
-#
-# KV page layout: layer-major, ``(num_layers, num_blocks, block_size,
-# d_model)`` for each of K and V, so ``pages[l]`` is one layer's
-# contiguous pool and a layer gathers only its own pages. Heads are folded
-# into d_model, so tp-sharding the trailing dim shards heads
-# (`kvcache.page_sharding`). Per layer l, position p of a sequence lives
-# at ``pages[l, table[p // bs], p % bs]``.
-#
-# Masking contract (shared with the built-in fixture): padding/inactive
-# writes scatter into the null block, and every read masks additively
-# with -1e30 — exp(-1e30 - m) is exactly 0.0 in f32, so not-yet-written
-# or foreign page content can never perturb a real row's bits. This is
-# what makes chunked prefill BIT-identical to whole-prompt prefill: a
-# query at global position p gathers the same table-shaped page block
-# either way, real keys (tpos <= p) hold identical bits by induction
-# over layers/chunks, and masked lanes contribute exactly 0 regardless
-# of content.
+# The page format, the masking contract, the step's walk over the live
+# positions and the prefill chunk's attention are
+# `kernels/paged_attention.py`'s; what is written here is this family's
+# layout and its two contractions. K and V each have a layer-major pool
+# ``(num_layers, num_blocks, block_size, d_model)``; heads are folded into
+# d_model, so tp-sharding the trailing dim shards heads
+# (`kvcache.page_sharding`).
 
-_NEG = -1e30
-
-
-def _decode_attn_prefill(q, ks, vs, start, cfg, use_pallas, interpret):
-    """Chunk attention over gathered pages. q: (C, H, Dh); ks/vs:
-    (T, H, Dh) gathered from the sequence's block table. Causal at
-    global offset `start` (query row i sits at position start + i).
-
-    Kernel tier: the offset-aware flash kernels
-    (`_flash_fwd_offs_kernel` block-table variant) with
-    offs = [start, 0]; lax tier: `blockwise_attention` with q_offset —
-    identical masking semantics, fp-tolerance numerics."""
-    from ..kernels.flash_attention import (blockwise_attention,
-                                           flash_attention_with_lse)
-    C, H, Dh = q.shape
-    T = ks.shape[0]
-    sm = 1.0 / _np.sqrt(Dh)
-    q4 = q.transpose(1, 0, 2)[None]                     # (1, H, C, Dh)
-    k4 = ks.transpose(1, 0, 2)[None]
-    v4 = vs.transpose(1, 0, 2)[None]
-    # block sizes must tile exactly: C is a prefill bucket (so C itself
-    # always works), T = mb * block_size (so block_size always works)
-    bq = C if C % min(cfg.block_k, C) else min(cfg.block_k, C)
-    bk = T if T % min(cfg.block_k, T) else min(cfg.block_k, T)
-    if use_pallas or interpret:
-        offs = jnp.asarray([start, 0], jnp.int32) \
-            if not hasattr(start, "dtype") else \
-            jnp.stack([start.astype(jnp.int32), jnp.int32(0)])
-        out, _ = flash_attention_with_lse(q4, k4, v4, offs, sm, True,
-                                          bq, bk, interpret,
-                                          cfg.attn_variant)
-    else:
-        out, _ = blockwise_attention(q4, k4, v4, causal=True, sm_scale=sm,
-                                     block_k=bk, q_offset=start, k_offset=0)
-    return out[0].transpose(1, 0, 2)                    # (C, H, Dh)
+# The decode step's walk: rows a block and positions a piece. Settled on
+# the chip at 64 rows over 64 blocks of 16 (PERF.md, PR 30): 8 x 128 reads
+# 7.2 ms a step, 8 x 32 and 2 x 128 9.5, 32 x 256 14.
+_WALK_ROWS = 8
+_WALK_SPAN = 128
 
 
 @jax.named_scope("decode.prefill")      # the trace's device-side name
@@ -334,37 +290,33 @@ def transformer_decode_prefill(params, cfg, cache, tokens,
     aux)``; the cache is ``{"k": pages, "v": pages}``, ``aux`` empty.
     Whole-prompt prefill is the ``start=0`` call; chunked prefill is the
     SAME bucket program called repeatedly with advancing ``start`` —
-    the program family stays at len(buckets)+1."""
+    the program family stays at len(buckets)+1, and the result stays
+    BIT-identical: real keys hold identical bits by induction over layers
+    and chunks, and masked lanes contribute exactly 0 whatever they hold."""
     k_pages, v_pages = cache["k"], cache["v"]
     C = tokens.shape[0]
     bs = k_pages.shape[2]
-    mb = table.shape[0]
-    L = cfg.num_layers
     H, Dh = cfg.num_heads, cfg.d_model // cfg.num_heads
-    T = mb * bs
-    idx = jnp.arange(C, dtype=jnp.int32)
-    pos = start + idx
+    T = table.shape[0] * bs
+    pos, _, blk, slot = paged.chunk_addresses(table, start, length, C, bs)
     x = params["embed"][tokens].astype(cfg.dtype)
     x = x + params["pos_embed"][jnp.clip(pos, 0, cfg.max_len - 1)] \
         .astype(cfg.dtype)
-    valid = idx < length
-    blk = jnp.where(valid, table[jnp.clip(pos, 0, T - 1) // bs], 0)
-    slot = jnp.clip(pos, 0, T - 1) % bs
+    heads = lambda t, n: t.reshape(n, H, Dh).transpose(1, 0, 2)  # noqa: E731
     lp_all = params["layers"]
-    for l in range(L):
+    for l in range(cfg.num_layers):
         with jax.named_scope("layer"):
             lp = {k: v[l] for k, v in lp_all.items()}
             h = _layer_norm(x, lp["ln1_scale"], lp["ln1_bias"])
-            q = (h @ lp["wq"]).reshape(C, H, Dh)
-            kk = h @ lp["wk"]                               # (C, D)
-            vv = h @ lp["wv"]
-            k_pages = k_pages.at[l, blk, slot].set(kk)
-            v_pages = v_pages.at[l, blk, slot].set(vv)
-            ks = k_pages[l, table].reshape(T, H, Dh)
-            vs = v_pages[l, table].reshape(T, H, Dh)
-            a = _decode_attn_prefill(q, ks, vs, start, cfg, use_pallas,
-                                     interpret)
-            x = x + a.reshape(C, cfg.d_model) @ lp["wo"]
+            k_pages = k_pages.at[l, blk, slot].set(h @ lp["wk"])
+            v_pages = v_pages.at[l, blk, slot].set(h @ lp["wv"])
+            a = paged.chunk_attention(
+                heads(h @ lp["wq"], C),
+                heads(paged.gather_pages(k_pages, l, table), T),
+                heads(paged.gather_pages(v_pages, l, table), T),
+                start, 1.0 / _np.sqrt(Dh), cfg.block_k, use_pallas,
+                interpret, cfg.attn_variant)
+            x = x + a.transpose(1, 0, 2).reshape(C, cfg.d_model) @ lp["wo"]
             h = _layer_norm(x, lp["ln2_scale"], lp["ln2_bias"])
             x = x + (jax.nn.gelu(h @ lp["w1"] + lp["b1"]) @ lp["w2"]
                      + lp["b2"])
@@ -375,68 +327,20 @@ def transformer_decode_prefill(params, cfg, cache, tokens,
             {"k": k_pages, "v": v_pages}, {})
 
 
-# The decode step's walk over the live positions: how many rows a block
-# of the walk holds and how many positions one piece of it covers. The
-# trade is walked-but-masked positions (larger blocks and pieces: a block
-# walks as far as its LONGEST row, rounded up to a piece) against loop
-# iterations (smaller ones: layers x row blocks x pieces, each with a
-# fixed cost). Settled on the chip at 64 rows over 64 blocks of 16
-# (PERF.md, PR 30): 8 x 128 reads 7.2 ms a step, 8 x 32 and 2 x 128 9.5,
-# 32 x 256 14.
-_WALK_ROWS = 8
-_WALK_SPAN = 128
-
-
-def _walk_sizes(B, mb, bs):
-    """``(rows per block, table blocks per piece)`` of the step's walk for
-    ``B`` rows over tables of ``mb`` blocks of ``bs`` positions. One block
-    over all rows where ``B`` does not divide into several; one piece over
-    the whole table where ``mb`` does not."""
-    rb = _WALK_ROWS if B > _WALK_ROWS and B % _WALK_ROWS == 0 else B
-    cb = max(1, _WALK_SPAN // bs)
-    return rb, (mb if mb % cb else cb)
-
-
-def _walk_plan(positions, tables, bs):
-    """What every layer's walk shares: the rows sorted by length (and the
-    way back) and split into blocks, each block's tables and positions,
-    and the number of pieces it walks: as far as its longest row reaches
-    and no further. With it, the positions one layer's walk covers."""
-    B, mb = tables.shape
-    rb, cb = _walk_sizes(B, mb, bs)
-    order = jnp.argsort(positions)
-    pos_s = jnp.take(positions, order).reshape(B // rb, rb)
-    tables_s = jnp.take(tables, order, axis=0).reshape(B // rb, rb, mb)
-    pieces = jnp.max(pos_s, axis=1) // (cb * bs) + 1
-    return ((order, jnp.argsort(order), pos_s, tables_s, pieces, cb),
-            jnp.sum(pieces) * (rb * cb * bs))
-
-
 def _live_attention(q, k_pages, v_pages, l, plan, num_heads):
-    """One layer's decode attention over the LIVE positions only, heads
-    kept in the lanes. ``q`` ``(B, d_model)``; pools ``(L, blocks, bs,
-    d_model)`` read at layer ``l`` (``pages[l, tab]``, never
-    ``pages[l][tab]``: the second copies the layer's pool first).
+    """One layer's decode attention over the live positions
+    (`paged_attention.live_walk`), heads kept in the lanes. ``q`` ``(B,
+    d_model)``; pools ``(L, blocks, bs, d_model)`` read at layer ``l``.
 
-    A block of rows walks its tables a piece at a time (`_walk_plan`: a
-    loop with a traced trip count, static shapes, one program) and folds
-    each piece in with a running softmax in float32. A gathered piece stays
-    ``(rows, span, d_model)``. The query is laid out block-diagonally,
-    ``(rows, d_model, heads)`` with head ``h``'s 64 numbers in column ``h``,
-    so the scores are ``piece @ q_bd`` and the context ``p^T @ piece``, of
-    which head ``h`` keeps its own lanes: no positions-sized array ever
-    has ``(heads, head_dim)`` as its minor pair (the (8,128) tile pads
-    that pair from 768 lanes to 2,048). Both contractions carry
-    ``HIGHEST`` precision: no key, value, score or weight is rounded on
-    the way through the matrix unit.
-
-    A masked position contributes exact 0 and a piece past a row's end
-    leaves that row's carry bit-for-bit (``alpha = 1``, ``p = 0``), so a
-    row's result does not depend on which rows share its block."""
-    order, inverse, pos_s, tables_s, pieces, cb = plan
-    B, D = q.shape
-    nb, rb, _ = tables_s.shape
-    span = cb * k_pages.shape[2]
+    A gathered piece stays ``(rows, span, d_model)``. The query is laid
+    out block-diagonally, ``(rows, d_model, heads)`` with head ``h``'s 64
+    numbers in column ``h``, so the scores are ``piece @ q_bd`` and the
+    context ``p^T @ piece``, of which head ``h`` keeps its own lanes: no
+    positions-sized array ever has ``(heads, head_dim)`` as its minor pair
+    (the (8,128) tile pads that pair from 768 lanes to 2,048). Both
+    contractions carry ``HIGHEST`` precision: no key, value, score or
+    weight is rounded on the way through the matrix unit."""
+    D = q.shape[1]
     dh = D // num_heads
     sm = 1.0 / _np.sqrt(dh)
     hi = lax.Precision.HIGHEST
@@ -444,38 +348,21 @@ def _live_attention(q, k_pages, v_pages, l, plan, num_heads):
     ind = (jnp.arange(D)[:, None] // dh
            == jnp.arange(num_heads)[None, :]).astype(jnp.float32)
 
-    def rows_block(args):
-        q_b, tables_b, pos_b, n = args          # (rb, D), (rb, mb), (rb,), ()
+    def rows_block(q_b, pos_b, walk):
         q_bd = q_b[:, :, None] * ind            # (rb, D, H), block-diagonal
 
-        def piece(j, carry):
-            m, den, acc = carry                 # (rb, H), (rb, H), (rb, H, D)
-            tab = lax.dynamic_slice_in_dim(tables_b, j * cb, cb, axis=1)
-            kp = k_pages[l, tab].reshape(rb, span, D)
-            vp = v_pages[l, tab].reshape(rb, span, D)
+        def fold(carry, pieces, tpos):
+            kp, vp = pieces                     # (rb, span, D) each
             s = jnp.einsum("bsd,bdh->bsh", kp, q_bd, precision=hi) * sm
-            tpos = j * span + jnp.arange(span, dtype=jnp.int32)
-            s = jnp.where(tpos[None, :, None] <= pos_b[:, None, None], s,
-                          _NEG)
-            m_new = jnp.maximum(m, jnp.max(s, axis=1))
-            p = jnp.exp(s - m_new[:, None, :])
-            alpha = jnp.exp(m - m_new)
-            return (m_new, den * alpha + jnp.sum(p, axis=1),
-                    acc * alpha[..., None]
-                    + jnp.einsum("bsh,bsd->bhd", p, vp, precision=hi))
+            return paged.softmax_fold(
+                carry, s, tpos, pos_b, 1,
+                lambda p: jnp.einsum("bsh,bsd->bhd", p, vp, precision=hi))
 
-        # position 0 is live for every row, so the first piece leaves a
-        # finite running maximum and a masked piece after it adds exact 0
-        carry = (jnp.full((rb, num_heads), _NEG, jnp.float32),
-                 jnp.zeros((rb, num_heads), jnp.float32),
-                 jnp.zeros((rb, num_heads, D), jnp.float32))
-        _, den, acc = lax.fori_loop(0, n, piece, carry)
+        _, den, acc = walk(fold, (q_b.shape[0], num_heads), D)
         # head h keeps its own lanes of row h of the (H, D) accumulator
         return jnp.sum(acc / den[..., None] * ind.T, axis=1)
 
-    q_s = jnp.take(q, order, axis=0).reshape(nb, rb, D)
-    ctx = lax.map(rows_block, (q_s, tables_s, pos_s, pieces))
-    return jnp.take(ctx.reshape(B, D), inverse, axis=0)
+    return paged.live_walk(plan, (k_pages, v_pages), l, q, rows_block)
 
 
 @jax.named_scope("decode.step")      # the trace's device-side name
@@ -486,11 +373,7 @@ def transformer_decode_step(params, cfg, cache, token_ids,
     Matches the DecodeEngine step seam ``(params, cache, token_ids,
     positions, tables, active) -> (next_ids, cache, aux)``. Attention reads
     and contracts only the LIVE positions of each row's table
-    (`_live_attention`): the rows are sorted by length, a block of rows
-    walks its tables a piece at a time as far as its longest row reaches,
-    and the 768-wide row of the pool stays in the lane dimension
-    throughout. One program whatever the lengths (the walk's trip counts
-    are traced values). A row contracts only over its own gathered blocks
+    (`_live_attention`). A row contracts only over its own gathered blocks
     and a walked-but-masked position adds exact 0, so rows cannot observe
     each other: batched decode stays bit-identical to solo decode. The
     model's products run at the default matmul precision; the attention's
@@ -504,25 +387,19 @@ def transformer_decode_step(params, cfg, cache, token_ids,
     count); their ratio is the walk's efficiency."""
     k_pages, v_pages = cache["k"], cache["v"]
     bs = k_pages.shape[2]
-    L = cfg.num_layers
     x = params["embed"][token_ids].astype(cfg.dtype)
     x = x + params["pos_embed"][jnp.clip(positions, 0, cfg.max_len - 1)] \
         .astype(cfg.dtype)
-    blk = jnp.take_along_axis(tables, (positions // bs)[:, None], axis=1)
-    blk = jnp.where(active, blk[:, 0], 0)
-    slot = positions % bs
-    plan, walked = _walk_plan(positions, tables, bs)
+    blk, slot = paged.step_addresses(tables, positions, active, bs)
+    plan = paged.walk_plan(positions, tables, bs, _WALK_ROWS, _WALK_SPAN)
     lp_all = params["layers"]
-    for l in range(L):
+    for l in range(cfg.num_layers):
         with jax.named_scope("layer"):
             lp = {k: v[l] for k, v in lp_all.items()}
             h = _layer_norm(x, lp["ln1_scale"], lp["ln1_bias"])
-            q = h @ lp["wq"]
-            kk = h @ lp["wk"]
-            vv = h @ lp["wv"]
-            k_pages = k_pages.at[l, blk, slot].set(kk)
-            v_pages = v_pages.at[l, blk, slot].set(vv)
-            ctx = _live_attention(q, k_pages, v_pages, l, plan,
+            k_pages = k_pages.at[l, blk, slot].set(h @ lp["wk"])
+            v_pages = v_pages.at[l, blk, slot].set(h @ lp["wv"])
+            ctx = _live_attention(h @ lp["wq"], k_pages, v_pages, l, plan,
                                   cfg.num_heads)
             x = x + ctx @ lp["wo"]
             h = _layer_norm(x, lp["ln2_scale"], lp["ln2_bias"])
@@ -531,12 +408,12 @@ def transformer_decode_step(params, cfg, cache, token_ids,
     x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
     logits = x @ params["embed"].T.astype(cfg.dtype)
     aux = {"kv_live_tokens": jnp.sum(jnp.where(active, positions + 1, 0)),
-           "kv_walked_tokens": walked}
+           "kv_walked_tokens": plan.walked}
     return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
             {"k": k_pages, "v": v_pages}, aux)
 
 
-class TransformerDecodeModel:
+class TransformerDecodeModel(DecodeModel):
     """Adapter: a multi-layer TransformerConfig wired for the
     DecodeEngine seam.
 
@@ -544,29 +421,21 @@ class TransformerDecodeModel:
     ...     num_layers=2, num_heads=4, d_model=64, max_len=128))
     >>> eng = DecodeEngine(**model.engine_kwargs(), max_seq_len=128)
 
-    ``flash`` picks the prefill attention tier (the step body runs no
-    kernel: see `transformer_decode_step`): None reads
-    ``MXNET_SERVING_DECODE_FLASH`` (auto | 1/on | 0/off | interpret,
-    the `resolve_kernel_tier` vocabulary). Params default to
-    `init_transformer` from a seeded key, so every process (engine,
-    smoke clients, bench) derives the same model."""
+    ``flash`` picks the prefill attention tier (`DecodeModel.resolve_flash`;
+    the step body runs no kernel: see `transformer_decode_step`). Params
+    default to `init_transformer` from a seeded key, so every process
+    (engine, smoke clients, bench) derives the same model."""
 
     def __init__(self, cfg, params=None, seed=0, flash=None):
-        from ..parallel.mesh_kernels import resolve_kernel_tier
         self.cfg = cfg
         if params is None:
             params = init_transformer(cfg, jax.random.PRNGKey(seed))
         self.params = params
-        mode = flash
-        if mode is None:
-            import os
-            mode = os.environ.get("MXNET_SERVING_DECODE_FLASH", "auto")
-        self.use_pallas, self.interpret = resolve_kernel_tier(mode)
-        self.flash_engaged = bool(self.use_pallas or self.interpret)
+        self.resolve_flash(flash)
 
     def cache_spec(self, num_blocks, block_size):
         """The cache: twin float32 K and V pools, layer-major, so a
-        layer reads and writes only ``pages[l]``."""
+        layer reads and writes only its own pages."""
         pool = jax.ShapeDtypeStruct(
             (self.cfg.num_layers, num_blocks, block_size, self.cfg.d_model),
             jnp.float32)
@@ -580,8 +449,3 @@ class TransformerDecodeModel:
     def step_fn(self, params, cache, token_ids, positions, tables, active):
         return transformer_decode_step(params, self.cfg, cache,
                                        token_ids, positions, tables, active)
-
-    def engine_kwargs(self):
-        """kwargs bundle for DecodeEngine(**model.engine_kwargs(), ...)."""
-        return {"params": self.params, "cache_spec": self.cache_spec,
-                "prefill_fn": self.prefill_fn, "step_fn": self.step_fn}

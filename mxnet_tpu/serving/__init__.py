@@ -63,7 +63,7 @@ from .pool import FleetPool, RemoteReplica
 from .worker import ReplicaWorker
 from .autoscaler import Autoscaler, LocalProcessLauncher
 from .kvcache import PagedKVCache, CacheOverflow, NULL_BLOCK
-from .decode import DecodeEngine, DecodeStream, tiny_lm_params
+from .decode import DecodeEngine, DecodeStream
 
 __all__ = ["InferenceEngine", "ModelServer", "ServingFrontDoor",
            "ServingClient", "ClientStream", "FleetPool", "RemoteReplica",
@@ -72,4 +72,4 @@ __all__ = ["InferenceEngine", "ModelServer", "ServingFrontDoor",
            "DynamicBatcher", "DeadlineExceeded", "DEFAULT_BUCKETS",
            "bucket_for", "pad_to_bucket", "default_max_batch",
            "DecodeEngine", "DecodeStream", "PagedKVCache",
-           "CacheOverflow", "NULL_BLOCK", "tiny_lm_params"]
+           "CacheOverflow", "NULL_BLOCK"]

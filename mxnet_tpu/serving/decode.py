@@ -7,11 +7,8 @@ a *sequence* that holds device state (its KV cache) across many program
 calls, produces output incrementally, and finishes at a data-dependent
 time. This module is the decode side of the stack (ISSUE 18):
 
-- **Paged KV cache** (:mod:`.kvcache`): device pages shaped
-  ``(num_blocks, block_size, dim)``; a sequence owns a block table and
-  HBM scales with live tokens, not ``max_length x batch``. Block 0 is
-  the null block — fixed-shape programs route padding/inactive writes
-  there and real reads never touch it, so partial batches cannot alias.
+- **Paged KV cache** (:mod:`.kvcache`): a sequence owns a block table
+  and HBM scales with live tokens, not ``max_length x batch``.
 - **Iteration-level continuous batching**: the decode loop generalizes
   the EDF batcher's formation pass. Between *every* step it retires
   finished sequences (EOS / max-new-tokens / deadline) and admits
@@ -28,20 +25,15 @@ time. This module is the decode side of the stack (ISSUE 18):
   so ``program_count()`` is ``len(buckets)`` + 1 and stays there — the
   steady-state decode loop never compiles.
 
-The built-in program bodies implement a deliberately tiny single-layer
-attention LM (embed → K/V into the paged cache → masked attention over
-the sequence's own blocks → greedy argmax). It is small enough for the
-CPU test mesh yet genuinely history-dependent and row-independent, so
-"continuous-batched decode is bit-identical to solo decode" is a real
-statement about the cache/batching machinery. Custom models plug in via
-``prefill_fn``/``step_fn`` with the same signatures — the real
-multi-layer multi-head transformer family lives in
-:class:`~..models.transformer.TransformerDecodeModel` (flash-kernel
-prefill over the paged cache; its ``cache_spec`` states two layer-major
-float32 pools, ``(num_layers, num_blocks, block_size, d_model)``) and the
-latent-attention expert family in
-:class:`~..models.moe_mla.MoEMLADecodeModel` (one bfloat16 pool of
-latent rows).
+**The engine holds no model.** A family brings its cache and its two
+bodies through the decode-model seam (:mod:`~..models.decode_model`):
+``DecodeEngine(**model.engine_kwargs(), ...)``. Its clients are
+:class:`~..models.transformer.TransformerDecodeModel` (GPT-2 style),
+:class:`~..models.moe_mla.MoEMLADecodeModel` (latent attention, experts)
+and the tests' single-layer fixture beside them. The
+device side of the page format (addressing, the null block, the step's
+walk over the live positions, the prefill chunk's attention) is
+:mod:`~..kernels.paged_attention`'s.
 
 **The cache seam.** The model states its cache as a pytree of
 ``jax.ShapeDtypeStruct`` (``cache_spec(num_blocks, block_size)``: one
@@ -88,123 +80,12 @@ from .. import profiler as _prof
 from ..base import get_env
 from ..resilience import faults as _faults
 from .batcher import DeadlineExceeded
-from .kvcache import PagedKVCache, CacheOverflow, NULL_BLOCK
+from .kvcache import PagedKVCache, CacheOverflow
 
-__all__ = ["DecodeEngine", "DecodeStream", "tiny_lm_params",
-           "DEFAULT_DECODE_BUCKETS"]
+__all__ = ["DecodeEngine", "DecodeStream", "DEFAULT_DECODE_BUCKETS"]
 
 #: Default prompt-length buckets for the prefill program family.
 DEFAULT_DECODE_BUCKETS = (16, 64)
-
-# Additive attention mask for padded positions. exp(-1e30 - max) is
-# exactly 0.0 in f32, so masked garbage can never perturb real rows —
-# the bit-parity guarantee rides on this.
-_MASKED = -1e30
-
-
-def tiny_lm_params(vocab=32, dim=16, seed=0):
-    """Deterministic parameters for the built-in single-layer LM.
-
-    Keys: ``emb (V, D)``, ``w_k (D, D)``, ``w_v (D, D)``,
-    ``w_out (D, V)`` — all float32 from a seeded RandomState, so every
-    process (tests, smoke clients, bench) derives the same model."""
-    rng = _np.random.RandomState(seed)
-    s = 1.0 / math.sqrt(dim)
-    return {
-        "emb": rng.standard_normal((vocab, dim)).astype(_np.float32),
-        "w_k": (rng.standard_normal((dim, dim)) * s).astype(_np.float32),
-        "w_v": (rng.standard_normal((dim, dim)) * s).astype(_np.float32),
-        "w_out": (rng.standard_normal((dim, vocab)) * s).astype(_np.float32),
-    }
-
-
-def _lm_cache_spec(dim):
-    """``cache_spec`` of the built-in LM: twin float32 pools of width
-    ``dim``, ``{"k": ..., "v": ...}``."""
-    def cache_spec(num_blocks, block_size):
-        import jax
-        import jax.numpy as jnp
-        pool = jax.ShapeDtypeStruct((num_blocks, block_size, dim),
-                                    jnp.float32)
-        return {"k": pool, "v": pool}
-    return cache_spec
-
-
-def _lm_prefill(params, cache, tokens, start, length, table):
-    """Built-in prefill body (batch 1, bucketed prompt chunk).
-
-    ``tokens (L,) i32`` bucket-padded prompt chunk; ``start () i32``
-    global position of the chunk's first token; ``length () i32`` real
-    tokens in the chunk; ``table (MB,) i32`` the sequence's block table
-    padded with the null block. Writes K/V for global positions
-    ``start..start+length-1`` (padding rows scatter into the null
-    block), attends the chunk's last real token over
-    ``pos < start + length``, returns ``(next_id, cache, aux)``.
-    Whole-prompt prefill is the ``start=0`` call; chunked prefill calls
-    the SAME bucket program with advancing ``start`` — bit-identical
-    because masked lanes contribute exactly 0 and every attended
-    position already holds its final K/V bits."""
-    import jax
-    import jax.numpy as jnp
-    emb, w_k, w_v, w_out = (params["emb"], params["w_k"],
-                            params["w_v"], params["w_out"])
-    k_pages, v_pages = cache["k"], cache["v"]
-    bs = k_pages.shape[1]
-    dim = emb.shape[1]
-    mb = table.shape[0]
-    x = emb[tokens]                                     # (L, D)
-    k = x @ w_k
-    v = x @ w_v
-    idx = jnp.arange(tokens.shape[0], dtype=jnp.int32)
-    pos = jnp.clip(start + idx, 0, mb * bs - 1)
-    blk = jnp.where(idx < length, table[pos // bs], NULL_BLOCK)
-    k_pages = k_pages.at[blk, pos % bs].set(k)
-    v_pages = v_pages.at[blk, pos % bs].set(v)
-    x_last = jnp.take(x, length - 1, axis=0)            # (D,)
-    ks = k_pages[table].reshape(mb * bs, dim)
-    vs = v_pages[table].reshape(mb * bs, dim)
-    tpos = jnp.arange(mb * bs, dtype=jnp.int32)
-    scores = (ks @ x_last) * (1.0 / math.sqrt(dim))
-    scores = jnp.where(tpos < start + length, scores, _MASKED)
-    ctx = jax.nn.softmax(scores) @ vs
-    next_id = jnp.argmax(ctx @ w_out).astype(jnp.int32)
-    return next_id, {"k": k_pages, "v": v_pages}, {}
-
-
-def _lm_step(params, cache, token_ids, positions, tables, active):
-    """Built-in decode-step body (fixed batch shape, one program total).
-
-    ``token_ids (B,) i32`` last emitted token per row; ``positions (B,)
-    i32`` write position of that token; ``tables (B, MB) i32`` block
-    tables (inactive rows all-null); ``active (B,) bool``. Inactive
-    rows scatter into the null block and their outputs are discarded on
-    host. Every per-row computation contracts only over that row's own
-    gathered blocks — rows cannot observe each other, which is what
-    makes batched decode bit-identical to solo decode."""
-    import jax
-    import jax.numpy as jnp
-    emb, w_k, w_v, w_out = (params["emb"], params["w_k"],
-                            params["w_v"], params["w_out"])
-    k_pages, v_pages = cache["k"], cache["v"]
-    bs = k_pages.shape[1]
-    dim = emb.shape[1]
-    b, mb = tables.shape
-    x = emb[token_ids]                                  # (B, D)
-    k = x @ w_k
-    v = x @ w_v
-    blk = jnp.take_along_axis(tables, (positions // bs)[:, None], axis=1)
-    blk = jnp.where(active, blk[:, 0], NULL_BLOCK)
-    k_pages = k_pages.at[blk, positions % bs].set(k)
-    v_pages = v_pages.at[blk, positions % bs].set(v)
-    ks = k_pages[tables].reshape(b, mb * bs, dim)       # (B, T, D)
-    vs = v_pages[tables].reshape(b, mb * bs, dim)
-    tpos = jnp.arange(mb * bs, dtype=jnp.int32)[None, :]
-    scores = jnp.einsum("bd,btd->bt", x, ks) * (1.0 / math.sqrt(dim))
-    scores = jnp.where(tpos <= positions[:, None], scores, _MASKED)
-    ctx = jnp.einsum("bt,btd->bd", jax.nn.softmax(scores, axis=-1), vs)
-    next_ids = jnp.argmax(ctx @ w_out, axis=-1).astype(jnp.int32)
-    return next_ids, {"k": k_pages, "v": v_pages}, {}
-
 
 class DecodeStream:
     """Handle for one decode request: tokens appear incrementally, the
@@ -301,9 +182,11 @@ class DecodeEngine:
 
     Parameters
     ----------
-    params : dict of arrays
-        Model parameters (see :func:`tiny_lm_params` for the built-in
-        LM's keys; opaque pytree for custom ``prefill_fn``/``step_fn``).
+    params : pytree of arrays
+        Model parameters, opaque to the engine: handed to the bodies.
+    prefill_fn, step_fn, cache_spec : callables, required
+        The model (module docstring, "The cache seam"): a family's
+        ``engine_kwargs()`` brings them with ``params``.
     eos_id : int or None
         Token id that terminates a sequence (emitted, then retired).
     block_size / num_blocks : int
@@ -320,16 +203,6 @@ class DecodeEngine:
     default_deadline_ms : float or None
         Deadline applied when ``submit`` passes none
         (``MXNET_SERVING_DECODE_DEADLINE_MS``; unset/0 = no deadline).
-    cache_spec : callable or None
-        ``cache_spec(num_blocks, block_size)`` returns the cache as a
-        pytree of ``jax.ShapeDtypeStruct``, one leaf per pool: the model
-        states shapes and dtypes, the engine builds, places, donates and
-        AOT-describes whatever it is and hands it to the bodies whole.
-        Default: the built-in LM's twin float32 pools ``{"k", "v"}`` of
-        ``(num_blocks, block_size, model_dim)``. The transformer family
-        is layer-major, ``(num_layers, num_blocks, block_size,
-        d_model)``; the latent-attention family holds one bfloat16 pool
-        of latent rows, ``(num_layers, num_blocks, block_size, 640)``.
     prefill_chunk : int or None
         Chunked-prefill piece size
         (``MXNET_SERVING_DECODE_PREFILL_CHUNK``; 0 disables). Resolved
@@ -347,11 +220,11 @@ class DecodeEngine:
     construction so the loop never compiles.
     """
 
-    def __init__(self, params, *, name="decode", eos_id=None,
+    def __init__(self, params, *, prefill_fn, step_fn, cache_spec,
+                 name="decode", eos_id=None,
                  block_size=None, num_blocks=None, batch_size=None,
                  max_seq_len=None, prefill_buckets=None,
                  default_deadline_ms=_MISSING, default_max_new=None,
-                 prefill_fn=None, step_fn=None, cache_spec=None,
                  prefill_chunk=None, mesh=None, kv_shard_axis="tp",
                  warmup=True, autostart=True):
         import jax
@@ -400,10 +273,6 @@ class DecodeEngine:
 
         self._kv = PagedKVCache(num_blocks, block_size)
         self._mb = self._kv.blocks_for(self.max_seq_len)  # table width
-        if cache_spec is None:
-            dim = int(params["emb"].shape[1]) if "emb" in params else int(
-                next(iter(params.values())).shape[-1])
-            cache_spec = _lm_cache_spec(dim)
         spec = cache_spec(self._kv.num_blocks, self._kv.block_size)
         self._params = jax.device_put(
             jax.tree_util.tree_map(jnp.asarray, params))
@@ -441,10 +310,10 @@ class DecodeEngine:
         # to XLA where the backend supports it (not host CPU)
         donate = (1,) if _donate_supported() else ()
         self._prefill_b = ProgramBuilder(
-            prefill_fn or _lm_prefill, site="decode.prefill.%s" % name,
+            prefill_fn, site="decode.prefill.%s" % name,
             donate_argnums=donate)
         self._step_b = ProgramBuilder(
-            step_fn or _lm_step, site="decode.step.%s" % name,
+            step_fn, site="decode.step.%s" % name,
             donate_argnums=donate)
 
         self._cv = threading.Condition()

@@ -11,18 +11,10 @@ per ``block_size`` tokens of its history), and blocks come from a
 free-list allocator. HBM then scales with *live tokens*, not with the
 worst case, and the accounting counters below prove it.
 
-Layout contract (shared with serving/decode.py's programs):
-
-- token at absolute position ``p`` of a sequence lives at
-  ``pages[table[p // block_size], p % block_size]``; a model with
-  several layers keeps one such pool per layer, stacked layer-major
-  (``pages[l, table[p // block_size], p % block_size]``), so a layer's
-  read gathers only its own pages;
-- **block 0 is the null block**: never allocated, never owned. Device
-  programs route every *inactive* or *padding* write to block 0 and
-  real reads never touch it (attention masks by sequence length), so a
-  fixed-shape scatter over a partially-active batch cannot alias a live
-  sequence's state. The allocator hands out ids ``1..num_blocks-1``.
+The layout contract (where position ``p`` lives, and **block 0, the null
+block**: never allocated, the target of every inactive or padding write)
+is `kernels/paged_attention.py`'s, which the models' programs use. The
+allocator hands out ids ``1..num_blocks-1``.
 
 Allocation failure raises the typed :class:`CacheOverflow` — a
 :class:`~.batcher.DeadlineExceeded` subclass, so every existing shed
@@ -35,12 +27,10 @@ only copies ints).
 """
 from __future__ import annotations
 
+from ..kernels.paged_attention import NULL_BLOCK
 from .batcher import DeadlineExceeded
 
 __all__ = ["PagedKVCache", "CacheOverflow", "NULL_BLOCK", "page_sharding"]
-
-#: Block id reserved for padding/inactive scatter targets. Never allocated.
-NULL_BLOCK = 0
 
 
 def page_sharding(mesh, page_shape, axis_name="tp"):

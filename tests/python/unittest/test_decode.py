@@ -26,7 +26,25 @@ import pytest
 
 from mxnet_tpu.serving import (ModelServer, ServingFrontDoor, ServingClient,
                                DeadlineExceeded, DecodeEngine, PagedKVCache,
-                               CacheOverflow, NULL_BLOCK, tiny_lm_params)
+                               CacheOverflow, NULL_BLOCK)
+from mxnet_tpu.models.tiny_lm import TinyLMDecodeModel
+
+
+def _lm():
+    return TinyLMDecodeModel().engine_kwargs()
+
+
+def _tf_walk_sizes(B, mb, bs):
+    """The GPT-2 family's walk sizes: the shared rule at its constants."""
+    from mxnet_tpu.kernels.paged_attention import walk_sizes
+    from mxnet_tpu.models.transformer import _WALK_ROWS, _WALK_SPAN
+    return walk_sizes(B, mb, bs, _WALK_ROWS, _WALK_SPAN)
+
+
+def _tf_walk_plan(positions, tables, bs):
+    from mxnet_tpu.kernels.paged_attention import walk_plan
+    from mxnet_tpu.models.transformer import _WALK_ROWS, _WALK_SPAN
+    return walk_plan(positions, tables, bs, _WALK_ROWS, _WALK_SPAN)
 
 
 # ---------------------------------------------------------------------------
@@ -111,7 +129,7 @@ def _engine(**kw):
     kw.setdefault("batch_size", 4)
     kw.setdefault("max_seq_len", 64)
     kw.setdefault("prefill_buckets", (8, 16))
-    return DecodeEngine(tiny_lm_params(), **kw)
+    return DecodeEngine(**_lm(), **kw)
 
 
 class TestDecodeEngine:
@@ -338,7 +356,7 @@ class TestTransformerDecode:
         ONE layer's view of the block table, and no gather of the step is
         larger than one PIECE of the walk (rows per block x span)."""
         import jax
-        from mxnet_tpu.models.transformer import _WALK_ROWS, _walk_sizes
+        from mxnet_tpu.models.transformer import _WALK_ROWS
         model = _tf_model(num_layers=num_layers)
         eng = _tf_engine(model, "tfg%d" % num_layers, warmup=False,
                          autostart=False)
@@ -360,7 +378,7 @@ class TestTransformerDecode:
         # the step at the engine's shapes, and at shapes that split both
         # ways (several row blocks, several pieces a table)
         for rows, blocks in ((b, mb), (4 * _WALK_ROWS, 1024 // bs)):
-            rb, cb = _walk_sizes(rows, blocks, bs)
+            rb, cb = _tf_walk_sizes(rows, blocks, bs)
             if rows > b:
                 assert rb < rows and cb < blocks
             # the reads of the pools: K and V of a layer each gather one
@@ -445,7 +463,7 @@ class TestTransformerDecode:
         from mxnet_tpu.models import transformer as tf
         rows = tf._WALK_ROWS * row_blocks
         model, cache, *args = self._walk_case(rows)
-        rb, cb = tf._walk_sizes(rows, args[2].shape[1], 16)
+        rb, cb = _tf_walk_sizes(rows, args[2].shape[1], 16)
         assert rows // rb == row_blocks and args[2].shape[1] // cb >= 2
         ids, new_cache, _ = jax.jit(model.step_fn)(model.params, cache,
                                                    *args)
@@ -461,7 +479,7 @@ class TestTransformerDecode:
         assert (top2[:, 1] - top2[:, 0]).min() > 1e-4
         assert (ids == best).all()
         # layer contexts: the walk's own attention against the formula's
-        plan, _ = tf._walk_plan(args[1], args[2], 16)
+        plan = _tf_walk_plan(args[1], args[2], 16)
         lp0 = {k: v[0] for k, v in model.params["layers"].items()}
         x = model.params["embed"][args[0]] + model.params["pos_embed"][args[1]]
         q = tf._layer_norm(x, lp0["ln1_scale"], lp0["ln1_bias"]) @ lp0["wq"]
@@ -485,7 +503,7 @@ class TestTransformerDecode:
 
         @jax.jit
         def attend(q, positions, tables):
-            plan, _ = tf._walk_plan(positions, tables, 16)
+            plan = _tf_walk_plan(positions, tables, 16)
             return tf._live_attention(q, cache["k"], cache["v"], 1, plan,
                                       model.cfg.num_heads)
 
@@ -545,7 +563,7 @@ class TestTransformerDecode:
         pair is what the (8,128) tile pads from 768 lanes to 2,048."""
         import jax
         from mxnet_tpu.models.transformer import (
-            TransformerConfig, TransformerDecodeModel, _walk_sizes)
+            TransformerConfig, TransformerDecodeModel)
         cfg = TransformerConfig(vocab_size=128, num_layers=2, num_heads=12,
                                 d_model=768, d_ff=64, max_len=1024)
         sd = jax.ShapeDtypeStruct
@@ -553,7 +571,7 @@ class TestTransformerDecode:
             lambda: TransformerDecodeModel(cfg, flash="off").params)
         model = TransformerDecodeModel(cfg, params=params, flash="off")
         B, mb, bs = 64, 64, 16
-        rb, cb = _walk_sizes(B, mb, bs)
+        rb, cb = _tf_walk_sizes(B, mb, bs)
         assert rb < B and cb < mb
         i32 = np.int32
         jaxpr = jax.make_jaxpr(model.step_fn)(
@@ -635,7 +653,7 @@ def _gateway(**engine_kw):
     engine_kw.setdefault("batch_size", 4)
     engine_kw.setdefault("max_seq_len", 64)
     engine_kw.setdefault("prefill_buckets", (16,))
-    eng = DecodeEngine(tiny_lm_params(), name="lm", **engine_kw)
+    eng = DecodeEngine(**_lm(), name="lm", **engine_kw)
     srv = ModelServer()
     srv.register_decode("lm", eng)
     fd = ServingFrontDoor(srv, port=0).start()
@@ -721,9 +739,9 @@ class TestWireStreaming:
     def test_pinning_routes_same_sequence_to_same_replica(self):
         """Stateful dispatch: the same pin lands on the same replica
         (its KV state lives there); hedging never sees decode."""
-        a = DecodeEngine(tiny_lm_params(), name="lm", num_blocks=32,
+        a = DecodeEngine(**_lm(), name="lm", num_blocks=32,
                          batch_size=2, max_seq_len=32, prefill_buckets=(8,))
-        b = DecodeEngine(tiny_lm_params(), name="lm", num_blocks=32,
+        b = DecodeEngine(**_lm(), name="lm", num_blocks=32,
                          batch_size=2, max_seq_len=32, prefill_buckets=(8,))
         srv = ModelServer()
         srv.register_decode("lm", a)

@@ -1,7 +1,7 @@
 """The latent-attention expert decoder (mxnet_tpu/models/moe_mla.py,
 parallel/moe.py::routed_experts) against its plain reference's copy
-(pangu_umoe_reference.py, the same file as benchmark/cells/references/
-pangu_umoe.py), at a tiny preset in float32 on the CPU.
+(pangu_umoe_reference.py loads benchmark/cells/references/pangu_umoe.py by
+path), at a tiny preset in float32 on the CPU.
 
 Tolerance 1e-4 on logits of magnitude about 7: program and reference run the
 same float32 arithmetic in a different association (absorbed against expanded
@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 import pangu_umoe_reference as ref
+from mxnet_tpu.kernels.paged_attention import walk_plan
 from mxnet_tpu.models import moe_mla as M
 from mxnet_tpu.models.moe_mla import (MoEMLAConfig, MoEMLADecodeModel,
                                       init_moe_mla, moe_mla_forward)
@@ -59,9 +60,11 @@ def tokens_of(seed, shape):
 
 
 def test_the_tests_reference_is_the_benchmarks_file():
-    with open(os.path.join(REPO, "benchmark", "cells", "references",
-                           "pangu_umoe.py")) as a, open(ref.__file__) as b:
-        assert a.read() == b.read()
+    """Not a copy of it: what `ref` holds was defined by that very file."""
+    path = os.path.join(REPO, "benchmark", "cells", "references",
+                        "pangu_umoe.py")
+    for fn in (ref.init_params, ref.logits_at):
+        assert os.path.samefile(fn.__code__.co_filename, path)
 
 
 def test_config_from_the_published_keys_and_the_cut():
@@ -238,9 +241,10 @@ def test_absorbed_step_equals_expanded_attention(params):
     table = jnp.asarray([5, 2, 7, 0], jnp.int32)
     pool = pool.at[1, table[pos // bs], pos % bs].set(
         M._cache_rows(rows, pool))
+    plan = walk_plan(pos[-1:], table[None], bs, cfg.step_row_block,
+                     cfg.step_col_blocks * bs)
     got = M._absorbed_attention(
-        cfg, lp, q_nope[-1:], q_rope[-1:], pool, 1, table[None],
-        pos[-1:])                                         # [1, H * dv]
+        cfg, lp, q_nope[-1:], q_rope[-1:], pool, 1, plan)  # [1, H * dv]
     k, v = M._mla_expand(cfg, rows, *M._expansion_weights(cfg, lp))
     q = jnp.concatenate([q_nope, q_rope], -1)[-1]         # [H, dn + dr]
     s = jnp.einsum("hd,htd->ht", q, k) / np.sqrt(q.shape[-1])

@@ -208,12 +208,13 @@ def test_gpt2_decode_step_keeps_heads_in_lanes(one_chip):
     and no whole-table buffer: its temporaries stay far under one row
     block's gathered table. No Pallas kernel: the walk is plain XLA."""
     import re
+    from mxnet_tpu.kernels.paged_attention import walk_sizes
     from mxnet_tpu.models.transformer import (
-        TransformerConfig, TransformerDecodeModel, _walk_sizes)
+        TransformerConfig, TransformerDecodeModel, _WALK_ROWS, _WALK_SPAN)
     cfg = TransformerConfig(vocab_size=1024, num_layers=2, num_heads=12,
                             d_model=768, d_ff=3072, max_len=1024)
     B, mb, bs = 64, 64, 16
-    rb, cb = _walk_sizes(B, mb, bs)
+    rb, cb = walk_sizes(B, mb, bs, _WALK_ROWS, _WALK_SPAN)
     on_chip = lambda a: jax.ShapeDtypeStruct(                  # noqa: E731
         a.shape, a.dtype, sharding=one_chip)
     params = jax.tree_util.tree_map(on_chip, jax.eval_shape(

@@ -46,8 +46,9 @@ if "--xla_force_host_platform_device_count" not in \
         os.environ.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=8").strip()
 
+from mxnet_tpu.models.tiny_lm import TinyLMDecodeModel  # noqa: E402
 from mxnet_tpu.serving import (ModelServer, ServingFrontDoor,  # noqa: E402
-                               DecodeEngine, tiny_lm_params)
+                               DecodeEngine)
 
 # Client subprocess body: a REAL ServingClient in a REAL second OS
 # process streaming decodes — the acceptance criteria are cross-process
@@ -108,14 +109,14 @@ print(json.dumps(out))
 
 
 def main():
-    params = tiny_lm_params()
+    lm = TinyLMDecodeModel().engine_kwargs()
     # healthy engine: pool comfortably covers the traffic
-    eng = DecodeEngine(params, name="lm", num_blocks=64, batch_size=4,
+    eng = DecodeEngine(**lm, name="lm", num_blocks=64, batch_size=4,
                        max_seq_len=96, prefill_buckets=(16,))
     # starved engine: 2 usable blocks x 4 tokens = 8-token capacity, so
     # a 10-token prompt can never fit and a 5-token prompt overflows
     # mid-generation — both must shed typed across the wire
-    tiny = DecodeEngine(params, name="tiny", block_size=4, num_blocks=3,
+    tiny = DecodeEngine(**lm, name="tiny", block_size=4, num_blocks=3,
                         batch_size=2, max_seq_len=64, prefill_buckets=(16,))
     srv = ModelServer()
     srv.register_decode("lm", eng)

@@ -95,8 +95,10 @@ def capture_corpus():
         cli.list_models()
         # stateful-decode leg: decode request + streamed stok frames +
         # terminal sdone cross the tap (ISSUE 18 stream frames)
-        from mxnet_tpu.serving import DecodeEngine, tiny_lm_params
-        eng = DecodeEngine(tiny_lm_params(), name="fz_lm", num_blocks=16,
+        from mxnet_tpu.models.tiny_lm import TinyLMDecodeModel
+        from mxnet_tpu.serving import DecodeEngine
+        eng = DecodeEngine(**TinyLMDecodeModel().engine_kwargs(),
+                           name="fz_lm", num_blocks=16,
                            batch_size=2, max_seq_len=64,
                            prefill_buckets=(16,))
         srv.register_decode("fz_lm", eng)
