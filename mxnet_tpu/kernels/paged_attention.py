@@ -182,6 +182,31 @@ def softmax_fold(carry, s, tpos, pos_b, axis, weigh):
             acc * alpha[..., None] + weigh(p))
 
 
+def chunk_spans(chunk, table_len, floor=1024):
+    """The static widths a prefill chunk's keys and values may be made
+    over: ``max(chunk, floor)`` doubled while under ``table_len``, then
+    ``table_len`` itself, so every ``start + length`` the table holds is
+    served and a program carries a few branches, not one a bucket. A
+    causal chunk reads positions ``0 .. start + length - 1`` only (the
+    flash kernel skips the tiles beyond, the lax tier masks them): what a
+    family makes of the rows past its span nobody reads. One span, and no
+    branch, where the table is no longer than the first."""
+    spans = []
+    s = max(chunk, floor)
+    while s < table_len:
+        spans.append(s)
+        s *= 2
+    return tuple(spans) + (table_len,)
+
+
+def span_index(spans, end):
+    """Which of `chunk_spans` serves a chunk whose last real position is
+    ``end - 1`` (``end = start + length``, traced): the smallest span that
+    holds ``end`` positions."""
+    return jnp.sum(jnp.asarray(end, jnp.int32)
+                   > jnp.asarray(spans[:-1], jnp.int32)).astype(jnp.int32)
+
+
 def chunk_attention(q, k, v, start, sm_scale, block_k, use_pallas,
                     interpret, variant="grid"):
     """Causal attention of a prefill chunk over its sequence's gathered

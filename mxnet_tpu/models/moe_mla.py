@@ -41,6 +41,7 @@ Device-side names (`jax.named_scope`): ``mla``, ``moe.route``,
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import numpy as _np
 import jax
@@ -246,9 +247,9 @@ def _cache_rows(rows, pool):
                    ((0, 0), (0, pool.shape[-1] - rows.shape[-1])))
 
 
-def _expansion_weights(cfg, lp, k_width=None, v_width=None):
-    """The up-projection as two matrices that expand latent rows straight
-    into the attention kernels' layout: ``w_k`` ``[rkv + dr, H, k_width]``
+def _expansion_weights(cfg, wkv_b, k_width=None, v_width=None):
+    """The up-projection ``wkv_b`` as two matrices that expand latent rows
+    straight into the attention kernels' layout: ``w_k`` ``[rkv + dr, H, k_width]``
     gives a key ``[k_nope | k_rope | 0]`` (an identity block carries the
     row's rotary part, one for all heads, into its columns: a product with
     1.0, exact), ``w_v`` ``[rkv, H, v_width]`` a value ``[v | 0]``. The
@@ -260,8 +261,8 @@ def _expansion_weights(cfg, lp, k_width=None, v_width=None):
                           cfg.kv_lora_rank)
     k_width = dn + dr if k_width is None else k_width
     v_width = dv if v_width is None else v_width
-    dt = lp["wkv_b"].dtype          # operands of the attention products
-    w_kvb = lp["wkv_b"].reshape(rkv, H, dn + dv)
+    dt = wkv_b.dtype                # operands of the attention products
+    w_kvb = wkv_b.reshape(rkv, H, dn + dv)
     carry = jnp.pad(jnp.broadcast_to(jnp.eye(dr, dtype=dt)[:, None, :],
                                      (dr, H, dr)),
                     ((0, 0), (0, 0), (dn, k_width - dn - dr)))
@@ -284,29 +285,35 @@ def _mla_expand(cfg, rows, w_k, w_v):
     return k, v
 
 
-def _attend_expanded(cfg, lp, q, rows, start, use_pallas, interpret):
+@functools.partial(jax.jit, static_argnums=(0, 5, 6))
+def _attend_expanded(cfg, wkv_b, q, rows, start, use_pallas, interpret):
     """Causal attention of queries ``q`` ``[C, H, dn + dr]`` at global
     positions ``start + i`` over the keys and values that the latent
-    ``rows`` ``[T, .]`` at positions ``0..T-1`` expand to
-    (`paged_attention.chunk_attention`: the flash kernel or the blockwise
-    lax tier). The kernels want ONE head width, a multiple of the lane
-    count: q and k (192 wide as published) and v (128) are zero-padded to
-    it and the output is cut back: layout only, a zero column adds nothing
-    to a score or to a value. (Expanding only the live positions, a group
-    at a time into buffers carried from layer to layer, was tried and lost:
-    the loop's buffers take another layout than the kernel's and are copied
-    into it, PERF.md PR 28.)"""
+    ``rows`` ``[T, .]`` at positions ``0..T-1`` expand to through the
+    layer's ``wkv_b`` (`paged_attention.chunk_attention`: the flash kernel
+    or the blockwise lax tier). The kernels want ONE head width, a multiple
+    of the lane count: q and k (192 wide as published) and v (128) are
+    zero-padded to it and the output is cut back: layout only, a zero
+    column adds nothing to a score or to a value. (Expanding only the live
+    positions, a group at a time into buffers carried from layer to layer,
+    was tried and lost: the loop's buffers take another layout than the
+    kernel's and are copied into it, PERF.md PR 28.)
+
+    Jitted, though it only ever runs inside a program: the layers of a
+    program then share ONE trace and ONE lowering of the kernel for each
+    ``T``, where every call of its own cost half a second of set-up on the
+    chip's host (PERF.md PR 32). The compiler inlines the calls."""
     dqk, dv = q.shape[-1], cfg.v_head_dim
     q = q.transpose(1, 0, 2)
     if use_pallas or interpret:
         w = -(-max(dqk, dv) // _LANES) * _LANES if use_pallas \
             else max(dqk, dv)
-        k, v = _mla_expand(cfg, rows, *_expansion_weights(cfg, lp, w, w))
+        k, v = _mla_expand(cfg, rows, *_expansion_weights(cfg, wkv_b, w, w))
         q = jnp.pad(q.astype(k.dtype), ((0, 0), (0, 0), (0, w - dqk)))
     else:
         # the lax tier keeps its online softmax in its operands' dtype:
         # hand it float32 (the kernels accumulate in float32 themselves)
-        k, v = _mla_expand(cfg, rows, *_expansion_weights(cfg, lp))
+        k, v = _mla_expand(cfg, rows, *_expansion_weights(cfg, wkv_b))
         q, k, v = (t.astype(jnp.float32) for t in (q, k, v))
     out = paged.chunk_attention(q, k, v, start, 1.0 / _np.sqrt(dqk),
                                 cfg.block_k, use_pallas, interpret)
@@ -373,8 +380,8 @@ def moe_mla_forward(params, cfg, tokens, *, use_pallas=False,
             def attend(h, lp=lp):
                 q_nope, q_rope, rows = _mla_project(cfg, lp, h, pos)
                 q = jnp.concatenate([q_nope, q_rope], -1)
-                o = _attend_expanded(cfg, lp, q, rows, 0, use_pallas,
-                                     interpret)
+                o = _attend_expanded(cfg, lp["wkv_b"], q, rows, 0,
+                                     use_pallas, interpret)
                 return o.reshape(S, -1)
             x, _ = _block(cfg, lp, x, attend)
         return _logits(cfg, params, x)
@@ -391,15 +398,27 @@ def moe_mla_decode_prefill(params, cfg, cache, tokens, start, length, table,
                            with_logits=False):
     """Bucketed batch-1 prefill chunk: write the latent rows of global
     positions ``start .. start+length-1`` into ``cache["latent"]``, expand
-    keys and values of the sequence's whole table from the latent rows,
-    attend causally, return the greedy next token after the chunk's last
-    real position. The DecodeEngine prefill seam ``(params, cache, tokens,
-    start, length, table) -> (next_id, cache, aux)``; ``with_logits``
-    (tests) appends that position's float32 logits."""
+    keys and values from the sequence's latent rows over the chunk's live
+    span, attend causally, return the greedy next token after the chunk's
+    last real position. The DecodeEngine prefill seam ``(params, cache,
+    tokens, start, length, table) -> (next_id, cache, aux)``;
+    ``with_logits`` (tests) appends that position's float32 logits.
+
+    The chunk reads positions ``0 .. start + length - 1`` of a table that
+    holds ``max_seq_len``: keys and values are made over the smallest of
+    `paged_attention.chunk_spans` that holds them, a static width chosen on
+    the device (``lax.switch``; the last branch is the whole table). The
+    write and the gather of the table's rows stay OUTSIDE the branches: the
+    pool is never a branch's operand. ``aux`` counts what was asked for and
+    what was made, ``prefill_kv_live_tokens`` and
+    ``prefill_kv_expanded_tokens``, a piece once (not a layer)."""
     pool = cache["latent"]                  # [L, blocks, bs, rkv + dr]
     C, width = tokens.shape[0], pool.shape[3]
     pos, valid, blk, slot = paged.chunk_addresses(table, start, length, C,
                                                   pool.shape[2])
+    end = start + length
+    spans = paged.chunk_spans(C, table.shape[0] * pool.shape[2])
+    which = paged.span_index(spans, end)
     x = params["embed"][tokens].astype(jnp.float32)
     all_counts = []
     for l, lp in enumerate(params["layers"]):
@@ -410,16 +429,22 @@ def moe_mla_decode_prefill(params, cfg, cache, tokens, start, length, table,
                 pool = pool.at[l, blk, slot].set(_cache_rows(rows, pool))
                 q = jnp.concatenate([q_nope, q_rope], -1)
                 seen = paged.gather_pages(pool, l, table).reshape(-1, width)
-                o = _attend_expanded(cfg, lp, q, seen, start, use_pallas,
-                                     interpret)
-                return o.reshape(C, -1)
+
+                def over(span):
+                    return lambda q, seen: _attend_expanded(
+                        cfg, lp["wkv_b"], q, seen[:span], start, use_pallas,
+                        interpret).reshape(C, -1)
+                # (one span: `lax.switch` calls it, and builds no branch)
+                return lax.switch(which, [over(s) for s in spans], q, seen)
             x, counts = _block(cfg, lp, x, attend, valid)
             if counts is not None:
                 all_counts.append(counts)
     x_last = jnp.take(x, jnp.clip(length - 1, 0, C - 1), axis=0)
     logits = _logits(cfg, params, x_last)
-    out = (jnp.argmax(logits).astype(jnp.int32), {"latent": pool},
-           _aux(cfg, all_counts, "prefill_"))
+    aux = _aux(cfg, all_counts, "prefill_")
+    aux["prefill_kv_live_tokens"] = jnp.asarray(end, jnp.int32)
+    aux["prefill_kv_expanded_tokens"] = jnp.asarray(spans, jnp.int32)[which]
+    out = (jnp.argmax(logits).astype(jnp.int32), {"latent": pool}, aux)
     return out + (logits,) if with_logits else out
 
 

@@ -9,7 +9,9 @@ attention, a grouped product against a masked dense one, blockwise softmax),
 which costs a few 1e-6; the same comparison with bfloat16 operands reads 1e-2
 and more, and one test holds that it FAILS the tolerance.
 """
+import functools
 import os
+from unittest import mock
 
 import numpy as np
 import pytest
@@ -18,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 import pangu_umoe_reference as ref
+from mxnet_tpu.kernels import paged_attention as paged
 from mxnet_tpu.kernels.paged_attention import walk_plan
 from mxnet_tpu.models import moe_mla as M
 from mxnet_tpu.models.moe_mla import (MoEMLAConfig, MoEMLADecodeModel,
@@ -129,7 +132,7 @@ class Recorder:
             use_pallas=False, interpret=self.model.interpret,
             with_logits=True)
         jax.debug.callback(self._keep("prefill"), table, start + length,
-                           logits)
+                           logits, aux["prefill_kv_expanded_tokens"])
         return nid, cache, aux
 
     def step_fn(self, params, cache, token_ids, positions, tables, active):
@@ -145,24 +148,35 @@ class Recorder:
                     step_fn=self.step_fn)
 
 
-def serve(params, name, flash="0", new_tokens=6):
-    """Serve PROMPTS together through a real engine; returns (prompts with
+def serve(params, name, flash="0", new_tokens=6, prompts=PROMPTS, **engine):
+    """Serve prompts of the lengths ``prompts`` together through a real
+    engine (``engine`` overrides its tiny geometry); returns (prompts with
     their outputs, the recorder, the engine's closing stats)."""
     rec = Recorder(MoEMLADecodeModel(cfg_of(TINY), params=params,
                                      flash=flash))
-    eng = DecodeEngine(**rec.engine_kwargs(), name=name, block_size=4,
-                       num_blocks=64, batch_size=4, max_seq_len=64,
-                       prefill_buckets=(8, 16), prefill_chunk=16,
-                       default_deadline_ms=None)
-    assert eng.program_counts() == (2, 1)
-    prompts = [list(tokens_of(10 + i, (n,))) for i, n in enumerate(PROMPTS)]
+    engine = dict(dict(block_size=4, num_blocks=64, batch_size=4,
+                       max_seq_len=64, prefill_buckets=(8, 16),
+                       prefill_chunk=16), **engine)
+    eng = DecodeEngine(**rec.engine_kwargs(), name=name,
+                       default_deadline_ms=None, **engine)
+    family = (len(engine["prefill_buckets"]), 1)
+    assert eng.program_counts() == family
+    prompts = [list(tokens_of(10 + i, (n,))) for i, n in enumerate(prompts)]
     streams = [eng.submit(p, max_new_tokens=new_tokens) for p in prompts]
     outs = [s.result_wait(120.0) for s in streams]
     jax.effects_barrier()
     stats = eng.stats()
-    assert eng.program_counts() == (2, 1)       # nothing compiled in service
+    assert eng.program_counts() == family       # nothing compiled in service
     eng.stop()
     return list(zip(prompts, outs)), rec, stats
+
+
+def prefill_spans(rec):
+    """``(start + length, positions expanded)`` of every prefill piece the
+    recorder saw."""
+    return [(int(end), int(expanded))
+            for kind, _, end, _, expanded in
+            (a for a in rec.seen if a[0] == "prefill")]
 
 
 def worst_logit_gap(params32, served, rec):
@@ -178,7 +192,7 @@ def worst_logit_gap(params32, served, rec):
         want[i] = np.asarray(ref.logits_at(TINY, params32, toks, pos))[0]
     for kind, *arrays in rec.seen:
         if kind == "prefill":
-            table, end, logits = arrays
+            table, end, logits, _ = arrays
             rows = [(table, int(end) - 1, logits)]
         else:
             tables, positions, active, logits = arrays
@@ -245,11 +259,103 @@ def test_absorbed_step_equals_expanded_attention(params):
                      cfg.step_col_blocks * bs)
     got = M._absorbed_attention(
         cfg, lp, q_nope[-1:], q_rope[-1:], pool, 1, plan)  # [1, H * dv]
-    k, v = M._mla_expand(cfg, rows, *M._expansion_weights(cfg, lp))
+    k, v = M._mla_expand(cfg, rows, *M._expansion_weights(cfg, lp["wkv_b"]))
     q = jnp.concatenate([q_nope, q_rope], -1)[-1]         # [H, dn + dr]
     s = jnp.einsum("hd,htd->ht", q, k) / np.sqrt(q.shape[-1])
     want = jnp.einsum("ht,htd->hd", jax.nn.softmax(s, -1), v).reshape(1, -1)
     assert np.abs(np.asarray(got - want)).max() < 1e-5
+
+
+# ---------------------------------------------------------------------------
+# the prefill's live span, at the served cell's geometry and the tiny widths
+# ---------------------------------------------------------------------------
+LONG_T, LONG_BS = 4096, 16       # spans (1024, 2048, 4096) for every bucket
+
+
+@functools.lru_cache(maxsize=None)
+def long_prefill(bucket, tier, whole):
+    """The jitted prefill of one bucket over a 4,096-position table; with
+    ``whole`` the keys and values are made over the whole table, as before
+    there were spans."""
+    cfg = MoEMLAConfig.from_dict(TINY, block_k=256)
+
+    def fn(params, cache, tokens, start, length, table):
+        spans = (lambda C, T: (T,)) if whole else paged.chunk_spans
+        with mock.patch.object(paged, "chunk_spans", spans):
+            return M.moe_mla_decode_prefill(
+                params, cfg, cache, tokens, start, length, table,
+                interpret=tier == "interpret", with_logits=True)
+    return jax.jit(fn)
+
+
+@functools.lru_cache(maxsize=None)
+def long_cache():
+    """A table of 256 shuffled blocks and a pool whose EVERY row holds
+    numbers: what lies past a chunk's end is stale, not zero."""
+    rng = np.random.default_rng(5)
+    mb = LONG_T // LONG_BS
+    table = (1 + rng.permutation(mb)).astype(np.int32)
+    pool = rng.standard_normal((3, mb + 1, LONG_BS, 128)).astype(np.float32)
+    pool[..., 24:] = 0.0                        # the pad of a latent row
+    return table, pool
+
+
+@pytest.mark.parametrize("tier", ["lax", "interpret"])
+@pytest.mark.parametrize("bucket,start,length,span", [
+    (256, 0, 5, 1024),              # a short first piece
+    (256, 768, 256, 1024),          # ends ON the first span
+    (1024, 0, 1024, 1024),          # fills it from the start
+    (256, 1024, 1, 2048),           # one past it
+    (512, 1000, 25, 2048),          # one past it, start on no boundary
+    (1024, 1024, 1024, 2048),       # ends ON the second span
+    (512, 2048, 1, 4096),           # one past it
+    (1024, 2048, 1000, 4096),       # a third piece
+    (256, 3840, 256, 4096),         # ends ON the table's end
+    (1024, 3072, 1024, 4096),
+])
+def test_prefill_over_the_live_span_equals_the_whole_table(
+        params, tier, bucket, start, length, span):
+    """Next id, logits and every written cache row of a chunk whose keys and
+    values are made over the chosen span equal those made over the whole
+    table; the counters say which span it was."""
+    table, pool = long_cache()
+    toks = np.zeros((bucket,), np.int32)
+    toks[:length] = tokens_of(start + length, (length,))
+    args = (params, {"latent": jnp.asarray(pool)}, toks, np.int32(start),
+            np.int32(length), table)
+    nid, cache, aux, logits = long_prefill(bucket, tier, False)(*args)
+    nid_w, cache_w, aux_w, logits_w = long_prefill(bucket, tier, True)(*args)
+    assert int(aux["prefill_kv_live_tokens"]) == start + length
+    assert int(aux["prefill_kv_expanded_tokens"]) == span
+    assert int(aux_w["prefill_kv_expanded_tokens"]) == LONG_T
+    assert int(nid) == int(nid_w)
+    assert np.abs(np.asarray(logits_w)).max() > 1.0
+    assert np.abs(np.asarray(logits - logits_w)).max() < TOL
+    got, want = np.asarray(cache["latent"]), np.asarray(cache_w["latent"])
+    at = start + np.arange(length)
+    written = want[:, table[at // LONG_BS], at % LONG_BS]
+    assert np.abs(written - pool[:, table[at // LONG_BS],
+                                 at % LONG_BS]).max() > 0.1
+    assert np.abs(got - want).max() < TOL
+
+
+def test_a_prompt_of_three_pieces_takes_a_span_a_piece(params):
+    """Through a real engine at the served cell's geometry: a prompt of
+    2,085 tokens is prefilled as 1,024 + 1,024 + 37, its keys and values
+    made over 1,024, 2,048 and 4,096 positions; every piece and step agrees
+    with the reference's full forward."""
+    served, rec, stats = serve(
+        params, "mlaspans", new_tokens=3, prompts=[2085, 300],
+        block_size=LONG_BS, num_blocks=160, batch_size=2,
+        max_seq_len=LONG_T, prefill_buckets=(256, 512, 1024),
+        prefill_chunk=1024)
+    seen = sorted(prefill_spans(rec))
+    assert seen == [(300, 1024), (1024, 1024), (2048, 2048), (2085, 4096)]
+    m = stats["model"]
+    assert m["prefill_kv_live_tokens"] == sum(e for e, _ in seen)
+    assert m["prefill_kv_expanded_tokens"] == sum(x for _, x in seen)
+    worst, n = worst_logit_gap(params, served, rec)
+    assert n >= 4 + 4 and worst < TOL, worst
 
 
 # ---------------------------------------------------------------------------
@@ -352,6 +458,15 @@ def test_engine_aux_counters_equal_a_numpy_count(params):
     # cached tokens attended over: a row at position p reads p + 1
     live = sum(len(p) + i + 1 for p, o in served for i in range(len(o) - 1))
     assert m["kv_live_tokens"] == live
+    # what the prefill pieces attended over, and what their keys and values
+    # were made over: a piece once, the tiny table (64) being one span
+    ends = [min(s + 16, n) for n in PROMPTS for s in range(0, n, 16)]
+    seen = prefill_spans(rec)
+    assert sorted(e for e, _ in seen) == sorted(ends)
+    assert all(expanded >= end for end, expanded in seen)
+    assert m["prefill_kv_live_tokens"] == sum(ends)
+    assert m["prefill_kv_expanded_tokens"] == 64 * pieces \
+        == sum(x for _, x in seen)
 
 
 def test_routed_experts_over_an_ep_axis_sums_the_shares(params):

@@ -2,7 +2,8 @@
 against a whole-table masked softmax in float64, under a fold shaped like
 each family's (GPT-2: twin pools, positions on axis 1, `HIGHEST`; latent:
 one pool scored and summed, positions on axis 2); the addressing of both
-seams; the plan's count."""
+seams; the plan's count; the spans a prefill chunk's keys and values are
+made over."""
 import numpy as np
 import pytest
 
@@ -10,8 +11,8 @@ import jax
 import jax.numpy as jnp
 
 from mxnet_tpu.kernels.paged_attention import (
-    NULL_BLOCK, chunk_addresses, live_walk, softmax_fold, step_addresses,
-    walk_plan, walk_sizes)
+    NULL_BLOCK, chunk_addresses, chunk_spans, live_walk, softmax_fold,
+    span_index, step_addresses, walk_plan, walk_sizes)
 from mxnet_tpu.models.transformer import _live_attention
 
 BS, H, DH, ROWS, SPAN = 4, 2, 8, 4, 8       # span: two table blocks a piece
@@ -165,3 +166,49 @@ def test_addresses_send_padding_and_inactive_rows_to_the_null_block():
                                np.asarray([True, True, False]), BS)
     assert np.asarray(blk).tolist() == [2, 7, NULL_BLOCK]
     assert np.asarray(slot).tolist() == [1, 2, 0]
+
+
+@pytest.mark.parametrize("chunk,table_len,floor,want", [
+    (256, 4096, 1024, (1024, 2048, 4096)),      # the latent cell's buckets
+    (512, 4096, 1024, (1024, 2048, 4096)),
+    (1024, 4096, 1024, (1024, 2048, 4096)),
+    (2048, 4096, 1024, (2048, 4096)),           # the chunk lifts the floor
+    (256, 3072, 1024, (1024, 2048, 3072)),      # a table no power of two
+    (256, 1024, 1024, (1024,)),                 # the first span holds it all
+    (16, 64, 1024, (64,)),                      # the tests' tiny shapes
+    (64, 64, 8, (64,)),                         # T <= C: a full forward
+    (128, 64, 8, (64,)),
+    (8, 64, 8, (8, 16, 32, 64)),
+    (8, 60, 16, (16, 32, 60)),
+])
+def test_chunk_spans_double_from_the_floor_and_end_at_the_table(
+        chunk, table_len, floor, want):
+    spans = chunk_spans(chunk, table_len, floor)
+    assert spans == want
+    if floor == 1024:                           # the default
+        assert chunk_spans(chunk, table_len) == want
+    assert list(spans) == sorted(set(spans)) and spans[-1] == table_len
+    assert len(spans) == 1 or spans[0] == max(chunk, floor)
+    assert all(b == 2 * a for a, b in zip(spans[:-2], spans[1:-1]))
+    if table_len <= chunk:
+        assert spans == (table_len,)
+
+
+@pytest.mark.parametrize("chunk,table_len,floor", [
+    (8, 64, 8), (4, 60, 16), (16, 64, 1024), (8, 8, 8)])
+def test_span_index_is_the_smallest_span_that_holds_the_chunks_end(
+        chunk, table_len, floor):
+    """Over every ``start`` and ``length`` the table holds (and ``length``
+    0, a chunk of padding alone)."""
+    spans = chunk_spans(chunk, table_len, floor)
+    start, length = np.meshgrid(np.arange(table_len + 1),
+                                np.arange(chunk + 1), indexing="ij")
+    held = start + length <= table_len
+    start, length = start[held], length[held]
+    got = np.asarray(jax.jit(jax.vmap(
+        lambda s, n: span_index(spans, s + n)))(start, length))
+    want = [min(i for i, s in enumerate(spans) if s >= e)
+            for e in start + length]
+    assert got.dtype == np.int32 and got.tolist() == want
+    assert set(got.tolist()) == set(range(len(spans)))  # every span chosen
+    assert int(span_index(spans, 3)) == 0               # host integers too

@@ -179,7 +179,7 @@ def test_latent_prefill_attention_compiles_at_published_widths(one_chip):
                                          sharding=one_chip)
     start = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     text = _compile(
-        lambda w, q, rows, s: _attend_expanded(cfg, {"wkv_b": w}, q, rows, s,
+        lambda w, q, rows, s: _attend_expanded(cfg, w, q, rows, s,
                                                True, False),
         sd(512, 128 * 256), sd(1024, 128, 192), sd(4096, 640), start)
     assert "mx_flash_fwd_offs_grid" in text
