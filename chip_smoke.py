@@ -437,7 +437,8 @@ def phase_decode(run):
             i32 = np.int32
             text = eng._prefill_b.aot(
                 eng._params, eng._cache_spec, sd((d["buckets"][-1],), i32),
-                sd((), i32), sd((), i32), sd((eng._mb,), i32)).as_text()
+                sd((), i32), sd((), i32), sd((eng._mb,), i32),
+                sd((), i32)).as_text()
             assert "tpu_custom_call" in text, \
                 "no Pallas kernel in the compiled prefill program"
         params = eng._params

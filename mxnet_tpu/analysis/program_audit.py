@@ -796,7 +796,7 @@ def _build_decode():
     mb = eng._mb
     plans = eng.comm_plan()
     prefill_args = (params, cache, sd((8,), i32), sd((), i32),
-                    sd((), i32), sd((mb,), i32))
+                    sd((), i32), sd((mb,), i32), sd((), i32))
     b = eng.batch_size
     step_args = (params, cache, sd((b,), i32), sd((b,), i32),
                  sd((b, mb), i32), sd((b,), _np.bool_))

@@ -3,8 +3,10 @@ sparse experts beside a shared one, assembled from a published config.
 
 What the GPT-2-style family of `models/transformer.py` does not have, each
 built from the config's own keys (`MoEMLAConfig.from_dict`): RMSNorm and
-sandwich norms, rotary positions on part of the head, multi-head latent
-attention, a gated SiLU MLP, an untied head, leading dense layers and then
+sandwich norms (plain pre-norm where a layer lacks the outer gains), rotary
+positions on part of the head (or none: ``mla_use_nope``), multi-head latent
+attention (the query through a low rank, or directly: ``q_lora_rank``
+null), a gated SiLU MLP, an untied head, leading dense layers and then
 expert layers (`parallel/moe.py::routed_experts`: top-k over the router's
 full width, the experts held HERE computed through a grouped matrix product,
 a shared expert beside them).
@@ -59,6 +61,20 @@ __all__ = ["MoEMLAConfig", "init_moe_mla", "moe_mla_forward",
 _LANES = 128
 
 
+def _held_experts(held, routed):
+    """``experts_held`` as ``(first, count)``: ``None`` is all ``routed``
+    experts, a dict is the configuration file's ``{"first", "count"}``."""
+    if held is None:
+        held = (0, routed)
+    elif isinstance(held, dict):
+        held = (held["first"], held["count"])
+    held = (int(held[0]), int(held[1]))
+    if not (0 <= held[0] and held[0] + held[1] <= routed and held[1] >= 1):
+        raise ValueError("experts_held %r lies outside the %d routed "
+                         "experts" % (held, routed))
+    return held
+
+
 @dataclasses.dataclass(frozen=True)
 class MoEMLAConfig:
     """The published keys by their own names, plus ``experts_held``
@@ -67,7 +83,7 @@ class MoEMLAConfig:
     num_hidden_layers: int
     first_k_dense_replace: int
     num_attention_heads: int
-    q_lora_rank: int
+    q_lora_rank: int            # None: the query is projected directly
     kv_lora_rank: int
     qk_nope_head_dim: int
     qk_rope_head_dim: int
@@ -83,6 +99,7 @@ class MoEMLAConfig:
     vocab_size: int
     experts_held: tuple = None
     initializer_range: float = 0.02
+    mla_use_nope: bool = False  # True: the rope part is carried unrotated
     # decode-path knobs (not the model's): flash tile; rows, and blocks of
     # their tables, that a step's attention holds live at once
     block_k: int = 512
@@ -90,17 +107,8 @@ class MoEMLAConfig:
     step_col_blocks: int = 32
 
     def __post_init__(self):
-        held = self.experts_held
-        if held is None:
-            held = (0, self.n_routed_experts)
-        elif isinstance(held, dict):
-            held = (held["first"], held["count"])
-        held = (int(held[0]), int(held[1]))
-        if not (0 <= held[0] and held[0] + held[1] <= self.n_routed_experts
-                and held[1] >= 1):
-            raise ValueError("experts_held %r lies outside the %d routed "
-                             "experts" % (held, self.n_routed_experts))
-        object.__setattr__(self, "experts_held", held)
+        object.__setattr__(self, "experts_held", _held_experts(
+            self.experts_held, self.n_routed_experts))
 
     @classmethod
     def from_dict(cls, config, **overrides):
@@ -135,14 +143,25 @@ class MoEMLAConfig:
                                             self.num_hidden_layers)
 
 
+def _query_shapes(cfg):
+    """The query projection's leaves: ``wq_a``, ``norm_q``, ``wq_b`` through
+    the published low rank, or ONE matrix ``wq`` where ``q_lora_rank`` is
+    null."""
+    d = cfg.hidden_size
+    wide = cfg.num_attention_heads * (cfg.qk_nope_head_dim
+                                      + cfg.qk_rope_head_dim)
+    if cfg.q_lora_rank is None:
+        return {"wq": (d, wide)}
+    return {"wq_a": (d, cfg.q_lora_rank), "norm_q": (cfg.q_lora_rank,),
+            "wq_b": (cfg.q_lora_rank, wide)}
+
+
 def _layer_shapes(cfg, dense):
     d, H = cfg.hidden_size, cfg.num_attention_heads
     out = {
         "norm_attn_in": (d,), "norm_attn_out": (d,),
         "norm_ffn_in": (d,), "norm_ffn_out": (d,),
-        "wq_a": (d, cfg.q_lora_rank), "norm_q": (cfg.q_lora_rank,),
-        "wq_b": (cfg.q_lora_rank,
-                 H * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)),
+        **_query_shapes(cfg),
         "wkv_a": (d, cfg.latent_width), "norm_kv": (cfg.kv_lora_rank,),
         "wkv_b": (cfg.kv_lora_rank,
                   H * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
@@ -165,22 +184,34 @@ def _layer_shapes(cfg, dense):
 def init_moe_mla(cfg, key, dtype=jnp.float32):
     """Seeded parameters in ONE jitted call, every leaf made in ``dtype``
     directly: normal(0, ``initializer_range``) matrices, norm gains 1."""
+    return _init_tree(cfg, key, dtype,
+                      [_layer_shapes(cfg, cfg.is_dense(l))
+                       for l in range(cfg.num_hidden_layers)])
+
+
+def _init_tree(cfg, key, dtype, layers, special=None):
+    """The parameter tree ``{"embed", "head", "norm_f", "layers"}`` over the
+    ``layers``' shapes, made in ONE jitted call: a leaf named ``norm_*`` is
+    ones, one named in ``special`` (``name -> fn(key, shape)``) is that
+    function's, every other normal(0, ``initializer_range``) in ``dtype``."""
     shapes = {"embed": (cfg.vocab_size, cfg.hidden_size),
               "head": (cfg.hidden_size, cfg.vocab_size),
-              "norm_f": (cfg.hidden_size,),
-              "layers": [_layer_shapes(cfg, cfg.is_dense(l))
-                         for l in range(cfg.num_hidden_layers)]}
+              "norm_f": (cfg.hidden_size,), "layers": layers}
     is_shape = lambda s: isinstance(s, tuple)           # noqa: E731
     leaves, tree = jax.tree_util.tree_flatten_with_path(shapes,
                                                         is_leaf=is_shape)
     dt = jnp.dtype(dtype)
+    special = special or {}
 
     @jax.jit
     def make(key):
         keys = jax.random.split(key, len(leaves))
         out = []
         for k, (path, shape) in zip(keys, leaves):
-            if str(path[-1].key).startswith("norm_"):
+            name = str(path[-1].key)
+            if name in special:
+                out.append(special[name](k, shape))
+            elif name.startswith("norm_"):
                 out.append(jnp.ones(shape, dt))
             else:
                 out.append((jax.random.normal(k, shape, jnp.float32)
@@ -226,17 +257,24 @@ def _gated_mlp(x, wg, wu, wd):
 def _mla_project(cfg, lp, h, pos):
     """Normed activations ``h`` ``[N, d]`` at positions ``pos`` ``[N]`` ->
     ``(q_nope [N, H, dn], q_rope [N, H, dr] rotated, rows [N, rkv + dr])``:
-    ``rows`` is what the cache holds, ``[c | k_rope]``, float32 here."""
+    ``rows`` is what the cache holds, ``[c | k_rope]``, float32 here. With
+    ``mla_use_nope`` nothing is rotated and ``pos`` is not read."""
     H, dn, dr = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                  cfg.qk_rope_head_dim)
     N = h.shape[0]
-    c_q = _rms(_mm(h, lp["wq_a"]), lp["norm_q"], cfg.rms_norm_eps)
-    # only ever an operand again (the rotary part after its float32 turn)
-    q = _mm(c_q, lp["wq_b"], lp["wq_b"].dtype).reshape(N, H, dn + dr)
+    # q is only ever an operand again (its rotary part after a float32 turn)
+    if "wq" in lp:              # q_lora_rank null: no down-projection
+        q = _mm(h, lp["wq"], lp["wq"].dtype)
+    else:
+        c_q = _rms(_mm(h, lp["wq_a"]), lp["norm_q"], cfg.rms_norm_eps)
+        q = _mm(c_q, lp["wq_b"], lp["wq_b"].dtype)
+    q = q.reshape(N, H, dn + dr)
     kv = _mm(h, lp["wkv_a"])
     c = _rms(kv[:, :cfg.kv_lora_rank], lp["norm_kv"], cfg.rms_norm_eps)
-    k_rope = _rope(kv[:, cfg.kv_lora_rank:], pos, cfg.rope_theta)
-    q_rope = _rope(q[..., dn:], pos, cfg.rope_theta)
+    k_rope, q_rope = kv[:, cfg.kv_lora_rank:], q[..., dn:]
+    if not cfg.mla_use_nope:    # (NoPE: the shared part goes in as it is)
+        k_rope = _rope(k_rope, pos, cfg.rope_theta)
+        q_rope = _rope(q_rope, pos, cfg.rope_theta)
     return q[..., :dn], q_rope, jnp.concatenate([c, k_rope], -1)
 
 
@@ -337,16 +375,20 @@ def _ffn(cfg, lp, h, valid=None):
     return shared + routed, counts
 
 
-def _block(cfg, lp, x, attend, valid=None):
-    """One sandwich-normed block. ``attend(h)`` maps the normed input to the
-    attention output BEFORE ``W_o`` (``[N, H * dv]``): the three paths
-    (full sequence, prefill chunk, absorbed step) differ only there."""
+def _block(cfg, lp, x, attend, valid=None, scope="mla"):
+    """One residual block. ``attend(h)`` maps the normed input to the
+    mixer's output BEFORE ``W_o`` (``[N, H * dv]``): the paths (full
+    sequence, prefill chunk, absorbed step; another family's mixer under
+    its own ``scope``) differ only there. Sandwich-normed where the layer
+    has the two outer gains (``norm_attn_out``, ``norm_ffn_out``), else
+    plain pre-norm: ``x + mix(norm(x))``, ``h + ffn(norm(h))``."""
     eps = cfg.rms_norm_eps
-    with jax.named_scope("mla"):
+    with jax.named_scope(scope):
         a = _mm(attend(_rms(x, lp["norm_attn_in"], eps)), lp["wo"])
-    h = x + _rms(a, lp["norm_attn_out"], eps)
+    sandwich = "norm_attn_out" in lp
+    h = x + (_rms(a, lp["norm_attn_out"], eps) if sandwich else a)
     f, counts = _ffn(cfg, lp, _rms(h, lp["norm_ffn_in"], eps), valid)
-    return h + _rms(f, lp["norm_ffn_out"], eps), counts
+    return h + (_rms(f, lp["norm_ffn_out"], eps) if sandwich else f), counts
 
 
 def _aux(cfg, all_counts, prefix=""):
@@ -392,6 +434,27 @@ def moe_mla_forward(params, cfg, tokens, *, use_pallas=False,
 # ---------------------------------------------------------------------------
 # the DecodeEngine seam
 # ---------------------------------------------------------------------------
+def _prefill_attend(cfg, lp, h, pool, l, pos, blk, slot, table, start,
+                    spans, which, use_pallas, interpret):
+    """One layer's latent attention over a prefill chunk: write the chunk's
+    rows into layer ``l`` of ``pool``, gather the table's rows, expand keys
+    and values over the span ``which`` names (``lax.switch``: one span calls
+    it, and builds no branch) and attend. ``(out [C, H * dv], pool)``. The
+    write and the gather stay OUTSIDE the branches: the pool is never a
+    branch's operand."""
+    C = h.shape[0]
+    q_nope, q_rope, rows = _mla_project(cfg, lp, h, pos)
+    pool = pool.at[l, blk, slot].set(_cache_rows(rows, pool))
+    q = jnp.concatenate([q_nope, q_rope], -1)
+    seen = paged.gather_pages(pool, l, table).reshape(-1, pool.shape[3])
+
+    def over(span):
+        return lambda q, seen: _attend_expanded(
+            cfg, lp["wkv_b"], q, seen[:span], start, use_pallas,
+            interpret).reshape(C, -1)
+    return lax.switch(which, [over(s) for s in spans], q, seen), pool
+
+
 @jax.named_scope("decode.prefill")      # the trace's device-side name
 def moe_mla_decode_prefill(params, cfg, cache, tokens, start, length, table,
                            *, use_pallas=False, interpret=False,
@@ -413,7 +476,7 @@ def moe_mla_decode_prefill(params, cfg, cache, tokens, start, length, table,
     what was made, ``prefill_kv_live_tokens`` and
     ``prefill_kv_expanded_tokens``, a piece once (not a layer)."""
     pool = cache["latent"]                  # [L, blocks, bs, rkv + dr]
-    C, width = tokens.shape[0], pool.shape[3]
+    C = tokens.shape[0]
     pos, valid, blk, slot = paged.chunk_addresses(table, start, length, C,
                                                   pool.shape[2])
     end = start + length
@@ -425,17 +488,10 @@ def moe_mla_decode_prefill(params, cfg, cache, tokens, start, length, table,
         with jax.named_scope("layer"):
             def attend(h, l=l, lp=lp):
                 nonlocal pool
-                q_nope, q_rope, rows = _mla_project(cfg, lp, h, pos)
-                pool = pool.at[l, blk, slot].set(_cache_rows(rows, pool))
-                q = jnp.concatenate([q_nope, q_rope], -1)
-                seen = paged.gather_pages(pool, l, table).reshape(-1, width)
-
-                def over(span):
-                    return lambda q, seen: _attend_expanded(
-                        cfg, lp["wkv_b"], q, seen[:span], start, use_pallas,
-                        interpret).reshape(C, -1)
-                # (one span: `lax.switch` calls it, and builds no branch)
-                return lax.switch(which, [over(s) for s in spans], q, seen)
+                out, pool = _prefill_attend(
+                    cfg, lp, h, pool, l, pos, blk, slot, table, start,
+                    spans, which, use_pallas, interpret)
+                return out
             x, counts = _block(cfg, lp, x, attend, valid)
             if counts is not None:
                 all_counts.append(counts)
@@ -486,6 +542,15 @@ def _absorbed_attention(cfg, lp, q_nope, q_rope, pool, l, plan):
     return o.reshape(-1, H * dv)
 
 
+def _step_attend(cfg, lp, h, pool, l, positions, blk, slot, plan):
+    """One layer's latent attention of a decode step: write the rows' new
+    latent row into layer ``l`` of ``pool`` and attend absorbed over the
+    live positions. ``(out [B, H * dv], pool)``."""
+    q_nope, q_rope, rows = _mla_project(cfg, lp, h, positions)
+    pool = pool.at[l, blk, slot].set(_cache_rows(rows, pool))
+    return _absorbed_attention(cfg, lp, q_nope, q_rope, pool, l, plan), pool
+
+
 @jax.named_scope("decode.step")      # the trace's device-side name
 def moe_mla_decode_step(params, cfg, cache, token_ids, positions, tables,
                         active, *, with_logits=False):
@@ -506,10 +571,9 @@ def moe_mla_decode_step(params, cfg, cache, token_ids, positions, tables,
         with jax.named_scope("layer"):
             def attend(h, l=l, lp=lp):
                 nonlocal pool
-                q_nope, q_rope, rows = _mla_project(cfg, lp, h, positions)
-                pool = pool.at[l, blk, slot].set(_cache_rows(rows, pool))
-                return _absorbed_attention(cfg, lp, q_nope, q_rope, pool, l,
-                                           plan)
+                out, pool = _step_attend(cfg, lp, h, pool, l, positions,
+                                         blk, slot, plan)
+                return out
             x, counts = _block(cfg, lp, x, attend, active)
             if counts is not None:
                 all_counts.append(counts)
@@ -543,7 +607,7 @@ class MoEMLADecodeModel(DecodeModel):
         self.mesh = mesh
         self.resolve_flash(flash)
 
-    def cache_spec(self, num_blocks, block_size):
+    def cache_spec(self, num_blocks, block_size, slots):
         """One pool: ``(layers, blocks, block_size, cache_row_width)`` in
         the parameters' dtype; a row is ``[c | k_rope]``, ``kv_lora_rank +
         qk_rope_head_dim`` numbers, padded to whole lanes."""
@@ -556,7 +620,7 @@ class MoEMLADecodeModel(DecodeModel):
              self.cfg.cache_row_width), self.cache_dtype,
             sharding=sharding)}
 
-    def prefill_fn(self, params, cache, tokens, start, length, table):
+    def prefill_fn(self, params, cache, tokens, start, length, table, slot):
         return moe_mla_decode_prefill(
             params, self.cfg, cache, tokens, start, length, table,
             use_pallas=self.use_pallas, interpret=self.interpret)

@@ -40,12 +40,12 @@ class TinyLMDecodeModel(DecodeModel):
                 _np.float32),
         }
 
-    def cache_spec(self, num_blocks, block_size):
+    def cache_spec(self, num_blocks, block_size, slots):
         pool = jax.ShapeDtypeStruct((num_blocks, block_size, self.dim),
                                     jnp.float32)
         return {"k": pool, "v": pool}
 
-    def prefill_fn(self, params, cache, tokens, start, length, table):
+    def prefill_fn(self, params, cache, tokens, start, length, table, slot):
         """Writes K/V for global positions ``start..start+length-1`` of
         the bucket-padded chunk ``tokens (L,)``, attends the chunk's last
         real token over ``pos < start + length``."""

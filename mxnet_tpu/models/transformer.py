@@ -433,7 +433,7 @@ class TransformerDecodeModel(DecodeModel):
         self.params = params
         self.resolve_flash(flash)
 
-    def cache_spec(self, num_blocks, block_size):
+    def cache_spec(self, num_blocks, block_size, slots):
         """The cache: twin float32 K and V pools, layer-major, so a
         layer reads and writes only its own pages."""
         pool = jax.ShapeDtypeStruct(
@@ -441,7 +441,7 @@ class TransformerDecodeModel(DecodeModel):
             jnp.float32)
         return {"k": pool, "v": pool}
 
-    def prefill_fn(self, params, cache, tokens, start, length, table):
+    def prefill_fn(self, params, cache, tokens, start, length, table, slot):
         return transformer_decode_prefill(
             params, self.cfg, cache, tokens, start, length,
             table, use_pallas=self.use_pallas, interpret=self.interpret)

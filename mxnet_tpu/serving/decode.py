@@ -16,7 +16,12 @@ time. This module is the decode side of the stack (ISSUE 18):
   the batch stays full while sequences join and leave, and the
   deadline/shed contract is enforced per *token*, not per request
   (a sequence can be shed typed mid-generation, keeping the tokens it
-  already produced).
+  already produced). The loop runs ONE STEP AHEAD of the host: where the
+  next step needs nothing that only the host knows, it is dispatched
+  behind the step in flight before that step's ids are read back, and
+  the host's share of a step (read-back, callbacks, growth, admission)
+  runs while the device runs the next (``_decode_step``; ``stats()``
+  ``["steps_ahead"]``).
 - **Two-program family** through :class:`~..compile.builder.ProgramBuilder`
   (TPL108 seam): per model, one bucketed batch-1 *prefill* program per
   prompt-length bucket (site ``decode.prefill.<name>``) and exactly one
@@ -36,14 +41,28 @@ walk over the live positions, the prefill chunk's attention) is
 :mod:`~..kernels.paged_attention`'s.
 
 **The cache seam.** The model states its cache as a pytree of
-``jax.ShapeDtypeStruct`` (``cache_spec(num_blocks, block_size)``: one
-leaf per pool, any shape and dtype); the engine allocates it, places it
-on the mesh, donates it, describes it to ``aot_info`` and passes it
-WHOLE: ``prefill_fn(params, cache, tokens, start, length, table) ->
-(next_id, cache, aux)`` and ``step_fn(params, cache, token_ids,
-positions, tables, active) -> (next_ids, cache, aux)``. ``aux`` is a
-dict of small integer arrays (may be empty) that comes back in the same
-read-back as the ids and is summed into ``stats()["model"]``.
+``jax.ShapeDtypeStruct`` (``cache_spec(num_blocks, block_size, slots)``: any
+shape and dtype a leaf). A leaf is one of two kinds. A *paged pool* (a plain
+``ShapeDtypeStruct``) is indexed by the block tables `PagedKVCache` hands
+out: keys and values, or latent rows, a row a cached token. A *per-slot pool*
+(`~..models.decode_model.SlotPool`, leading axes ``[layers, slots, ...]``)
+holds one row a decode SLOT: recurrent state that does not grow with the
+sequence. The engine allocates every leaf, places it on the mesh, donates
+it, describes it to ``aot_info`` and passes the cache WHOLE; it never looks
+inside a leaf, and tells the two kinds apart only to account their bytes
+(``stats()["kv"]``: ``pool_bytes`` and ``state_bytes``). The bodies:
+``prefill_fn(params, cache, tokens, start, length, table, slot) ->
+(next_id, cache, aux)``, ``slot`` the decode slot the prompt was admitted
+to, and ``step_fn(params, cache, token_ids, positions, tables, active) ->
+(next_ids, cache, aux)``, whose row ``i`` IS slot ``i``. A family without
+per-slot state ignores ``slot``. The lifetime of a slot's state is the
+model's: a piece with ``start == 0`` starts from zero state whatever the
+slot held before (slots are re-used and never cleared by the engine), and a
+step neither reads nor writes the state of a row whose ``active`` is false
+(a vacant slot, or one in mid-prefill between two pieces of a chunked
+prompt). ``aux`` is a dict of small integer arrays (may be empty) that comes
+back in the same read-back as the ids and is summed into
+``stats()["model"]``.
 
 **Chunked prefill** (``prefill_chunk`` /
 ``MXNET_SERVING_DECODE_PREFILL_CHUNK``): a long prompt runs as
@@ -64,8 +83,8 @@ blocks free up or its deadline sheds it.
 
 Observability: always-on counters via ``profiler.record_decode_event``
 (tokens, steps, occupancy, cache OOMs) plus latency histograms
-``decode.<name>.step`` / ``decode.<name>.ttft`` /
-``decode.<name>.intertoken``; fault site ``decode.step`` fires before
+``decode.<name>.step`` (dispatch to read-back) /
+``decode.<name>.ttft`` / ``decode.<name>.intertoken``; fault site ``decode.step`` fires before
 every device dispatch (prefill and step) for chaos tests.
 """
 from __future__ import annotations
@@ -121,6 +140,9 @@ class DecodeStream:
         # positions with K/V on device; None while prefill is still in
         # flight — the step loop must not see a mid-prefill sequence
         self._cached = None
+        # the dispatched step whose read-back yields this sequence's next
+        # token, or None: the engine runs one step ahead of the host
+        self._inflight = None
 
     def _emit(self, token):
         with self._cond:
@@ -273,7 +295,16 @@ class DecodeEngine:
 
         self._kv = PagedKVCache(num_blocks, block_size)
         self._mb = self._kv.blocks_for(self.max_seq_len)  # table width
-        spec = cache_spec(self._kv.num_blocks, self._kv.block_size)
+        spec = cache_spec(self._kv.num_blocks, self._kv.block_size,
+                          self.batch_size)
+        # what the two kinds of leaf hold, before placement rebuilds them
+        from ..models.decode_model import SlotPool
+        for p in jax.tree_util.tree_leaves(spec):
+            nbytes = math.prod(p.shape) * jnp.dtype(p.dtype).itemsize
+            if isinstance(p, SlotPool):
+                self._kv.state_bytes += nbytes
+            else:
+                self._kv.pool_bytes += nbytes
         self._params = jax.device_put(
             jax.tree_util.tree_map(jnp.asarray, params))
         # tp-shardable pools: a leaf that carries its own sharding keeps
@@ -295,9 +326,6 @@ class DecodeEngine:
             self._params = jax.device_put(
                 self._params, NamedSharding(mesh, PartitionSpec()))
         self._cache_spec = spec
-        self._kv.pool_bytes = sum(
-            math.prod(p.shape) * jnp.dtype(p.dtype).itemsize
-            for p in jax.tree_util.tree_leaves(spec))
         # the pools are born on the device in their own dtype and
         # sharding: one program, no host copy, no float32 twin
         shardings = jax.tree_util.tree_map(lambda p: p.sharding, spec) \
@@ -323,9 +351,14 @@ class DecodeEngine:
         self._rid_ctr = 0
         self._counters = {"submitted": 0, "served": 0, "shed": 0,
                           "failed": 0, "tokens": 0, "prefills": 0,
-                          "prefill_chunks": 0, "steps": 0, "cache_oom": 0}
+                          "prefill_chunks": 0, "steps": 0, "steps_ahead": 0,
+                          "cache_oom": 0}
         self._model = {}                # the bodies' aux, summed
+        # the step dispatched and not yet read back: (rows, next_ids, aux,
+        # dispatch time), landed by the next _decode_step
+        self._ahead = None
         self._device_get = jax.device_get
+        self._tree_leaves = jax.tree_util.tree_leaves
         self._lat_step = "decode.%s.step" % name
         self._lat_ttft = "decode.%s.ttft" % name
         self._lat_tok = "decode.%s.intertoken" % name
@@ -350,7 +383,8 @@ class DecodeEngine:
         for bucket in self.prefill_buckets:
             self._prefill_b.aot_info(
                 self._params, cache, sd((bucket,), i32),
-                sd((), i32), sd((), i32), sd((self._mb,), i32), mode="aot")
+                sd((), i32), sd((), i32), sd((self._mb,), i32), sd((), i32),
+                mode="aot")
         b, mb = self.batch_size, self._mb
         self._step_b.aot_info(
             self._params, cache, sd((b,), i32), sd((b,), i32),
@@ -467,6 +501,7 @@ class DecodeEngine:
                 if seq is not None:
                     leftovers.append(seq)
                     self._slots[i] = None
+            self._ahead = None
         for s in leftovers:
             self._kv.free(s.rid)
             self._finish(s, RuntimeError("decode engine stopped"))
@@ -620,7 +655,8 @@ class DecodeEngine:
                 with _prof.span("mx.decode.prefill.dispatch", bucket=bucket):
                     next_id, self._cache, aux = self._prefill_b(
                         self._params, self._cache, toks,
-                        _np.int32(start), _np.int32(len(piece)), table)
+                        _np.int32(start), _np.int32(len(piece)), table,
+                        _np.int32(stream._slot))
                 auxes.append(aux)
                 if last:
                     with _prof.span("mx.decode.prefill.readback",
@@ -678,39 +714,68 @@ class DecodeEngine:
     def _decode_step(self):
         """One continuous-batching iteration over the active slots:
         per-token deadline enforcement, cache growth (typed shed on
-        overflow), one fixed-shape step program call, distribution."""
+        overflow), one fixed-shape step program call, distribution.
+
+        The loop runs ONE STEP AHEAD of the host. A dispatched step stays
+        in flight (``_ahead``) and the next call lands it: reads its ids
+        back, emits them, retires what finished. Where the next step
+        needs nothing of that (``_follows``) it is dispatched FIRST, its
+        ``token_ids`` the in-flight step's ``next_ids`` still on the
+        device, so the host's work on step N (the read-back, a callback
+        and two latency records a row, growth, the admission pass) runs
+        while the device runs step N + 1. Otherwise the step in flight
+        lands first and the call is the synchronous iteration it always
+        was. The programs, their inputs and every sequence's tokens are
+        the same either way."""
         with _prof.span("mx.decode.step") as step_sp:
+            ahead = self._ahead
+            if ahead is not None and not self._follows(ahead):
+                self._land(ahead)
+                ahead = None
             with _prof.span("mx.decode.step.grow") as sp:
-                now = time.monotonic()
+                if ahead is None:
+                    now = time.monotonic()
+                    for seq in self._live():
+                        if seq.deadline is not None and now > seq.deadline:
+                            self._evict(seq, DeadlineExceeded(
+                                "decode %s: deadline exceeded after %d tokens"
+                                % (seq.rid, len(seq.tokens))))
+                active = []
                 for seq in self._live():
-                    if seq.deadline is not None and now > seq.deadline:
-                        self._evict(seq, DeadlineExceeded(
-                            "decode %s: deadline exceeded after %d tokens"
-                            % (seq.rid, len(seq.tokens))))
-                for seq in self._live():
+                    if seq._inflight is not None \
+                            and len(seq.tokens) + 1 >= seq.max_new_tokens:
+                        continue        # the token in flight is its last
                     try:
                         # room for the token this step writes at _cached
                         self._kv.extend(seq.rid, 1)
+                        active.append(seq)
                     except CacheOverflow as e:
                         self._evict(seq, e)
-                active = self._live()
                 sp.set_metadata(rows=len(active))
             step_sp.set_metadata(active=len(active))
             if not active:
+                if ahead is not None:
+                    self._land(ahead)
                 return
             with _prof.span("mx.decode.step.pack"):
                 b, mb = self.batch_size, self._mb
-                token_ids = _np.zeros((b,), _np.int32)
                 positions = _np.zeros((b,), _np.int32)
                 tables = _np.zeros((b, mb), _np.int32)
                 mask = _np.zeros((b,), _np.bool_)
                 for seq in active:
                     i = seq._slot
-                    token_ids[i] = seq.tokens[-1]
                     positions[i] = seq._cached
                     own = self._kv.table(seq.rid)
                     tables[i, :len(own)] = own
                     mask[i] = True
+                if ahead is None:
+                    token_ids = _np.zeros((b,), _np.int32)
+                    for seq in active:
+                        token_ids[seq._slot] = seq.tokens[-1]
+                else:
+                    # row i of the step in flight IS slot i of this one; a
+                    # row it did not step is masked here too
+                    token_ids = ahead[1]
             _faults.fault_point("decode.step", model=self.name, kind="step",
                                 batch=len(active))
             t0 = time.monotonic()
@@ -719,40 +784,90 @@ class DecodeEngine:
                     next_ids, self._cache, aux = self._step_b(
                         self._params, self._cache,
                         token_ids, positions, tables, mask)
-                with _prof.span("mx.decode.step.readback"):
-                    ids, aux = self._device_get((next_ids, aux))  # tpulint: allow-host-sync sampled tokens feed the next step and the reply streams; decode cannot proceed without them
             except Exception as e:
-                # step state is unknown after a failed dispatch: fail the
-                # whole active set (chaos tests drive this via decode.step)
-                err = e if isinstance(e, DeadlineExceeded) else RuntimeError(
-                    "decode step failed: %s" % e)
-                for seq in active:
-                    self._evict(seq, err)
+                self._fail_step(active, e)
+                if ahead is not None:
+                    self._land(ahead)
                 return
-            with _prof.span("mx.decode.step.emit") as sp:
-                now = time.monotonic()
-                step_ns = int((now - t0) * 1e9)
-                _prof.record_latency(self._lat_step, step_ns)
-                with self._cv:
-                    self._counters["steps"] += 1
-                    self._counters["tokens"] += len(active)
-                    self._count_aux_locked(aux)
-                _prof.record_decode_event(steps=1, tokens=len(active),
-                                          slot_steps=len(active),
-                                          slot_capacity=self.batch_size)
-                retired = []
-                for seq in active:
-                    tok = int(ids[seq._slot])
-                    seq._cached += 1
-                    if seq.last_token_t is not None:
-                        _prof.record_latency(
-                            self._lat_tok,
-                            int((now - seq.last_token_t) * 1e9))
-                    seq.last_token_t = now
-                    seq._emit(tok)
-                    if self._maybe_retire(seq, tok):
-                        retired.append(seq.rid)
-                sp.set_metadata(retired=len(retired), rid=",".join(retired))
+            # ask for the ids NOW: a copy to the host asked for once the
+            # next step is queued waits behind that step on the device
+            for out in self._tree_leaves((next_ids, aux)):
+                out.copy_to_host_async()
+            self._ahead = step = (active, next_ids, aux, t0)
+            for seq in active:
+                seq._cached += 1        # the write at _cached is queued
+                seq._inflight = step
+            if ahead is not None:
+                self._land(ahead)
+
+    def _follows(self, ahead):
+        """Whether the next step can be dispatched behind the one in
+        flight BEFORE its tokens are read: every live row takes its token
+        from it (none is fresh from a prefill, its token on the host), no
+        deadline has passed (an eviction comes after the tokens it
+        follows), and the pool holds a block for every row (no growth
+        can overflow)."""
+        live = self._live()
+        now = time.monotonic()
+        return (self._kv.free_blocks >= len(live)
+                and all(s._inflight is ahead
+                        and (s.deadline is None or now <= s.deadline)
+                        for s in live))
+
+    def _fail_step(self, rows, e):
+        # step state is unknown after a failed dispatch or read-back: fail
+        # the whole active set (chaos tests drive this via decode.step)
+        err = e if isinstance(e, DeadlineExceeded) else RuntimeError(
+            "decode step failed: %s" % e)
+        for seq in rows:
+            if self._slots[seq._slot] is seq:
+                self._evict(seq, err)
+
+    def _land(self, step):
+        """Read a dispatched step's ids back, emit them, retire what
+        finished. A row that left its slot since the dispatch (it ended
+        on the token before, which only the host could see) is passed
+        over."""
+        rows, next_ids, aux, t0 = step
+        if self._ahead is step:
+            self._ahead = None
+        try:
+            with _prof.span("mx.decode.step.readback"):
+                ids, aux = self._device_get((next_ids, aux))  # tpulint: allow-host-sync sampled tokens feed the reply streams and the retirements; a step behind it may already be queued
+        except Exception as e:
+            # whatever was dispatched behind it read the same cache
+            later, self._ahead = self._ahead, None
+            self._fail_step(rows + (later[0] if later else []), e)
+            return
+        with _prof.span("mx.decode.step.emit") as sp:
+            now = time.monotonic()
+            step_ns = int((now - t0) * 1e9)
+            _prof.record_latency(self._lat_step, step_ns)
+            stepped = len(rows)
+            rows = [s for s in rows if self._slots[s._slot] is s]
+            with self._cv:
+                self._counters["steps"] += 1
+                # its successor was queued before this one was read
+                self._counters["steps_ahead"] += self._ahead is not None
+                self._counters["tokens"] += len(rows)
+                self._count_aux_locked(aux)
+            _prof.record_decode_event(steps=1, tokens=len(rows),
+                                      slot_steps=stepped,
+                                      slot_capacity=self.batch_size)
+            retired = []
+            for seq in rows:
+                if seq._inflight is step:
+                    seq._inflight = None
+                tok = int(ids[seq._slot])
+                if seq.last_token_t is not None:
+                    _prof.record_latency(
+                        self._lat_tok,
+                        int((now - seq.last_token_t) * 1e9))
+                seq.last_token_t = now
+                seq._emit(tok)
+                if self._maybe_retire(seq, tok):
+                    retired.append(seq.rid)
+            sp.set_metadata(retired=len(retired), rid=",".join(retired))
 
     # ------------------------------------------------------------------
     def stats(self):

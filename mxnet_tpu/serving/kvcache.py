@@ -91,10 +91,14 @@ class PagedKVCache:
         self._frees = 0
         self._alloc_failures = 0
         self._high_water = 0        # max blocks simultaneously live
-        # bytes of the device pools these blocks index, all of them
-        # together: whoever builds the pools (DecodeEngine, from the
-        # model's cache_spec) writes it here; the manager only reports it
+        # the device state this manager accounts, of two kinds: bytes of
+        # the paged pools these blocks index (all of them together), and
+        # bytes of the per-slot pools beside them (recurrent state: a row a
+        # decode slot, nothing a block). Whoever builds the pools
+        # (DecodeEngine, from the model's cache_spec) writes both here;
+        # the manager only reports them
         self.pool_bytes = 0
+        self.state_bytes = 0
 
     # -- capacity queries ------------------------------------------------
     @property
@@ -217,5 +221,6 @@ class PagedKVCache:
                 "sequences": len(self._tables),
                 "tokens_live": sum(self._lengths.values()),
                 "pool_bytes": int(self.pool_bytes),
+                "state_bytes": int(self.state_bytes),
                 "allocs": self._allocs, "frees": self._frees,
                 "alloc_failures": self._alloc_failures}

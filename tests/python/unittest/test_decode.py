@@ -132,6 +132,21 @@ def _engine(**kw):
     return DecodeEngine(**_lm(), **kw)
 
 
+class _Slowly:
+    """A step builder that takes its time, so that a deadline can pass in
+    mid-generation."""
+
+    def __init__(self, builder, seconds):
+        self._builder, self._seconds = builder, seconds
+
+    def __call__(self, *args):
+        time.sleep(self._seconds)
+        return self._builder(*args)
+
+    def __getattr__(self, name):
+        return getattr(self._builder, name)
+
+
 class TestDecodeEngine:
     def test_continuous_matches_solo_with_join_leave(self):
         """The acceptance bit: per-sequence output under continuous
@@ -220,6 +235,88 @@ class TestDecodeEngine:
         # retired (the free run may hit it before index 2)
         assert out == free_run[:free_run.index(eos) + 1]
         eng.stop()
+
+    @pytest.mark.parametrize("case", ["budget", "eos", "deadline", "tight",
+                                      "lost"])
+    def test_one_step_ahead_serves_what_the_synchronous_loop_serves(
+            self, case):
+        """The loop dispatches step N + 1 behind step N before N's ids are
+        read (`steps_ahead` counts them) and every sequence still gets the
+        tokens of the synchronous iteration: rows that end on their budget
+        are left out of the step behind, a row that ends on an EOS only
+        the host can see is stepped once for nothing and passed over, an
+        eviction waits for the tokens before it, a pool without a block a
+        row runs synchronously, and a lost read-back fails both steps'
+        rows and nothing else."""
+        prompts = [[3, 1, 4], [1, 5, 9, 2, 6], [5, 3], [8, 9, 7, 9, 3, 2],
+                   [2, 7, 1, 8, 2, 8], [1]]
+        budgets = [14, 25, 9, 30, 17, 22]
+        kw = dict(batch_size=3)
+        if case == "eos":
+            kw["eos_id"] = _engine(name="a0").generate(
+                prompts[1], max_new_tokens=25)[6]
+        if case == "tight":
+            # 9 usable blocks of 4: the three longest cannot all grow
+            kw.update(num_blocks=10, block_size=4, max_seq_len=40)
+
+        def drive(name, ahead):
+            eng = _engine(name=name, autostart=False, **kw)
+            if not ahead:
+                eng._follows = lambda step: False
+            if case == "lost" and ahead:
+                get, lost = eng._device_get, []
+
+                def device_get(x):      # the first read-back with a step
+                    if eng._ahead is not None and not lost:  # behind it
+                        lost.append(1)
+                        raise OSError("lost")
+                    return get(x)
+                eng._device_get = device_get
+            streams = [eng.submit(p, max_new_tokens=m,
+                                  deadline_ms=150.0 if case == "deadline"
+                                  and i == 1 else None)
+                       for i, (p, m) in enumerate(zip(prompts, budgets))]
+            if case == "deadline":
+                # the deadline passes in mid-generation
+                eng._step_b = _Slowly(eng._step_b, 0.01)
+            eng.start()
+            outs = []
+            for s in streams:
+                try:
+                    outs.append(s.result_wait(60.0))
+                except (DeadlineExceeded, RuntimeError) as e:
+                    outs.append((type(e), list(s.tokens)))
+            st = eng.stats()
+            eng.stop()
+            assert st["kv"]["blocks_live"] == 0
+            assert st["submitted"] == st["served"] + st["shed"] + st["failed"]
+            return outs, st
+
+        sync, st0 = drive("s" + case, ahead=False)
+        outs, st = drive("a" + case, ahead=True)
+        assert st0["steps_ahead"] == 0 and st["steps_ahead"] > 0
+        if case == "deadline":
+            kind, partial = outs[1]
+            assert kind is DeadlineExceeded and sync[1][0] is kind
+            # it kept what had landed: a prefix of the unhurried answer
+            whole = _engine(name="w").generate(prompts[1], max_new_tokens=25)
+            assert 1 <= len(partial) < 25 and partial == whole[:len(partial)]
+            outs[1] = sync[1] = None
+        if case == "lost":
+            failed = [o for o in outs if isinstance(o, tuple)]
+            assert failed and all(k is RuntimeError for k, _ in failed)
+            assert st["failed"] == len(failed) <= 3 and st["served"] >= 3
+            for o, ref, p in zip(outs, sync, prompts):
+                toks = o[1] if isinstance(o, tuple) else o
+                assert toks == ref[:len(toks)]
+            return
+        assert outs == sync
+        if case == "tight":
+            assert st["cache_oom"] == st0["cache_oom"] > 0
+        if case in ("budget", "tight"):     # the others end on the clock,
+            # or step an ended row once for nothing
+            assert st["steps"] == st0["steps"]
+            assert st["tokens"] == st0["tokens"]
 
     def test_invalid_prompts_raise_synchronously(self):
         eng = _engine(name="bad")
@@ -371,7 +468,8 @@ class TestTransformerDecode:
 
         for bucket in eng.prefill_buckets:
             sizes = gathers(model.prefill_fn, sd((bucket,), i32),
-                            sd((), i32), sd((), i32), sd((mb,), i32))
+                            sd((), i32), sd((), i32), sd((mb,), i32),
+                            sd((), i32))
             one_layer = mb * bs * d_model
             assert sizes.count(one_layer) == 2 * num_layers     # K and V
             assert max(sizes) == one_layer
@@ -575,7 +673,7 @@ class TestTransformerDecode:
         assert rb < B and cb < mb
         i32 = np.int32
         jaxpr = jax.make_jaxpr(model.step_fn)(
-            params, model.cache_spec(1729, bs), sd((B,), i32),
+            params, model.cache_spec(1729, bs, B), sd((B,), i32),
             sd((B,), i32), sd((B, mb), i32), sd((B,), np.bool_)).jaxpr
 
         def shapes(jaxpr):

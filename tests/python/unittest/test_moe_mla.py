@@ -126,7 +126,7 @@ class Recorder:
             self.seen.append((kind,) + tuple(np.asarray(a) for a in arrays))
         return keep
 
-    def prefill_fn(self, params, cache, tokens, start, length, table):
+    def prefill_fn(self, params, cache, tokens, start, length, table, slot):
         nid, cache, aux, logits = M.moe_mla_decode_prefill(
             params, self.model.cfg, cache, tokens, start, length, table,
             use_pallas=False, interpret=self.model.interpret,

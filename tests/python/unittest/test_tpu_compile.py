@@ -220,7 +220,7 @@ def test_gpt2_decode_step_keeps_heads_in_lanes(one_chip):
     params = jax.tree_util.tree_map(on_chip, jax.eval_shape(
         lambda: TransformerDecodeModel(cfg, flash="off").params))
     model = TransformerDecodeModel(cfg, params=params, flash="off")
-    cache = jax.tree_util.tree_map(on_chip, model.cache_spec(1729, bs))
+    cache = jax.tree_util.tree_map(on_chip, model.cache_spec(1729, bs, B))
     sd = lambda s, d: jax.ShapeDtypeStruct(s, d,               # noqa: E731
                                            sharding=one_chip)
     compiled = jax.jit(model.step_fn, donate_argnums=(1,)).lower(
@@ -233,3 +233,71 @@ def test_gpt2_decode_step_keeps_heads_in_lanes(one_chip):
         assert positions < rb * cb * bs, "head-split buffer f32[%s,12,64]" % dims
     assert compiled.memory_analysis().temp_size_in_bytes \
         < rb * mb * bs * 768 * 4
+
+
+@pytest.mark.parametrize("state_dtype", [jnp.float32, jnp.bfloat16])
+def test_kda_step_kernel_compiles_in_place_at_published_widths(one_chip,
+                                                               state_dtype):
+    """`mx_kda_step` at Kimi-Linear's widths (32 heads of 128 x 128, 256
+    slots, six layers' pool): the packed tile's transpose, the per-head lane
+    slices and a row's 2 MB block pass Mosaic; the 3.2 GB pool is aliased to
+    the output, never copied (temporaries stay in the megabytes)."""
+    from mxnet_tpu.kernels import kda
+    L, B, H, dk = 6, 256, 32, 128
+    sd = lambda s, d=jnp.float32: jax.ShapeDtypeStruct(     # noqa: E731
+        s, d, sharding=one_chip)
+
+    def step(state, q, k, v, g, beta, active):
+        return kda.kda_step(state, 2, q, k, v, g, beta, active,
+                            use_pallas=True)
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        sd((L, B, H, dk, dk), state_dtype), sd((B, H, dk)), sd((B, H, dk)),
+        sd((B, H, dk)), sd((B, H, dk)), sd((B, H)),
+        sd((B,), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    pool = L * B * H * dk * dk * jnp.dtype(state_dtype).itemsize
+    assert mem.alias_size_in_bytes == pool
+    assert mem.temp_size_in_bytes < 64 << 20
+
+
+def test_kimi_step_rematerialises_nothing_on_a_donated_pool(one_chip):
+    """The Kimi-Linear decode step at the served cell's sizes (256 slots):
+    no instruction the compiler rematerialised reads or writes a donated
+    pool. A chain of in-place updates of the tail pool, a layer at a time,
+    was rematerialised at this size and read the pool AFTER it had been
+    overwritten: wrong tokens on the chip, invisible to every CPU test
+    (PERF.md, PR 34). Every layer now reads the pools as they came in and
+    the program writes the tails once."""
+    import json
+    import os
+    from mxnet_tpu.models.kimi_linear import (KimiLinearConfig,
+                                              KimiLinearDecodeModel,
+                                              init_kimi_linear)
+    cells = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
+                         "..", "..", "benchmark", "cells")
+    with open(os.path.join(cells, "configs", "kimi_linear_ep8.json")) as f:
+        cfg = KimiLinearConfig.from_dict(json.load(f))
+    with open(os.path.join(cells, "traffic",
+                           "decode_batch_longgen.json")) as f:
+        e = json.load(f)["engine"]
+    on_chip = lambda t: jax.tree_util.tree_map(                 # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    params = on_chip(jax.eval_shape(lambda: init_kimi_linear(
+        cfg, jax.random.PRNGKey(0), jnp.bfloat16)))
+    model = KimiLinearDecodeModel(cfg, params=params, flash="on")
+    B, bs = e["batch_size"], e["block_size"]
+    mb = e["max_seq_len"] // bs
+    cache = on_chip(model.cache_spec(e["num_blocks"], bs, B))
+    sd = lambda s, d: jax.ShapeDtypeStruct(s, d,               # noqa: E731
+                                           sharding=one_chip)
+    text = jax.jit(model.step_fn, donate_argnums=(1,)).lower(
+        params, cache, sd((B,), jnp.int32), sd((B,), jnp.int32),
+        sd((B, mb), jnp.int32), sd((B,), jnp.bool_)).compile().as_text()
+    assert text.count("mx_kda_step") >= len(cfg.kda_layers)
+    pools = ["[%s]" % ",".join(str(n) for n in p.shape)
+             for p in jax.tree_util.tree_leaves(cache)]
+    again = [ln for ln in text.splitlines() if ".remat" in ln.split("=")[0]
+             and any(p in ln for p in pools)]
+    assert not again, again[:2]
