@@ -4,8 +4,14 @@ the prefill chunk's attention.
 
 `serving/kvcache.py` owns the HOST side (which blocks a sequence holds);
 the model families of `models/` own their contractions and layouts. What
-they share is here, and is pure `jax.lax` today: a paged-attention Pallas
-kernel would go in behind `live_walk`.
+they share is here: addressing, `NULL_BLOCK`, `MASKED`, the lax walk
+(`walk_plan`, `live_walk`, `softmax_fold`: every family's on the CPU, and
+the GPT-2 family's everywhere) and, for the latent family whose 128 heads
+share ONE pool row, the walk as a Pallas kernel that reads the pool in
+place (`paged_latent_attention`, ``mx_paged_latent_attn``). A row a head
+(GPT-2: twin pools, heads folded into the lanes) is another contraction
+and would be another kernel; it can reuse the kernel's row compaction,
+its page copies by block table and its running softmax as they stand.
 
 **The page format.** A pool is ``(layers, num_blocks, block_size, width)``.
 Position ``p`` of a sequence whose block table is ``table`` lives at
@@ -23,9 +29,13 @@ because of it.
 from __future__ import annotations
 
 import collections
+import functools
 
+import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .flash_attention import blockwise_attention, flash_attention_with_lse
 
@@ -180,6 +190,248 @@ def softmax_fold(carry, s, tpos, pos_b, axis, weigh):
     alpha = jnp.exp(m - m_new)
     return (m_new, den * alpha + jnp.sum(p, axis=axis),
             acc * alpha[..., None] + weigh(p))
+
+
+# ---------------------------------------------------------------------------
+# the latent family's walk as one kernel
+# ---------------------------------------------------------------------------
+# The rows of a decode step as `paged_latent_attention` takes them.
+PagedRows = collections.namedtuple("PagedRows",
+                                   "positions tables active interpret")
+
+#: VMEM a chunk of the kernel's walk may take: its two page buffers and the
+#: float32 scores, weights and mask of one product.
+_CHUNK_VMEM = 4 << 20
+
+#: Pages whose copies are issued straight-line, as one group: the kernel's
+#: trace and lowering grow with it (0.55 s of set-up at 32, three sites),
+#: its issue cost falls with it.
+_COPY_GROUP = 8
+
+
+def chunk_pages(heads, mb, block_size, width, itemsize):
+    """Pages a chunk of `paged_latent_attention`'s walk: the largest power
+    of two whose double-buffered rows and three ``[heads, positions]``
+    float32 temporaries fit `_CHUNK_VMEM`, no more than the table."""
+    pages = 1
+    while pages * 2 <= mb and 4 * pages * block_size * (
+            2 * width * itemsize + 3 * heads * 4) <= _CHUNK_VMEM:
+        pages *= 2
+    return pages
+
+
+def _latent_attn_kernel(layer_ref, rows_ref, n_ref, pos_ref, tab_ref, q_ref,
+                        new_ref, pool_ref, o_ref, pool_out_ref, buf, sems,
+                        back_sem, slot_ref, m_ref, den_ref, acc_ref, *,
+                        sm_scale, width, mb):
+    """Grid step ``i``: the ``i``-th ACTIVE row. Its pages arrive a chunk
+    at a time in ``buf`` ``[2, pages, block_size, row]``, the next chunk's
+    copies (the next row's first, at a row's last) started before this
+    chunk's products; ``slot_ref`` carries which half is due from row to
+    row. The row's NEW latent row (``new_ref``) is set into its last page
+    as that page passes through VMEM, and the page is copied back to the
+    pool (``pool_out_ref`` is ``pool_ref``'s own buffer). Inside a chunk:
+    `softmax_fold`'s arithmetic."""
+    i = pl.program_id(0)
+    n = n_ref[0]
+    _, pages, bs, row_width = buf.shape
+    span = pages * bs
+    dt = buf.dtype
+
+    group = min(pages, _COPY_GROUP)
+
+    def chunk_copies(r, c, pages_from, one_page):
+        """The copies of chunk ``c`` of row ``r``, the LIVE pages only (a
+        row's pages end at its own position): ``pages_from(j)`` for every
+        whole group of ``group`` pages from page ``j`` on, straight-line;
+        ``one_page(j)`` for each page left over."""
+        live = jnp.minimum(pos_ref[r] // bs + 1 - c * pages, pages)
+        whole = live // group
+
+        def groups(g, carry):
+            pages_from(g * group)
+            return carry
+
+        def page(j, carry):
+            one_page(j)
+            return carry
+        lax.fori_loop(0, whole, groups, 0)
+        lax.fori_loop(whole * group, live, page, 0)
+
+    def start(r, c, slot):
+        def one_page(j):
+            pltpu.make_async_copy(
+                pool_ref.at[layer_ref[0], tab_ref[r * mb + c * pages + j]],
+                buf.at[slot, j], sems.at[slot]).start()
+
+        def pages_from(j):
+            # issuing 20 KB copies is what the walk costs beside its
+            # products: no loop and no branch inside a group
+            for k in range(group):
+                one_page(j + k)
+        chunk_copies(r, c, pages_from, one_page)
+
+    def wait(r, c, slot):
+        # a wait takes its amount from the shape it names: a page, or a
+        # group's pages in one
+        def pages_at(j, count):
+            at = buf.at[slot, pl.ds(j, count)]
+            pltpu.make_async_copy(at, at, sems.at[slot]).wait()
+        chunk_copies(r, c, lambda j: pages_at(j, group),
+                     lambda j: pages_at(j, 1))
+
+    @pl.when(i < n)
+    def _():
+        r = rows_ref[i]
+        pos = pos_ref[r]
+        chunks = pos // span + 1
+
+        @pl.when(i == 0)
+        def _():
+            # a partly filled chunk leaves the rows of an earlier one
+            # behind it (finite, masked to weight 0): never VMEM as found
+            buf[...] = jnp.zeros_like(buf)
+            slot_ref[0] = 0
+            start(r, 0, 0)
+
+        first_slot = slot_ref[0]
+        m_ref[...] = jnp.full_like(m_ref, MASKED)
+        den_ref[...] = jnp.zeros_like(den_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        q = q_ref[0]                                    # [H, row]
+
+        def fold(c, slot):
+            lat = buf[slot].reshape(span, row_width)
+            s = lax.dot_general(q, lat, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * sm_scale
+            tpos = c * span + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(tpos <= pos, s, MASKED)
+            m = m_ref[...]
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            m_ref[...] = m_new
+            den_ref[...] = den_ref[...] * alpha + jnp.sum(p, axis=1,
+                                                          keepdims=True)
+            acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+                p.astype(dt), lat[:, :width],
+                preferred_element_type=jnp.float32)
+
+        def whole_chunk(c, carry):
+            slot = (first_slot + c) % 2
+            start(r, c + 1, 1 - slot)
+            wait(r, c, slot)
+            fold(c, slot)
+            return carry
+
+        lax.fori_loop(0, chunks - 1, whole_chunk, 0)
+
+        # the row's last chunk holds the position this step writes: the new
+        # row goes into its page here, and the page back to the pool ahead
+        # of the next row's first copies
+        c = chunks - 1
+        slot = (first_slot + c) % 2
+        wait(r, c, slot)
+        j = pos // bs - c * pages
+        page = buf[slot, j].astype(jnp.float32)
+        at = lax.broadcasted_iota(jnp.int32, page.shape, 0)
+        buf[slot, j] = jnp.where(at == pos % bs,
+                                 new_ref[0].astype(jnp.float32),
+                                 page).astype(dt)
+        back = pltpu.make_async_copy(
+            buf.at[slot, j],
+            pool_out_ref.at[layer_ref[0], tab_ref[r * mb + pos // bs]],
+            back_sem)
+        back.start()
+
+        @pl.when(i + 1 < n)
+        def _():
+            start(rows_ref[i + 1], 0, 1 - slot)
+
+        fold(c, slot)
+        slot_ref[0] = (first_slot + chunks) % 2
+        o_ref[0] = acc_ref[...] / den_ref[...]
+        # before this half of ``buf`` is due again, and before the end
+        back.wait()
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "width",
+                                             "interpret"))
+def paged_latent_attention(q, new_rows, pool, layer, positions, tables,
+                           active, *, sm_scale, width, interpret=False):
+    """One layer's cache write and decode attention of the latent family,
+    the pool read and written in place: ONE ``pallas_call`` named
+    ``mx_paged_latent_attn``. ``q`` ``(B, H, row)`` holds every head's
+    query against a whole pool row, ``new_rows`` ``(B, row)`` the rows'
+    new latent rows, ``pool`` ``(L, blocks, block_size, row)`` stays in HBM
+    and is used at ``layer``; row ``b`` writes position ``positions[b]`` of
+    ``tables[b]`` and attends positions ``0 .. positions[b]``. Returns
+    ``(u, pool)``: ``u`` ``(B, H, width)`` float32, the softmax-weighted
+    sum of the rows' first ``width`` numbers (an inactive row's is 0), and
+    the pool with the active rows' new rows in it (an inactive row writes
+    nothing).
+
+    The grid walks the ACTIVE rows only (compacted through scalar prefetch
+    as `kda._step_pallas` does); a row copies its own pages ``0 ..
+    positions[b] // block_size`` and no further, each one contiguous copy
+    into VMEM, and no gathered piece is ever written to HBM. The pool is
+    aliased to the kernel's output and written by it alone, a page a row:
+    a chain of in-place updates in XLA's hands beside the kernel's reads
+    was rematerialised at the Kimi-Linear cell's sizes (PERF.md, PR 35; PR
+    34 for what that can do). Arithmetic: `softmax_fold`'s, a chunk of
+    `chunk_pages` pages a fold (operands in the pool's dtype, float32
+    accumulation and softmax). Jitted, though it only ever runs inside a
+    program: the layers share one trace."""
+    B, H, row_width = q.shape
+    bs = pool.shape[2]
+    mb = tables.shape[1]
+    pages = chunk_pages(H, mb, bs, row_width, pool.dtype.itemsize)
+    n = jnp.sum(active.astype(jnp.int32))
+    order = jnp.argsort(jnp.logical_not(active), stable=True).astype(
+        jnp.int32)
+    at = jnp.minimum(jnp.arange(B, dtype=jnp.int32), jnp.maximum(n - 1, 0))
+    rows = jnp.take(order, at)
+    row_block = lambda i, layer, rows, *_: (rows[i], 0, 0)      # noqa: E731
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5, grid=(B,),
+        in_specs=[pl.BlockSpec((1, H, row_width), row_block),
+                  pl.BlockSpec((1, 1, row_width), row_block), in_hbm],
+        out_specs=[pl.BlockSpec((1, H, width), row_block), in_hbm],
+        scratch_shapes=[pltpu.VMEM((2, pages, bs, row_width), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SemaphoreType.DMA(()),
+                        pltpu.SMEM((1,), jnp.int32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, width), jnp.float32)])
+    # (side effects: the call writes the pool; XLA neither drops it nor
+    # makes it twice)
+    params = None if interpret else pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",), has_side_effects=True)
+    u, pool = pl.pallas_call(
+        functools.partial(_latent_attn_kernel, sm_scale=sm_scale,
+                          width=width, mb=mb),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, H, width), jnp.float32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        # operands count the scalar-prefetch ones: 5 of them, q, new_rows
+        input_output_aliases={7: 1},
+        compiler_params=params, interpret=interpret,
+        name="mx_paged_latent_attn")(
+            jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)), rows,
+            jnp.reshape(n, (1,)), positions.astype(jnp.int32),
+            tables.astype(jnp.int32).reshape(-1), q.astype(pool.dtype),
+            new_rows.astype(pool.dtype)[:, None], pool)
+    # (the kernel leaves an inactive row's block unwritten)
+    return jnp.where(active[:, None, None], u, 0.0), pool
+
+
+def paged_walked(positions, active, block_size):
+    """Positions whose pages `paged_latent_attention` copies for one layer:
+    every active row's own pages, whole."""
+    return jnp.sum(jnp.where(active,
+                             (positions // block_size + 1) * block_size, 0))
 
 
 def chunk_spans(chunk, table_len, floor=1024):

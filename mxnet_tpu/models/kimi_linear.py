@@ -372,8 +372,8 @@ def kimi_linear_decode_step(params, cfg, cache, token_ids, positions, tables,
     pool, state, tail = cache["latent"], cache["kda_state"], cache["kda_conv"]
     bs = pool.shape[2]
     blk, at = paged.step_addresses(tables, positions, active, bs)
-    plan = paged.walk_plan(positions, tables, bs, cfg.step_row_block,
-                           cfg.step_col_blocks * bs)
+    walk, walked = M._step_walk(cfg, positions, tables, active, bs,
+                                use_pallas, interpret)
     x = params["embed"][token_ids].astype(jnp.float32)
     all_counts, tails = [], []
     for l, lp in enumerate(params["layers"]):
@@ -391,7 +391,7 @@ def kimi_linear_decode_step(params, cfg, cache, token_ids, positions, tables,
                 def attend(h, lp=lp, li=li):
                     nonlocal pool
                     out, pool = M._step_attend(cfg, lp, h, pool, li,
-                                               positions, blk, at, plan)
+                                               positions, blk, at, walk)
                     return out
                 x, counts = M._block(cfg, lp, x, attend, active)
             if counts is not None:
@@ -401,6 +401,7 @@ def kimi_linear_decode_step(params, cfg, cache, token_ids, positions, tables,
     rows = jnp.sum(active.astype(jnp.int32))
     # cached tokens the MLA layers attended over, the active rows together
     aux["kv_live_tokens"] = jnp.sum(jnp.where(active, positions + 1, 0))
+    aux["kv_walked_tokens"] = walked    # one MLA layer's walk
     aux["kda_rows_updated"] = rows * len(tails)
     aux["kda_layer_steps"] = jnp.int32(len(tails))
     if tails:

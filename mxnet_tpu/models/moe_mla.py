@@ -504,12 +504,34 @@ def moe_mla_decode_prefill(params, cfg, cache, tokens, start, length, table,
     return out + (logits,) if with_logits else out
 
 
-def _absorbed_attention(cfg, lp, q_nope, q_rope, pool, l, plan):
+def _step_walk(cfg, positions, tables, active, block_size, use_pallas,
+               interpret):
+    """What the latent layers of one decode step share, made once:
+    ``(walk, walked)``. Kernel tier: the rows as
+    `paged_attention.paged_latent_attention` takes them; lax tier:
+    `paged_attention.walk_plan` over the config's row block and span.
+    ``walked`` counts the positions one layer's walk covers."""
+    if use_pallas or interpret:
+        return (paged.PagedRows(positions, tables, active, interpret),
+                paged.paged_walked(positions, active, block_size))
+    plan = paged.walk_plan(positions, tables, block_size, cfg.step_row_block,
+                           cfg.step_col_blocks * block_size)
+    return plan, plan.walked
+
+
+def _absorbed_attention(cfg, lp, q_nope, q_rope, pool, l, walk,
+                        new_rows=None):
     """The decode step's attention in the latent space, over the live
-    positions only (`paged_attention.live_walk`, ``plan`` the step's).
-    ``q_nope`` ``[B, H, dn]``, ``q_rope`` ``[B, H, dr]``, ``pool`` ``[L,
-    blocks, bs, row]`` read at layer ``l``. What is live at once is a
-    piece's gathered latent rows and scores."""
+    positions only. ``q_nope`` ``[B, H, dn]``, ``q_rope`` ``[B, H, dr]``,
+    ``pool`` ``[L, blocks, bs, row]`` used at layer ``l``; ``walk`` is
+    `_step_walk`'s. Returns ``(out [B, H * dv], pool)``.
+
+    Lax tier (the CPU's, and the kernel's reference):
+    `paged_attention.live_walk` over a ``pool`` that HOLDS the rows' new
+    latent rows already; what is live at once is a piece's gathered latent
+    rows and scores. Kernel tier:
+    `paged_attention.paged_latent_attention` reads the pool's pages in
+    place and sets ``new_rows`` ``[B, row]`` into it itself."""
     H, dn, dv, rkv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                       cfg.v_head_dim, cfg.kv_lora_rank)
     dt = pool.dtype
@@ -522,49 +544,63 @@ def _absorbed_attention(cfg, lp, q_nope, q_rope, pool, l, plan):
     qq = jnp.pad(qq, ((0, 0), (0, 0), (0, pool.shape[3] - qq.shape[-1])))
     sm = 1.0 / _np.sqrt(dn + cfg.qk_rope_head_dim)
 
-    def rows_block(qq_b, pos_b, walk):
-        def fold(carry, pieces, tpos):
-            lat, = pieces                           # [rb, span, row]
-            s = jnp.einsum("bhc,btc->bht", qq_b, lat,
-                           preferred_element_type=jnp.float32) * sm
-            return paged.softmax_fold(
-                carry, s, tpos, pos_b, 2,
-                lambda p: jnp.einsum("bht,btr->bhr", p.astype(dt),
-                                     lat[..., :rkv],
-                                     preferred_element_type=jnp.float32))
+    if isinstance(walk, paged.PagedRows):
+        u, pool = paged.paged_latent_attention(
+            qq, new_rows, pool, l, walk.positions, walk.tables, walk.active,
+            sm_scale=float(sm), width=rkv, interpret=walk.interpret)
+    else:
+        def rows_block(qq_b, pos_b, pieces_of):
+            def fold(carry, pieces, tpos):
+                lat, = pieces                       # [rb, span, row]
+                s = jnp.einsum("bhc,btc->bht", qq_b, lat,
+                               preferred_element_type=jnp.float32) * sm
+                return paged.softmax_fold(
+                    carry, s, tpos, pos_b, 2,
+                    lambda p: jnp.einsum("bht,btr->bhr", p.astype(dt),
+                                         lat[..., :rkv],
+                                         preferred_element_type=jnp.float32))
 
-        _, den, acc = walk(fold, (qq_b.shape[0], H), rkv)
-        return acc / den[..., None]
+            _, den, acc = pieces_of(fold, (qq_b.shape[0], H), rkv)
+            return acc / den[..., None]
 
-    u = paged.live_walk(plan, (pool,), l, qq, rows_block)
+        u = paged.live_walk(walk, (pool,), l, qq, rows_block)
     o = jnp.einsum("bhr,rhv->bhv", u.astype(dt), w_v,
                    preferred_element_type=jnp.float32)
-    return o.reshape(-1, H * dv)
+    return o.reshape(-1, H * dv), pool
 
 
-def _step_attend(cfg, lp, h, pool, l, positions, blk, slot, plan):
+def _step_attend(cfg, lp, h, pool, l, positions, blk, slot, walk):
     """One layer's latent attention of a decode step: write the rows' new
-    latent row into layer ``l`` of ``pool`` and attend absorbed over the
-    live positions. ``(out [B, H * dv], pool)``."""
+    latent row into layer ``l`` of ``pool`` (here on the lax tier, inside
+    the kernel on its tier) and attend absorbed over the live positions.
+    ``(out [B, H * dv], pool)``."""
     q_nope, q_rope, rows = _mla_project(cfg, lp, h, positions)
-    pool = pool.at[l, blk, slot].set(_cache_rows(rows, pool))
-    return _absorbed_attention(cfg, lp, q_nope, q_rope, pool, l, plan), pool
+    rows = _cache_rows(rows, pool)
+    if not isinstance(walk, paged.PagedRows):
+        pool = pool.at[l, blk, slot].set(rows)
+    return _absorbed_attention(cfg, lp, q_nope, q_rope, pool, l, walk, rows)
 
 
 @jax.named_scope("decode.step")      # the trace's device-side name
 def moe_mla_decode_step(params, cfg, cache, token_ids, positions, tables,
-                        active, *, with_logits=False):
+                        active, *, use_pallas=False, interpret=False,
+                        with_logits=False):
     """Fixed-shape batched decode step, one token per active row, attention
     absorbed into the latent space. The DecodeEngine step seam ``(params,
     cache, token_ids, positions, tables, active) -> (next_ids, cache, aux)``.
-    A row contracts only over its own gathered blocks; an inactive row
-    writes to the null block, is routed to no expert and counted nowhere.
-    ``with_logits`` (tests) appends the rows' float32 logits."""
+    A row contracts only over its own blocks; an inactive row writes to the
+    null block (on the kernel tier: nowhere), is routed to no expert and
+    counted nowhere. ``aux`` counts
+    the walk as the GPT-2 step does: ``kv_live_tokens`` (``positions + 1``,
+    the active rows together) and ``kv_walked_tokens`` (positions the walk
+    of ONE layer read: the kernel's own pages, or the lax tier's rows x
+    span x pieces). ``with_logits`` (tests) appends the rows' float32
+    logits."""
     pool = cache["latent"]
     bs = pool.shape[2]
     blk, slot = paged.step_addresses(tables, positions, active, bs)
-    plan = paged.walk_plan(positions, tables, bs, cfg.step_row_block,
-                           cfg.step_col_blocks * bs)
+    walk, walked = _step_walk(cfg, positions, tables, active, bs, use_pallas,
+                              interpret)
     x = params["embed"][token_ids].astype(jnp.float32)
     all_counts = []
     for l, lp in enumerate(params["layers"]):
@@ -572,7 +608,7 @@ def moe_mla_decode_step(params, cfg, cache, token_ids, positions, tables,
             def attend(h, l=l, lp=lp):
                 nonlocal pool
                 out, pool = _step_attend(cfg, lp, h, pool, l, positions,
-                                         blk, slot, plan)
+                                         blk, slot, walk)
                 return out
             x, counts = _block(cfg, lp, x, attend, active)
             if counts is not None:
@@ -581,6 +617,7 @@ def moe_mla_decode_step(params, cfg, cache, token_ids, positions, tables,
     aux = _aux(cfg, all_counts)
     # cached tokens this step attended over, the active rows together
     aux["kv_live_tokens"] = jnp.sum(jnp.where(active, positions + 1, 0))
+    aux["kv_walked_tokens"] = walked
     out = (jnp.argmax(logits, axis=-1).astype(jnp.int32), {"latent": pool},
            aux)
     return out + (logits,) if with_logits else out
@@ -592,10 +629,10 @@ class MoEMLADecodeModel(DecodeModel):
     >>> model = MoEMLADecodeModel(cfg, params=params)       # or seed=
     >>> eng = DecodeEngine(**model.engine_kwargs(), max_seq_len=4096, ...)
 
-    ``flash`` picks the prefill attention tier
-    (`DecodeModel.resolve_flash`). The cache is ONE pool of latent rows in
-    the parameters' dtype; with a ``mesh`` it is stated replicated: a
-    latent row has no head axis to shard."""
+    ``flash`` picks the kernel tier of the prefill attention AND of the
+    step's walk (`DecodeModel.resolve_flash`). The cache is ONE pool of
+    latent rows in the parameters' dtype; with a ``mesh`` it is stated
+    replicated: a latent row has no head axis to shard."""
 
     def __init__(self, cfg, params=None, seed=0, dtype=jnp.bfloat16,
                  flash=None, mesh=None):
@@ -626,5 +663,6 @@ class MoEMLADecodeModel(DecodeModel):
             use_pallas=self.use_pallas, interpret=self.interpret)
 
     def step_fn(self, params, cache, token_ids, positions, tables, active):
-        return moe_mla_decode_step(params, self.cfg, cache, token_ids,
-                                   positions, tables, active)
+        return moe_mla_decode_step(
+            params, self.cfg, cache, token_ids, positions, tables, active,
+            use_pallas=self.use_pallas, interpret=self.interpret)

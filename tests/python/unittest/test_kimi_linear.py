@@ -303,6 +303,29 @@ def test_prefill_in_pieces_then_steps_match_the_one_full_forward(
         if config is not MLA_HEAVY else True
 
 
+def test_the_kernel_tier_step_serves_the_lax_tiers_tokens():
+    """Two latent layers beside a KDA layer, batched decode through a real
+    engine: the step on the kernels' tier (`mx_paged_latent_attn` and
+    `mx_kda_step`, interpreted) yields the lax tier's tokens; both tiers
+    count what their latent walks read, ONE layer's."""
+    params = ref.init_params(MLA_HEAVY, jax.random.PRNGKey(2))
+    prompts = [list(tokens_of(30 + i, (n,)))
+               for i, n in enumerate([3, 16, 29, 41, 7])]
+    kw = dict(new_tokens=8, prefill_buckets=(16,))
+    plain, _, lax_stats = serve(MLA_HEAVY, params, "kimisteplax", prompts,
+                                **kw)
+    kern, rec, stats = serve(MLA_HEAVY, params, "kimistepkern", prompts,
+                             flash="interpret", **kw)
+    assert [list(o) for _, o in kern] == [list(o) for _, o in plain]
+    assert worst_logit_gap(MLA_HEAVY, params, kern, rec)[0] < TOL
+    live = sum(len(q) + i + 1 for q, o in kern for i in range(len(o) - 1))
+    m, lm = stats["model"], lax_stats["model"]
+    assert m["kv_live_tokens"] == lm["kv_live_tokens"] == live
+    rows = stats["tokens"] - stats["prefills"]
+    assert live <= m["kv_walked_tokens"] < live + 4 * rows
+    assert lm["kv_walked_tokens"] > m["kv_walked_tokens"]
+
+
 def test_mla_projection_without_rotary_does_not_read_positions(params):
     """(e) `mla_use_nope`: the same rows whatever the positions; with the
     rotary on, they differ."""

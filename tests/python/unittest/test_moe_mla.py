@@ -138,7 +138,8 @@ class Recorder:
     def step_fn(self, params, cache, token_ids, positions, tables, active):
         ids, cache, aux, logits = M.moe_mla_decode_step(
             params, self.model.cfg, cache, token_ids, positions, tables,
-            active, with_logits=True)
+            active, use_pallas=False, interpret=self.model.interpret,
+            with_logits=True)
         jax.debug.callback(self._keep("step"), tables, positions, active,
                            logits)
         return ids, cache, aux
@@ -230,6 +231,26 @@ def test_the_flash_tier_prefill_serves_the_same_tokens(params):
     assert worst_logit_gap(params, flash, rec)[0] < TOL
 
 
+def test_the_kernel_tier_step_serves_the_lax_tiers_tokens(params):
+    """Batched decode through a real engine, rows of ragged lengths joining
+    and leaving: the step whose walk is `mx_paged_latent_attn`
+    (interpreted) yields the lax walk's tokens, and both tiers count what
+    their walks read."""
+    kw = dict(new_tokens=9, prompts=[3, 16, 29, 41, 7, 12], batch_size=4)
+    plain, _, lax_stats = serve(params, "steplax", **kw)
+    kern, rec, stats = serve(params, "stepkern", flash="interpret", **kw)
+    assert [o for _, o in kern] == [o for _, o in plain]
+    assert worst_logit_gap(params, kern, rec)[0] < TOL
+    live = sum(len(q) + i + 1 for q, o in kern for i in range(len(o) - 1))
+    m, lm = stats["model"], lax_stats["model"]
+    assert m["kv_live_tokens"] == lm["kv_live_tokens"] == live
+    # a row's own pages of 4, whole: under a page a row and step over
+    rows = stats["tokens"] - stats["prefills"]
+    assert live <= m["kv_walked_tokens"] < live + 4 * rows
+    # the lax walk: every row as far as its block of 2 rows' longest
+    assert lm["kv_walked_tokens"] > m["kv_walked_tokens"]
+
+
 def test_bfloat16_operands_fail_the_tolerance(params):
     """The same comparison with the program's operands (weights, cache,
     matrix products' inputs) in bfloat16, the reference on the very same
@@ -257,7 +278,7 @@ def test_absorbed_step_equals_expanded_attention(params):
         M._cache_rows(rows, pool))
     plan = walk_plan(pos[-1:], table[None], bs, cfg.step_row_block,
                      cfg.step_col_blocks * bs)
-    got = M._absorbed_attention(
+    got, _ = M._absorbed_attention(
         cfg, lp, q_nope[-1:], q_rope[-1:], pool, 1, plan)  # [1, H * dv]
     k, v = M._mla_expand(cfg, rows, *M._expansion_weights(cfg, lp["wkv_b"]))
     q = jnp.concatenate([q_nope, q_rope], -1)[-1]         # [H, dn + dr]

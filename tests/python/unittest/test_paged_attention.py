@@ -3,7 +3,10 @@ against a whole-table masked softmax in float64, under a fold shaped like
 each family's (GPT-2: twin pools, positions on axis 1, `HIGHEST`; latent:
 one pool scored and summed, positions on axis 2); the addressing of both
 seams; the plan's count; the spans a prefill chunk's keys and values are
-made over."""
+made over; the latent family's kernel (`paged_latent_attention`, interpreted)
+against that walk under the family's own fold."""
+import functools
+
 import numpy as np
 import pytest
 
@@ -11,8 +14,10 @@ import jax
 import jax.numpy as jnp
 
 from mxnet_tpu.kernels.paged_attention import (
-    NULL_BLOCK, chunk_addresses, chunk_spans, live_walk, softmax_fold,
+    NULL_BLOCK, PagedRows, chunk_addresses, chunk_pages, chunk_spans,
+    live_walk, paged_latent_attention, paged_walked, softmax_fold,
     span_index, step_addresses, walk_plan, walk_sizes)
+from mxnet_tpu.models import moe_mla
 from mxnet_tpu.models.transformer import _live_attention
 
 BS, H, DH, ROWS, SPAN = 4, 2, 8, 4, 8       # span: two table blocks a piece
@@ -212,3 +217,174 @@ def test_span_index_is_the_smallest_span_that_holds_the_chunks_end(
     assert got.dtype == np.int32 and got.tolist() == want
     assert set(got.tolist()) == set(range(len(spans)))  # every span chosen
     assert int(span_index(spans, 3)) == 0               # host integers too
+
+
+# ---------------------------------------------------------------------------
+# the latent family's kernel against its lax walk
+# ---------------------------------------------------------------------------
+K_BS, K_MB = 16, 40             # tables of 640 positions: two chunks of 512
+K_ROWS = {                      # name -> (position, active)
+    "one_position": (0, True),
+    "ends_on_the_first_page_edge": (15, True),
+    "inactive_between": (200, False),
+    "starts_a_second_page": (16, True),
+    "ends_on_the_chunk_edge": (511, True),
+    "starts_a_second_chunk": (512, True),
+    "inactive_long": (639, False),
+    "at_max_seq_len": (K_MB * K_BS - 1, True),
+    "ragged": (300, True),
+}
+K_FAR = 3.0e4                   # what every block no table names holds
+
+
+def latent_cfg(heads):
+    """The latent family at small widths (a 192-number row in 256 lanes)
+    and the cells' head counts: the kernel adapts to ``heads`` alone."""
+    return moe_mla.MoEMLAConfig.from_dict({
+        "hidden_size": 64, "num_hidden_layers": 2,
+        "first_k_dense_replace": 1, "num_attention_heads": heads,
+        "q_lora_rank": None, "kv_lora_rank": 128, "qk_nope_head_dim": 16,
+        "qk_rope_head_dim": 64, "v_head_dim": 16, "intermediate_size": 64,
+        "moe_intermediate_size": 16, "n_routed_experts": 4,
+        "n_shared_experts": 1, "num_experts_per_tok": 2,
+        "routed_scaling_factor": 1.0, "rms_norm_eps": 1e-5,
+        "rope_theta": 1e4, "vocab_size": 32, "mla_use_nope": True})
+
+
+@functools.lru_cache(maxsize=None)
+def both_tiers(heads, dtype):
+    """One step's attention of layer ``LAYER`` over the rows of `K_ROWS`,
+    through `moe_mla._absorbed_attention` on the lax tier (`live_walk` and
+    today's fold, over a pool that holds the step's new rows) and on the
+    interpreted kernel tier (handed the pool BEFORE the write, `K_FAR` at
+    the positions to be written, and the new rows beside it). Every block
+    that no live page names, the null block among them, holds `K_FAR`.
+    Returns the two results, the two counts, the pool the kernel handed
+    back and the pool it should be."""
+    cfg = latent_cfg(heads)
+    dt = jnp.dtype(dtype)
+    rng = np.random.RandomState(heads)
+    positions = np.asarray([p for p, _ in K_ROWS.values()], np.int32)
+    active = np.asarray([a for _, a in K_ROWS.values()])
+    B = len(positions)
+    pages = positions // K_BS + 1
+    blocks = 1 + rng.permutation(int(pages.sum()) + 7)
+    tables = np.full((B, K_MB), NULL_BLOCK, np.int32)
+    at = 0
+    for b in range(B):
+        tables[b, :pages[b]] = blocks[at:at + pages[b]]
+        at += pages[b]
+    before = np.full((2, len(blocks) + 1, K_BS, cfg.cache_row_width), K_FAR,
+                     np.float32)
+    named = tables[tables != NULL_BLOCK]
+    before[:, named] = rng.standard_normal(
+        (2, len(named), K_BS, cfg.cache_row_width))
+    new_rows = rng.standard_normal((B, cfg.cache_row_width)) \
+        .astype(np.float32)
+    blk, slot = tables[np.arange(B), positions // K_BS], positions % K_BS
+    before[:, blk, slot] = K_FAR            # not yet written
+    written = before.copy()
+    written[LAYER, blk[active], slot[active]] = new_rows[active]
+    before, written, new_rows = (jnp.asarray(a, dt)
+                                 for a in (before, written, new_rows))
+    lp = {"wkv_b": jnp.asarray(0.2 * rng.standard_normal(
+        (cfg.kv_lora_rank, heads * 32)), dt)}
+    q_nope = jnp.asarray(rng.standard_normal((B, heads, 16)), dt)
+    q_rope = jnp.asarray(rng.standard_normal((B, heads, 64)), jnp.float32)
+    pos, tab, act = (jnp.asarray(a) for a in (positions, tables, active))
+
+    def attend(walk, pool):
+        out, pool = jax.jit(
+            lambda qn, qr, pool, rows: moe_mla._absorbed_attention(
+                cfg, lp, qn, qr, pool, LAYER, walk, rows))(
+                    q_nope, q_rope, pool, new_rows)
+        return np.asarray(out, np.float32), np.asarray(pool, np.float32)
+    lax_walk, walked = moe_mla._step_walk(cfg, pos, tab, act, K_BS, False,
+                                          False)
+    kernel_walk, copied = moe_mla._step_walk(cfg, pos, tab, act, K_BS, False,
+                                             True)
+    assert isinstance(kernel_walk, PagedRows) and kernel_walk.interpret
+    want, _ = attend(lax_walk, written)
+    got, pool = attend(kernel_walk, before)
+    return (want, got, int(walked), int(copied), positions, active, pool,
+            np.asarray(written, np.float32))
+
+
+@pytest.mark.parametrize("row", list(K_ROWS))
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("heads", [128, 32])
+def test_the_latent_kernel_equals_the_lax_walk(heads, dtype, row):
+    """Ragged lengths in ONE step (`K_ROWS`), both cells' head counts, the
+    float32 of the tests and the bfloat16 as served: the kernel's row is
+    the lax walk's, and holds nothing of a block its table does not name
+    (`K_FAR` would show at once)."""
+    want, got, _, _, _, active, _, _ = both_tiers(heads, dtype)
+    b = list(K_ROWS).index(row)
+    assert np.isfinite(got).all()
+    if not active[b]:
+        assert (got[b] == 0).all()      # never read: stated, not garbage
+        return
+    assert np.abs(want[b]).max() > 1e-3
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(got[b], want[b], rtol=tol, atol=tol)
+    assert np.abs(got[b]).max() < 100
+
+
+@pytest.mark.parametrize("heads", [128, 32])
+def test_the_kernel_copies_a_rows_own_pages_and_no_further(heads):
+    """`kv_walked_tokens` of the two tiers: the kernel's is every active
+    row's own pages, whole; the lax walk's a block's longest row for every
+    row of the block."""
+    _, _, walked, copied, positions, active, _, _ = both_tiers(heads,
+                                                               "float32")
+    own = (positions[active] // K_BS + 1) * K_BS
+    assert copied == own.sum() == int(paged_walked(
+        jnp.asarray(positions), jnp.asarray(active), K_BS))
+    live = (positions[active] + 1).sum()
+    assert live <= copied < live + K_BS * active.sum()
+    assert walked > copied
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("heads", [128, 32])
+def test_the_kernel_writes_the_active_rows_new_rows_and_nothing_else(
+        heads, dtype):
+    """The pool the kernel hands back: every active row's new latent row
+    at its position of layer ``LAYER``, bit for bit, and every other
+    number as it was: an inactive row's position, the other layer, the
+    null block."""
+    *_, pool, written = both_tiers(heads, dtype)
+    assert (pool == written).all()
+
+
+@pytest.mark.parametrize("heads,mb,want", [
+    (128, 256, 32), (32, 512, 32), (128, 8, 8), (4, 16, 16)])
+def test_chunk_pages_come_from_the_shapes(heads, mb, want):
+    """The cells' shapes (128 and 32 heads over 640-wide bfloat16 rows,
+    pages of 16) take chunks of 512 positions; a short table is one
+    chunk."""
+    assert chunk_pages(heads, mb, 16, 640, 2) == want
+
+
+def test_the_kernel_serves_no_active_row_and_a_full_batch():
+    """No row active: every result 0, no page read (the pool holds NaN
+    everywhere) and none written; every row active and equal: equal
+    results."""
+    B, H, W = 4, 8, 128
+    pool = jnp.full((1, 6, K_BS, W), jnp.nan, jnp.float32)
+    q = jnp.ones((B, H, W), jnp.float32)
+    new = jnp.ones((B, W), jnp.float32)
+    tables = jnp.zeros((B, 4), jnp.int32)
+    pos = jnp.full((B,), 20, jnp.int32)
+    none, kept = paged_latent_attention(q, new, pool, 0, pos, tables,
+                                        jnp.zeros((B,), bool), sm_scale=1.0,
+                                        width=128, interpret=True)
+    assert (np.asarray(none) == 0).all() and np.isnan(np.asarray(kept)).all()
+    rng = np.random.RandomState(0)
+    pool = jnp.asarray(rng.standard_normal((1, 6, K_BS, W)), jnp.float32)
+    tables = jnp.tile(jnp.asarray([[3, 5, 0, 0]], jnp.int32), (B, 1))
+    every, _ = paged_latent_attention(
+        q, new, pool, 0, pos, tables, jnp.ones((B,), bool), sm_scale=0.1,
+        width=128, interpret=True)
+    every = np.asarray(every)
+    assert np.abs(every[0]).max() > 0 and (every == every[:1]).all()

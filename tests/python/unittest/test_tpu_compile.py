@@ -261,43 +261,123 @@ def test_kda_step_kernel_compiles_in_place_at_published_widths(one_chip,
     assert mem.temp_size_in_bytes < 64 << 20
 
 
-def test_kimi_step_rematerialises_nothing_on_a_donated_pool(one_chip):
-    """The Kimi-Linear decode step at the served cell's sizes (256 slots):
-    no instruction the compiler rematerialised reads or writes a donated
-    pool. A chain of in-place updates of the tail pool, a layer at a time,
-    was rematerialised at this size and read the pool AFTER it had been
-    overwritten: wrong tokens on the chip, invisible to every CPU test
-    (PERF.md, PR 34). Every layer now reads the pools as they came in and
-    the program writes the tails once."""
+@pytest.mark.parametrize("heads,mb,layers,blocks", [
+    (128, 256, 5, 29258), (32, 512, 2, 50717)], ids=["pangu", "kimi"])
+def test_paged_latent_attention_compiles_at_the_cells_shapes(
+        one_chip, heads, mb, layers, blocks):
+    """`mx_paged_latent_attn` at the two latent cells' shapes (256 rows of
+    128 or 32 heads over 640-wide bfloat16 rows, tables of 256 or 512 pages
+    of 16, the whole table in scalar memory): the page copies, the merged
+    ``(pages, 16)`` view of a chunk and the lane slice of the values pass
+    Mosaic, as do the new row's select into its page and the page's copy
+    back; the pool is aliased to the output, never copied, and nothing but
+    the rows' order is made beside it."""
+    from mxnet_tpu.kernels.paged_attention import paged_latent_attention
+    B = 256
+    sd = lambda s, d: jax.ShapeDtypeStruct(s, d,               # noqa: E731
+                                           sharding=one_chip)
+
+    def attend(q, new_rows, pool, positions, tables, active):
+        return paged_latent_attention(q, new_rows, pool, 1, positions,
+                                      tables, active, sm_scale=0.0722,
+                                      width=512)
+    compiled = jax.jit(attend, donate_argnums=(2,)).lower(
+        sd((B, heads, 640), jnp.bfloat16), sd((B, 640), jnp.bfloat16),
+        sd((layers, blocks, 16, 640), jnp.bfloat16), sd((B,), jnp.int32),
+        sd((B, mb), jnp.int32), sd((B,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "mx_paged_latent_attn" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == layers * blocks * 16 * 640 * 2
+    assert mem.temp_size_in_bytes < 1 << 20
+
+
+def _latent_cell(name):
+    """``(model, engine geometry, latent layers)`` of a latent cell as the
+    benchmark builds it, on the kernel tier, parameters as shapes."""
     import json
     import os
-    from mxnet_tpu.models.kimi_linear import (KimiLinearConfig,
-                                              KimiLinearDecodeModel,
-                                              init_kimi_linear)
     cells = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
                          "..", "..", "benchmark", "cells")
-    with open(os.path.join(cells, "configs", "kimi_linear_ep8.json")) as f:
-        cfg = KimiLinearConfig.from_dict(json.load(f))
-    with open(os.path.join(cells, "traffic",
-                           "decode_batch_longgen.json")) as f:
-        e = json.load(f)["engine"]
+    config, traffic = {
+        "kimi": ("kimi_linear_ep8.json", "decode_batch_longgen.json"),
+        "pangu": ("pangu_umoe_ep16.json", "decode_batch_long.json")}[name]
+    with open(os.path.join(cells, "configs", config)) as f:
+        config = json.load(f)
+    with open(os.path.join(cells, "traffic", traffic)) as f:
+        engine = json.load(f)["engine"]
+    if name == "kimi":
+        from mxnet_tpu.models.kimi_linear import (KimiLinearConfig,
+                                                  KimiLinearDecodeModel,
+                                                  init_kimi_linear)
+        cfg = KimiLinearConfig.from_dict(config)
+        init, Model = init_kimi_linear, KimiLinearDecodeModel
+        latent = len(cfg.full_attn_layers)
+    else:
+        from mxnet_tpu.models.moe_mla import (MoEMLAConfig,
+                                              MoEMLADecodeModel,
+                                              init_moe_mla)
+        cfg = MoEMLAConfig.from_dict(config)
+        init, Model = init_moe_mla, MoEMLADecodeModel
+        latent = cfg.num_hidden_layers
+    params = jax.eval_shape(lambda: init(cfg, jax.random.PRNGKey(0),
+                                         jnp.bfloat16))
+    return Model(cfg, params=params, flash="on"), engine, latent
+
+
+@pytest.mark.parametrize("cell", ["kimi", "pangu"])
+def test_latent_step_reads_its_pages_in_place_and_rematerialises_no_pool(
+        one_chip, cell):
+    """The two latent cells' decode steps at the served sizes (256 slots,
+    tables of 512 and 256 pages), on the kernel tier.
+
+    No instruction the compiler rematerialised reads or writes a donated
+    pool. A chain of in-place updates of Kimi-Linear's tail pool, a layer at
+    a time, was rematerialised at this size and read the pool AFTER it had
+    been overwritten: wrong tokens on the chip, invisible to every CPU test
+    (PERF.md, PR 34). Every layer now reads the pools as they came in and
+    the program writes the tails once.
+
+    Every latent layer's write and walk is ONE `mx_paged_latent_attn` that
+    takes the pool whole and hands it on: the latent pool is touched by
+    that chain of aliased calls and by nothing of XLA's (a scatter a layer
+    beside the kernel's reads WAS rematerialised here, PERF.md PR 35), no
+    gathered piece of 640-wide rows is left in the program (the lax walk's
+    was ``[32, 512, 640]``), and its temporaries are under the lax
+    step's."""
+    import re
+    model, e, latent = _latent_cell(cell)
     on_chip = lambda t: jax.tree_util.tree_map(                 # noqa: E731
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
         t)
-    params = on_chip(jax.eval_shape(lambda: init_kimi_linear(
-        cfg, jax.random.PRNGKey(0), jnp.bfloat16)))
-    model = KimiLinearDecodeModel(cfg, params=params, flash="on")
+    params = on_chip(model.params)
     B, bs = e["batch_size"], e["block_size"]
     mb = e["max_seq_len"] // bs
     cache = on_chip(model.cache_spec(e["num_blocks"], bs, B))
     sd = lambda s, d: jax.ShapeDtypeStruct(s, d,               # noqa: E731
                                            sharding=one_chip)
-    text = jax.jit(model.step_fn, donate_argnums=(1,)).lower(
+    compiled = jax.jit(model.step_fn, donate_argnums=(1,)).lower(
         params, cache, sd((B,), jnp.int32), sd((B,), jnp.int32),
-        sd((B, mb), jnp.int32), sd((B,), jnp.bool_)).compile().as_text()
-    assert text.count("mx_kda_step") >= len(cfg.kda_layers)
+        sd((B, mb), jnp.int32), sd((B,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    if cell == "kimi":
+        assert text.count("mx_kda_step") >= len(model.cfg.kda_layers)
     pools = ["[%s]" % ",".join(str(n) for n in p.shape)
              for p in jax.tree_util.tree_leaves(cache)]
     again = [ln for ln in text.splitlines() if ".remat" in ln.split("=")[0]
              and any(p in ln for p in pools)]
     assert not again, again[:2]
+    calls = [ln for ln in text.splitlines()
+             if "mx_paged_latent_attn" in ln and "custom-call(" in ln]
+    assert len(calls) == latent, len(calls)
+    # XLA itself writes nothing into the latent pool any more
+    latent = "bf16" + pools[0]
+    assert not [ln for ln in text.splitlines()
+                if latent in ln and "scatter" in ln]
+    # 640-wide and three-dimensional: the queries and the new rows only
+    pieces = set(re.findall(r"bf16\[\d+,\d+,640\]", text))
+    H = model.cfg.num_attention_heads
+    assert pieces <= {"bf16[%d,%d,640]" % (B, H), "bf16[%d,1,640]" % B}, \
+        pieces
+    assert compiled.memory_analysis().temp_size_in_bytes < {
+        "pangu": 280950272, "kimi": 331771392}[cell]
