@@ -9,9 +9,9 @@ they share is here: addressing, `NULL_BLOCK`, `MASKED`, the lax walk
 the GPT-2 family's everywhere) and, for the latent family whose 128 heads
 share ONE pool row, the walk as a Pallas kernel that reads the pool in
 place (`paged_latent_attention`, ``mx_paged_latent_attn``). A row a head
-(GPT-2: twin pools, heads folded into the lanes) is another contraction
-and would be another kernel; it can reuse the kernel's row compaction,
-its page copies by block table and its running softmax as they stand.
+(twin pools, heads folded into the lanes) is another contraction: the same
+compaction, page copies and running softmax as `paged_head_attention`
+(``mx_eva_paged_attn``; `models/evabyte.py`: summaries and window, one walk).
 
 **The page format.** A pool is ``(layers, num_blocks, block_size, width)``.
 Position ``p`` of a sequence whose block table is ``table`` lives at
@@ -483,3 +483,186 @@ def chunk_attention(q, k, v, start, sm_scale, block_k, use_pallas,
                                      sm_scale=sm_scale, block_k=bk,
                                      q_offset=start, k_offset=0)
     return out[0]
+
+
+# ---------------------------------------------------------------------------
+# a row a head: twin pools, heads folded into the lanes, as one kernel
+# ---------------------------------------------------------------------------
+#: Pages a chunk of `paged_head_attention`'s walk: two pools, two halves,
+#: 128 KB a page at 4,096 lanes of bfloat16 (4 MB of VMEM).
+_HEAD_CHUNK_PAGES = 8
+
+
+def _head_attn_kernel(layer_ref, rows_ref, n_ref, last_ref, tab_ref, q_ref,
+                      knew_ref, vnew_ref, k_pool, v_pool, o_ref, kbuf, vbuf,
+                      sems, slot_ref, m_ref, den_ref, acc_ref, *, sm_scale,
+                      mb):
+    """Grid step ``i``: the ``i``-th ACTIVE row. Its pages arrive a chunk at
+    a time in the halves of ``kbuf`` / ``vbuf`` ``[2, pages, block_size,
+    D]``, the next chunk's copies (the next row's first, at a row's last)
+    started before this chunk's products; ``slot_ref`` carries which half
+    is due from row to row, as `_latent_attn_kernel`'s. The running softmax
+    ``(m, den, acc)`` has a head a sublane row: it STARTS from the row's own
+    new key and value (``knew_ref``, ``vnew_ref``: not in the pool yet), so
+    no row is ever all masked. Head ``h`` owns lanes ``h * dh .. (h + 1) *
+    dh - 1``: the query is laid out block-diagonally ``[H, D]`` so one
+    product gives every head's scores, and of the ``[H, D]`` accumulator
+    head ``h`` keeps its own lanes."""
+    i = pl.program_id(0)
+    n = n_ref[0]
+    _, pages, bs, D = kbuf.shape
+    H = m_ref.shape[0]
+    span = pages * bs
+    dt = kbuf.dtype
+
+    def live_pages(r, c):
+        return jnp.minimum(last_ref[r] // bs + 1 - c * pages, pages)
+
+    def start(r, c, slot):
+        def page(j, carry):
+            blk = tab_ref[r * mb + c * pages + j]
+            pltpu.make_async_copy(k_pool.at[layer_ref[0], blk],
+                                  kbuf.at[slot, j], sems.at[slot, 0]).start()
+            pltpu.make_async_copy(v_pool.at[layer_ref[0], blk],
+                                  vbuf.at[slot, j], sems.at[slot, 1]).start()
+            return carry
+        lax.fori_loop(0, live_pages(r, c), page, 0)
+
+    def wait(r, c, slot):
+        def page(j, carry):
+            for buf, kv in ((kbuf, 0), (vbuf, 1)):
+                at = buf.at[slot, j]
+                pltpu.make_async_copy(at, at, sems.at[slot, kv]).wait()
+            return carry
+        lax.fori_loop(0, live_pages(r, c), page, 0)
+
+    @pl.when(i < n)
+    def _():
+        r = rows_ref[i]
+        last = last_ref[r]
+        chunks = last // span + 1
+
+        @pl.when(i == 0)
+        def _():
+            # a partly filled chunk leaves the rows of an earlier one
+            # behind it (finite, masked to weight 0): never VMEM as found
+            kbuf[...] = jnp.zeros_like(kbuf)
+            vbuf[...] = jnp.zeros_like(vbuf)
+            slot_ref[0] = 0
+            start(r, 0, 0)
+
+        first_slot = slot_ref[0]
+        own = (lax.broadcasted_iota(jnp.int32, (H, D), 1) // (D // H)
+               == lax.broadcasted_iota(jnp.int32, (H, D), 0))
+        # (masks are applied to float32: a bool of bfloat16's tiling is a
+        # relayout the compiler refuses)
+        q = q_ref[0].astype(jnp.float32)                    # [1, D]
+        q_bd = jnp.where(own, q, 0.0).astype(dt)            # [H, D]
+        m_ref[...] = jnp.sum(
+            jnp.where(own, q * knew_ref[0].astype(jnp.float32), 0.0),
+            axis=1, keepdims=True) * sm_scale
+        den_ref[...] = jnp.ones_like(den_ref)
+        acc_ref[...] = jnp.broadcast_to(vnew_ref[0].astype(jnp.float32),
+                                        (H, D))
+
+        def fold(c, slot):
+            s = lax.dot_general(q_bd, kbuf[slot].reshape(span, D),
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * sm_scale
+            tpos = c * span + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(tpos <= last, s, MASKED)
+            m = m_ref[...]
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m - m_new)
+            m_ref[...] = m_new
+            den_ref[...] = den_ref[...] * alpha + jnp.sum(p, axis=1,
+                                                          keepdims=True)
+            acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+                p.astype(dt), vbuf[slot].reshape(span, D),
+                preferred_element_type=jnp.float32)
+
+        def whole_chunk(c, carry):
+            slot = (first_slot + c) % 2
+            start(r, c + 1, 1 - slot)
+            wait(r, c, slot)
+            fold(c, slot)
+            return carry
+
+        lax.fori_loop(0, chunks - 1, whole_chunk, 0)
+        c = chunks - 1
+        slot = (first_slot + c) % 2
+
+        @pl.when(i + 1 < n)
+        def _():
+            start(rows_ref[i + 1], 0, 1 - slot)
+
+        wait(r, c, slot)
+        fold(c, slot)
+        slot_ref[0] = (first_slot + chunks) % 2
+        o_ref[0] = jnp.sum(jnp.where(own, acc_ref[...] / den_ref[...], 0.0),
+                           axis=0, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("num_heads", "sm_scale",
+                                             "interpret"))
+def paged_head_attention(q, k_new, v_new, k_pool, v_pool, layer, last,
+                         tables, active, *, num_heads, sm_scale,
+                         interpret=False):
+    """One layer's decode attention over twin K and V pools with the heads
+    folded into the lanes (the GPT-2 family's page format), the pools read
+    in place: ONE ``pallas_call`` named ``mx_eva_paged_attn``. ``q``,
+    ``k_new``, ``v_new`` ``(B, D)`` are the rows' queries and their own new
+    keys and values, which the pools do not hold yet; ``k_pool`` /
+    ``v_pool`` ``(L, blocks, block_size, D)`` stay in HBM and are read at
+    ``layer``; row ``b`` attends the positions ``0 .. last[b]`` of
+    ``tables[b]`` and itself, under one softmax. Returns ``(B, D)``
+    float32 (an inactive row's is 0).
+
+    What a position IS is the caller's: `models/evabyte.py` hands a table
+    whose front holds a row's visible chunk summaries and whose back its
+    open window, so one walk covers both spans. The grid walks the ACTIVE
+    rows only (compacted through scalar prefetch); a row copies its own
+    pages ``0 .. last[b] // block_size`` of both pools and no further, each
+    one contiguous copy into VMEM, `_HEAD_CHUNK_PAGES` a chunk, double
+    buffered. Arithmetic: `softmax_fold`'s a chunk (operands in the pools'
+    dtype, float32 accumulation and softmax). Jitted, though it only ever
+    runs inside a program: the layers share one trace."""
+    B, D = q.shape
+    bs = k_pool.shape[2]
+    mb = tables.shape[1]
+    pages = min(_HEAD_CHUNK_PAGES, mb)
+    n = jnp.sum(active.astype(jnp.int32))
+    order = jnp.argsort(jnp.logical_not(active), stable=True).astype(
+        jnp.int32)
+    at = jnp.minimum(jnp.arange(B, dtype=jnp.int32), jnp.maximum(n - 1, 0))
+    rows = jnp.take(order, at)
+    row_block = lambda i, layer, rows, *_: (rows[i], 0, 0)      # noqa: E731
+    row_spec = pl.BlockSpec((1, 1, D), row_block)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5, grid=(B,),
+        in_specs=[row_spec, row_spec, row_spec, in_hbm, in_hbm],
+        out_specs=row_spec,
+        scratch_shapes=[pltpu.VMEM((2, pages, bs, D), k_pool.dtype),
+                        pltpu.VMEM((2, pages, bs, D), v_pool.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.SMEM((1,), jnp.int32),
+                        pltpu.VMEM((num_heads, 1), jnp.float32),
+                        pltpu.VMEM((num_heads, 1), jnp.float32),
+                        pltpu.VMEM((num_heads, D), jnp.float32)])
+    params = None if interpret else pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",))
+    dt = k_pool.dtype
+    out = pl.pallas_call(
+        functools.partial(_head_attn_kernel, sm_scale=sm_scale, mb=mb),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, 1, D), jnp.float32),
+        compiler_params=params, interpret=interpret,
+        name="mx_eva_paged_attn")(
+            jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)), rows,
+            jnp.reshape(n, (1,)), last.astype(jnp.int32),
+            tables.astype(jnp.int32).reshape(-1), q.astype(dt)[:, None],
+            k_new.astype(dt)[:, None], v_new.astype(dt)[:, None], k_pool,
+            v_pool)
+    return jnp.where(active[:, None], out[:, 0], 0.0)

@@ -12,12 +12,22 @@ length, table, slot)`` is told the slot its prompt was admitted to; row
 is slot ``i``. A family with paged pools only takes ``slots`` and ``slot``
 and ignores them: one signature for every family.
 
+A family may also say WHICH entries of its block table a sequence of ``n``
+positions backs: ``cache_pages(n)``, a pure host function (the contract is
+`serving/kvcache.py`'s: a ``(start, stop)`` span a region of the table).
+`PagedKVCache` backs the entries that are new and releases those that
+dropped out while the sequence lives; the engine's admission, growth and
+step-ahead test reckon with the same function. A family without one gets
+``ceil(n / block_size)`` entries from the front, through the same code.
+
 The bodies take the page format, the step's walk over the live positions
 and the prefill chunk's attention from `kernels/paged_attention.py`. The
 methods keep the names ``prefill_fn`` and ``step_fn``: the benchmark finds
 the programs by the XLA module names ``jit_prefill_fn`` / ``jit_step_fn``.
 Clients: `transformer.TransformerDecodeModel`, `moe_mla.MoEMLADecodeModel`,
-`kimi_linear.KimiLinearDecodeModel` (the one with per-slot state) and
+`kimi_linear.KimiLinearDecodeModel` (the one with per-slot state),
+`evabyte.EvaByteDecodeModel` (the one with ``cache_pages``: a window of
+exact rows that are handed back, chunk summaries that stay) and
 `tiny_lm.TinyLMDecodeModel` (the tests' single-layer fixture).
 """
 from __future__ import annotations
@@ -38,7 +48,10 @@ class SlotPool(jax.ShapeDtypeStruct):
 class DecodeModel:
     """Base of the adapters: ``DecodeEngine(**model.engine_kwargs(), ...)``.
     A subclass sets ``params`` and defines ``cache_spec``, ``prefill_fn``
-    and ``step_fn``."""
+    and ``step_fn``, and ``cache_pages`` where its table is not one block
+    a ``block_size`` positions."""
+
+    cache_pages = None
 
     def resolve_flash(self, flash):
         """Pick the kernel tier of the prefill attention (and of a family's
@@ -53,4 +66,5 @@ class DecodeModel:
 
     def engine_kwargs(self):
         return {"params": self.params, "cache_spec": self.cache_spec,
+                "cache_pages": self.cache_pages,
                 "prefill_fn": self.prefill_fn, "step_fn": self.step_fn}

@@ -34,7 +34,9 @@ time. This module is the decode side of the stack (ISSUE 18):
 bodies through the decode-model seam (:mod:`~..models.decode_model`):
 ``DecodeEngine(**model.engine_kwargs(), ...)``. Its clients are
 :class:`~..models.transformer.TransformerDecodeModel` (GPT-2 style),
-:class:`~..models.moe_mla.MoEMLADecodeModel` (latent attention, experts)
+:class:`~..models.moe_mla.MoEMLADecodeModel` (latent attention, experts),
+:class:`~..models.kimi_linear.KimiLinearDecodeModel` (per-slot state),
+:class:`~..models.evabyte.EvaByteDecodeModel` (a cache that forgets)
 and the tests' single-layer fixture beside them. The
 device side of the page format (addressing, the null block, the step's
 walk over the live positions, the prefill chunk's attention) is
@@ -63,6 +65,17 @@ step neither reads nor writes the state of a row whose ``active`` is false
 prompt). ``aux`` is a dict of small integer arrays (may be empty) that comes
 back in the same read-back as the ids and is summed into
 ``stats()["model"]``.
+
+**Which table entries a sequence backs** is `PagedKVCache`'s to keep and
+the family's to say: ``cache_pages(n)`` (optional; :mod:`.kvcache` has the
+contract) names the entries a sequence of ``n`` positions needs. Without it
+entry ``p // block_size`` holds position ``p`` for the sequence's whole
+life. With it the table may have regions and holes, and a sequence hands
+pages BACK as it grows (a window that closed): admission, growth, the
+step-ahead test and the never-fit test all reckon with the same function;
+a prompt is admitted when the most its prefill pieces need fits beside what
+the next step of the rows already in the batch may take, and holds its own
+from admission to its first step (`PagedKVCache.allocate`'s ``via``).
 
 **Chunked prefill** (``prefill_chunk`` /
 ``MXNET_SERVING_DECODE_PREFILL_CHUNK``): a long prompt runs as
@@ -209,6 +222,9 @@ class DecodeEngine:
     prefill_fn, step_fn, cache_spec : callables, required
         The model (module docstring, "The cache seam"): a family's
         ``engine_kwargs()`` brings them with ``params``.
+    cache_pages : callable or None
+        The family's ``pages(n)`` (:mod:`.kvcache`); None: a block a
+        ``block_size`` positions, held to the sequence's end.
     eos_id : int or None
         Token id that terminates a sequence (emitted, then retired).
     block_size / num_blocks : int
@@ -243,7 +259,7 @@ class DecodeEngine:
     """
 
     def __init__(self, params, *, prefill_fn, step_fn, cache_spec,
-                 name="decode", eos_id=None,
+                 cache_pages=None, name="decode", eos_id=None,
                  block_size=None, num_blocks=None, batch_size=None,
                  max_seq_len=None, prefill_buckets=None,
                  default_deadline_ms=_MISSING, default_max_new=None,
@@ -293,8 +309,8 @@ class DecodeEngine:
         self.prefill_chunk = cands[-1] if (int(prefill_chunk) > 0
                                            and cands) else 0
 
-        self._kv = PagedKVCache(num_blocks, block_size)
-        self._mb = self._kv.blocks_for(self.max_seq_len)  # table width
+        self._kv = PagedKVCache(num_blocks, block_size, cache_pages)
+        self._mb = self._kv.table_width(self.max_seq_len)
         spec = cache_spec(self._kv.num_blocks, self._kv.block_size,
                           self.batch_size)
         # what the two kinds of leaf hold, before placement rebuilds them
@@ -465,6 +481,12 @@ class DecodeEngine:
                                   trace=trace, on_token=on_token,
                                   on_done=on_done)
             stream._order = self._rid_ctr
+            # reckoned once: the formation pass looks at every waiter
+            # every iteration. What its admission needs free (the most its
+            # prefill pieces hold at once) and what it can never do without
+            via = self._piece_ends(len(prompt))
+            stream._admit_blocks = self._kv.blocks_for(len(prompt), via)
+            stream._least_blocks = self._kv.blocks_for(len(prompt) + 1, via)
             self._counters["submitted"] += 1
             self._waiting.append(stream)
             self._cv.notify_all()
@@ -564,8 +586,9 @@ class DecodeEngine:
     def _form_batch_locked(self):
         """The formation pass (EDF, generalizing the batcher): shed
         expired waiters, reject never-fit prompts, admit into free slots
-        while their prompts fit the pool. Runs under ``_cv`` — host
-        bookkeeping only, no device calls (TPL104)."""
+        while their prompts fit the pool beside the batch's next step.
+        Runs under ``_cv`` — host bookkeeping only, no device calls
+        (TPL104)."""
         now = time.monotonic()
         sheds, rejects = [], []
         keep = []
@@ -574,8 +597,7 @@ class DecodeEngine:
                 s._shed_err = DeadlineExceeded(
                     "decode %s: deadline expired before admission" % s.rid)
                 sheds.append(s)
-            elif self._kv.blocks_for(len(s.prompt) + 1) \
-                    > self._kv.capacity_blocks:
+            elif s._least_blocks > self._kv.capacity_blocks:
                 s._shed_err = CacheOverflow(
                     "decode %s: prompt of %d tokens can never fit a pool "
                     "of %d blocks" % (s.rid, len(s.prompt),
@@ -589,17 +611,36 @@ class DecodeEngine:
                                  else float("inf"), s._order))
         admitted = []
         free = [i for i, s in enumerate(self._slots) if s is None]
+        # beside a prompt's own need, what the next step of the rows
+        # already in the batch may take stays free: a prompt's pages are
+        # taken for as long as its prefill lasts, and a pool that admission
+        # fills to its last block fails the rows that grow meanwhile
+        kv, held = self._kv, self.batch_size - len(free)
         still_waiting = []
         for s in keep:
-            if free and self._kv.can_fit(len(s.prompt)):
-                self._kv.allocate(s.rid, len(s.prompt))
+            if free and s._admit_blocks + kv.regions * held \
+                    <= kv.free_blocks:
+                # with what its earlier pieces are written through and the
+                # whole prompt no longer needs (a window that closes inside
+                # the prompt), taken HERE so the next waiter is tested
+                # against what is left; handed back at its first step.
+                # Nothing more for a table that only grows
+                kv.allocate(s.rid, len(s.prompt),
+                            self._piece_ends(len(s.prompt)))
                 s._slot = free.pop(0)
                 self._slots[s._slot] = s
                 admitted.append(s)
+                held += 1
             else:
                 still_waiting.append(s)
         self._waiting = still_waiting
         return sheds, rejects, admitted
+
+    def _piece_ends(self, n):
+        """The lengths a prompt of ``n`` tokens passes through before its
+        last prefill piece lands (`_prefill_one` cuts it the same way)."""
+        chunk = self.prefill_chunk
+        return range(chunk, n, chunk) if chunk and n > chunk else ()
 
     def _evict(self, stream, error):
         """Drop an ACTIVE sequence: free its blocks, vacate its slot,
@@ -805,11 +846,16 @@ class DecodeEngine:
         flight BEFORE its tokens are read: every live row takes its token
         from it (none is fresh from a prefill, its token on the host), no
         deadline has passed (an eviction comes after the tokens it
-        follows), and the pool holds a block for every row (no growth
-        can overflow)."""
+        follows), and the pool holds the blocks every row's next position
+        takes (no growth can overflow)."""
         live = self._live()
         now = time.monotonic()
-        return (self._kv.free_blocks >= len(live)
+        kv = self._kv
+        # (a position more takes at most a block a region of the table:
+        # only a pool that near to full is reckoned row by row)
+        room = kv.free_blocks
+        return ((room >= kv.regions * len(live)
+                 or room >= sum(kv.growth(s.rid) for s in live))
                 and all(s._inflight is ahead
                         and (s.deadline is None or now <= s.deadline)
                         for s in live))

@@ -11,6 +11,20 @@ per ``block_size`` tokens of its history), and blocks come from a
 free-list allocator. HBM then scales with *live tokens*, not with the
 worst case, and the accounting counters below prove it.
 
+**Which entries of its table a sequence backs** is a pure function of the
+positions it holds, ``pages(n)``: one ``(start, stop)`` span of table
+entries per REGION of the table (a region's ``start`` is fixed, its
+``stop`` moves, by at most one entry a position; the largest ``stop`` never
+falls as ``n`` grows, so a table is as wide as ``pages(max_seq_len)``
+says). The default is the one region
+``(0, ceil(n / block_size))``: entry ``p // block_size`` holds position
+``p``, and a block is its sequence's until the sequence ends. A family
+whose cache forgets (`models/evabyte.py`: exact rows die with their window,
+their summaries stay) hands its own function over; as a sequence grows the
+allocator backs the entries that are new and RELEASES those that dropped
+out, while the sequence lives (``stats()["blocks_released_live"]``). An
+entry that is not backed reads `NULL_BLOCK`.
+
 The layout contract (where position ``p`` lives, and **block 0, the null
 block**: never allocated, the target of every inactive or padding write)
 is `kernels/paged_attention.py`'s, which the models' programs use. The
@@ -69,11 +83,12 @@ class CacheOverflow(DeadlineExceeded):
 class PagedKVCache:
     """Free-list block allocator + per-sequence block tables.
 
-    ``blocks_for(n)`` tokens need ``ceil(n / block_size)`` blocks. The
+    ``blocks_for(n)`` tokens need as many blocks as ``pages(n)`` has
+    entries (``ceil(n / block_size)`` unless a family says otherwise). The
     usable pool is ``num_blocks - 1`` (block 0 is the null block).
     """
 
-    def __init__(self, num_blocks, block_size):
+    def __init__(self, num_blocks, block_size, pages=None):
         if num_blocks < 2:
             raise ValueError("PagedKVCache needs >= 2 blocks "
                              "(block 0 is reserved as the null block)")
@@ -84,11 +99,16 @@ class PagedKVCache:
         # LIFO free list: recently-freed blocks are reused first, which
         # keeps the touched working set small. Ids 1..num_blocks-1.
         self._free = list(range(self.num_blocks - 1, 0, -1))
-        self._tables = {}           # seq_id -> [block ids]
+        bs = self.block_size
+        self._pages = pages or (lambda n: ((0, -(-n // bs)),))
+        self.regions = len(self._pages(1))
+        self._tables = {}           # seq_id -> [block ids], NULL_BLOCK holes
+        self._spans = {}            # seq_id -> the spans its table backs
         self._lengths = {}          # seq_id -> token count
         # watermark / accounting counters
         self._allocs = 0
         self._frees = 0
+        self._released_live = 0     # of _frees: from sequences still live
         self._alloc_failures = 0
         self._high_water = 0        # max blocks simultaneously live
         # the device state this manager accounts, of two kinds: bytes of
@@ -114,57 +134,105 @@ class PagedKVCache:
     def live_blocks(self):
         return self.capacity_blocks - len(self._free)
 
-    def blocks_for(self, n_tokens):
-        """Blocks needed to hold ``n_tokens`` tokens."""
-        return -(-int(n_tokens) // self.block_size)
+    def _spans_for(self, n_tokens, via=()):
+        """``pages(n_tokens)``, each region widened to what any length of
+        ``via`` needs as well."""
+        spans = self._pages(int(n_tokens))
+        for m in via:
+            spans = tuple((a, max(b, d)) for (a, b), (_, d)
+                          in zip(spans, self._pages(int(m))))
+        return spans
 
-    def can_fit(self, n_tokens):
-        return self.blocks_for(n_tokens) <= len(self._free)
+    def blocks_for(self, n_tokens, via=()):
+        """Blocks needed to hold ``n_tokens`` tokens (and, with ``via``,
+        every length of it on the way there)."""
+        return sum(b - a for a, b in self._spans_for(n_tokens, via))
+
+    def can_fit(self, n_tokens, via=()):
+        return self.blocks_for(n_tokens, via) <= len(self._free)
+
+    def table_width(self, max_tokens):
+        """Entries a table must have for sequences of up to ``max_tokens``."""
+        return max(b for _, b in self._pages(int(max_tokens)))
+
+    def growth(self, seq_id, n_tokens=1):
+        """Blocks ``extend(seq_id, n_tokens)`` would take from the pool."""
+        spans = self._pages(self._lengths[seq_id] + int(n_tokens))
+        old = self._spans[seq_id]
+        return 0 if spans == old else self._added(spans, old)
+
+    @staticmethod
+    def _added(spans, old):
+        """Entries ``spans`` backs that ``old`` does not."""
+        return sum(max(0, b - d) for (_, b), (_, d) in zip(spans, old))
 
     # -- sequence lifecycle ---------------------------------------------
-    def allocate(self, seq_id, n_tokens):
-        """Register ``seq_id`` with blocks for ``n_tokens`` of history.
+    def _resize(self, seq_id, spans, what):
+        """Make ``seq_id``'s table back exactly ``spans``: take the new
+        entries' blocks from the pool, hand the dropped entries' back.
+        Raises :class:`CacheOverflow` without mutating anything when the
+        pool cannot cover what is new."""
+        old, table = self._spans[seq_id], self._tables[seq_id]
+        need = self._added(spans, old)
+        if need > len(self._free):
+            self._alloc_failures += 1
+            raise CacheOverflow(
+                "KV cache overflow: sequence %r %s %d blocks, %d free "
+                "(%d live of %d)" % (seq_id, what, need, len(self._free),
+                                     self.live_blocks, self.capacity_blocks))
+        width = max(b for _, b in spans)
+        if width > len(table):
+            table.extend([NULL_BLOCK] * (width - len(table)))
+        dropped = self._added(old, spans)
+        for (_, b), (_, d) in zip(spans, old):
+            for i in range(b, d):           # dropped out: back to the pool
+                self._free.append(table[i])
+                table[i] = NULL_BLOCK
+        for (_, b), (_, d) in zip(spans, old):
+            for i in range(d, b):
+                table[i] = self._free.pop()
+        self._spans[seq_id] = spans
+        self._frees += dropped
+        self._released_live += dropped
+        if need:
+            self._allocs += need
+            self._high_water = max(self._high_water, self.live_blocks)
+
+    def allocate(self, seq_id, n_tokens, via=()):
+        """Register ``seq_id`` with blocks for ``n_tokens`` of history and,
+        beside them, what every length of ``via`` needs (the ends of its
+        prefill pieces: a family whose table shrinks writes a prompt's
+        earlier windows through pages its last piece no longer needs) until
+        its first ``extend`` hands the surplus back. ``via`` adds nothing
+        to a table that only grows.
 
         Raises :class:`CacheOverflow` (and allocates nothing) when the
         free list cannot cover it.
         """
         if seq_id in self._tables:
             raise ValueError("sequence %r already allocated" % (seq_id,))
-        need = self.blocks_for(n_tokens)
-        if need > len(self._free):
-            self._alloc_failures += 1
-            raise CacheOverflow(
-                "KV cache overflow: sequence %r needs %d blocks, %d free "
-                "(%d live of %d)" % (seq_id, need, len(self._free),
-                                     self.live_blocks, self.capacity_blocks))
-        table = [self._free.pop() for _ in range(need)]
-        self._tables[seq_id] = table
+        spans = self._spans_for(n_tokens, via)
+        self._tables[seq_id] = []
+        self._spans[seq_id] = tuple((a, a) for a, _ in spans)
         self._lengths[seq_id] = int(n_tokens)
-        self._allocs += need
-        self._high_water = max(self._high_water, self.live_blocks)
-        return list(table)
+        try:
+            self._resize(seq_id, spans, "needs")
+        except CacheOverflow:
+            self.free(seq_id)
+            raise
+        return list(self._tables[seq_id])
 
     def extend(self, seq_id, n_tokens=1):
-        """Grow ``seq_id`` by ``n_tokens``, appending blocks as block
-        boundaries are crossed. Raises :class:`CacheOverflow` without
-        mutating anything when the pool cannot cover the growth."""
-        table = self._tables[seq_id]
+        """Grow ``seq_id`` by ``n_tokens``: back the entries its new length
+        adds, release those it no longer needs. Raises
+        :class:`CacheOverflow` without mutating anything when the pool
+        cannot cover the growth."""
         new_len = self._lengths[seq_id] + int(n_tokens)
-        need = self.blocks_for(new_len) - len(table)
-        if need > len(self._free):
-            self._alloc_failures += 1
-            raise CacheOverflow(
-                "KV cache overflow: sequence %r grew past %d blocks, %d "
-                "free (%d live of %d)" % (seq_id, len(table),
-                                          len(self._free), self.live_blocks,
-                                          self.capacity_blocks))
-        for _ in range(need):
-            table.append(self._free.pop())
+        spans = self._pages(new_len)
+        if spans != self._spans[seq_id]:
+            self._resize(seq_id, spans, "grew by")
         self._lengths[seq_id] = new_len
-        if need:
-            self._allocs += need
-            self._high_water = max(self._high_water, self.live_blocks)
-        return list(table)
+        return list(self._tables[seq_id])
 
     def free(self, seq_id):
         """Retire ``seq_id`` and return its blocks to the free list."""
@@ -172,9 +240,11 @@ class PagedKVCache:
         if table is None:
             return 0
         self._lengths.pop(seq_id, None)
-        self._free.extend(table)
-        self._frees += len(table)
-        return len(table)
+        held = [table[i] for a, b in self._spans.pop(seq_id)
+                for i in range(a, b)]
+        self._free.extend(held)
+        self._frees += len(held)
+        return len(held)
 
     def table(self, seq_id):
         return list(self._tables[seq_id])
@@ -189,15 +259,27 @@ class PagedKVCache:
     def check(self):
         """Assert allocator invariants; returns True or raises AssertionError.
 
-        - conservation: free + live tables == capacity, no block lost;
+        - conservation: free + live tables == capacity, no block lost,
+          and every block ever taken is live or was handed back (at the
+          sequence's end or, released, while it lived);
+        - a table backs the entries its spans name and no other, and the
+          spans hold what ``pages`` asks for at the sequence's length (more
+          only between an ``allocate`` through ``via`` and the first ``extend``);
         - no aliasing: a block id appears in at most one table, never in
           both a table and the free list, and never the null block.
         """
         seen = {}
         for sid, table in self._tables.items():
-            assert self.blocks_for(self._lengths[sid]) == len(table), \
+            spans = self._spans[sid]
+            assert all(b >= d for (_, b), (_, d) in zip(
+                spans, self._pages(self._lengths[sid]))), \
                 "table size mismatch for %r" % (sid,)
-            for b in table:
+            backed = {i for a, b in spans for i in range(a, b)}
+            for i, b in enumerate(table):
+                if i not in backed:
+                    assert b == NULL_BLOCK, \
+                        "entry %d of %r backed outside its spans" % (i, sid)
+                    continue
                 assert b != NULL_BLOCK, "null block leaked into %r" % (sid,)
                 assert 0 < b < self.num_blocks, "block %d out of range" % b
                 assert b not in seen, \
@@ -210,6 +292,9 @@ class PagedKVCache:
         assert len(free_set) + len(seen) == self.capacity_blocks, \
             "block conservation violated: %d free + %d live != %d" % (
                 len(free_set), len(seen), self.capacity_blocks)
+        assert self._allocs - self._frees == len(seen), \
+            "blocks taken and handed back do not add up to those live"
+        assert self._released_live <= self._frees
         return True
 
     def stats(self):
@@ -223,4 +308,5 @@ class PagedKVCache:
                 "pool_bytes": int(self.pool_bytes),
                 "state_bytes": int(self.state_bytes),
                 "allocs": self._allocs, "frees": self._frees,
+                "blocks_released_live": self._released_live,
                 "alloc_failures": self._alloc_failures}

@@ -381,3 +381,71 @@ def test_latent_step_reads_its_pages_in_place_and_rematerialises_no_pool(
         pieces
     assert compiled.memory_analysis().temp_size_in_bytes < {
         "pangu": 280950272, "kimi": 331771392}[cell]
+
+
+def _eva_cell():
+    """``(model, engine geometry)`` of the EvaByte cell as the benchmark
+    builds it, on the kernel tier, parameters as shapes."""
+    import json
+    import os
+    from mxnet_tpu.models.evabyte import (EvaByteConfig, EvaByteDecodeModel,
+                                          init_evabyte)
+    cells = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
+                         "..", "..", "benchmark", "cells")
+    with open(os.path.join(cells, "configs", "evabyte_l8.json")) as f:
+        cfg = EvaByteConfig.from_dict(json.load(f))
+    with open(os.path.join(cells, "traffic", "decode_batch_bytes.json")) as f:
+        engine = json.load(f)["engine"]
+    params = jax.eval_shape(lambda: init_evabyte(cfg, jax.random.PRNGKey(0),
+                                                 jnp.bfloat16))
+    return EvaByteDecodeModel(cfg, params=params, flash="on"), engine
+
+
+@pytest.mark.parametrize("program", ["step", 256, 1024])
+def test_evabyte_programs_write_each_pool_once_and_copy_none(one_chip,
+                                                             program):
+    """The EvaByte cell's decode step and prefill buckets at the served
+    sizes (48 slots, tables of 192 entries, twin pools of 5.7 GB), on the
+    kernel tier. Both pools are donated and come back in place; no
+    instruction the compiler rematerialised touches one and none copies
+    one (every layer reads the pools as they came in, and the rows of all
+    layers go in at the program's end: PERF.md PR 34's rule). The step's
+    attention is ONE `mx_eva_paged_attn` a layer, and its temporaries are
+    two hundredths of a pool."""
+    model, e = _eva_cell()
+    on_chip = lambda t: jax.tree_util.tree_map(                 # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    B, bs = e["batch_size"], e["block_size"]
+    mb = max(stop for _, stop in model.cache_pages(e["max_seq_len"]))
+    assert mb == 128 + 64
+    cache = on_chip(model.cache_spec(e["num_blocks"], bs, B))
+    sd = lambda s, d: jax.ShapeDtypeStruct(s, d,               # noqa: E731
+                                           sharding=one_chip)
+    if program == "step":
+        compiled = jax.jit(model.step_fn, donate_argnums=(1,)).lower(
+            on_chip(model.params), cache, sd((B,), jnp.int32),
+            sd((B,), jnp.int32), sd((B, mb), jnp.int32),
+            sd((B,), jnp.bool_)).compile()
+    else:
+        compiled = jax.jit(model.prefill_fn, donate_argnums=(1,)).lower(
+            on_chip(model.params), cache, sd((program,), jnp.int32),
+            sd((), jnp.int32), sd((), jnp.int32), sd((mb,), jnp.int32),
+            sd((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    pool = "bf16[%s]" % ",".join(str(n) for n in cache["k"].shape)
+    touched = [ln for ln in text.splitlines() if pool in ln]
+    assert not [ln for ln in touched if ".remat" in ln.split("=")[0]]
+    assert not [ln for ln in touched
+                if pool in ln.split("=", 1)[-1].split("(")[0]
+                and " copy(" in ln]
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * int(np.prod(cache["k"].shape))
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // (50 if program == "step"
+                                                   else 15)
+    if program == "step":
+        calls = [ln for ln in text.splitlines()
+                 if "mx_eva_paged_attn" in ln and "custom-call(" in ln]
+        assert len(calls) == model.cfg.num_hidden_layers
