@@ -326,7 +326,8 @@ def kimi_linear_decode_prefill(params, cfg, cache, tokens, start, length,
                     states.append(s)
                     tails.append(t)
                     return out
-                x, counts = M._block(cfg, lp, x, mix, valid, scope="kda")
+                x, counts = M._block(cfg, lp, x, mix, valid, scope="kda",
+                                     kernels=(use_pallas, interpret))
             else:
                 def attend(h, lp=lp, li=li):
                     nonlocal pool
@@ -334,7 +335,8 @@ def kimi_linear_decode_prefill(params, cfg, cache, tokens, start, length,
                         cfg, lp, h, pool, li, pos, blk, at, table, start,
                         spans, which, use_pallas, interpret)
                     return out
-                x, counts = M._block(cfg, lp, x, attend, valid)
+                x, counts = M._block(cfg, lp, x, attend, valid,
+                                     kernels=(use_pallas, interpret))
             if counts is not None:
                 all_counts.append(counts)
     x_last = jnp.take(x, jnp.clip(length - 1, 0, C - 1), axis=0)
@@ -386,14 +388,16 @@ def kimi_linear_decode_step(params, cfg, cache, token_ids, positions, tables,
                                               active, use_pallas, interpret)
                     tails.append(t)
                     return out
-                x, counts = M._block(cfg, lp, x, mix, active, scope="kda")
+                x, counts = M._block(cfg, lp, x, mix, active, scope="kda",
+                                     kernels=(use_pallas, interpret))
             else:
                 def attend(h, lp=lp, li=li):
                     nonlocal pool
                     out, pool = M._step_attend(cfg, lp, h, pool, li,
                                                positions, blk, at, walk)
                     return out
-                x, counts = M._block(cfg, lp, x, attend, active)
+                x, counts = M._block(cfg, lp, x, attend, active,
+                                     kernels=(use_pallas, interpret))
             if counts is not None:
                 all_counts.append(counts)
     logits = M._logits(cfg, params, x)

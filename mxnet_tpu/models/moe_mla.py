@@ -358,10 +358,12 @@ def _attend_expanded(cfg, wkv_b, q, rows, start, use_pallas, interpret):
     return out[..., :dv].transpose(1, 0, 2)             # [C, H, dv]
 
 
-def _ffn(cfg, lp, h, valid=None):
+def _ffn(cfg, lp, h, valid=None, kernels=(False, False)):
     """The layer's feed-forward over normed ``h`` ``[N, d]``: the dense gated
-    MLP, or shared expert + the held routed experts' part. Returns ``(out,
-    counts)``; ``counts`` (``[held]`` int32) is ``None`` for a dense layer."""
+    MLP, or shared expert + the held routed experts' part (``kernels``:
+    ``(use_pallas, interpret)``, the tier of its grouped product). Returns
+    ``(out, tally)``; ``tally`` (`routed_experts`'s ``(counts, cost)``) is
+    ``None`` for a dense layer."""
     if "w_gate" in lp:
         with jax.named_scope("mlp"):
             return _gated_mlp(h, lp["w_gate"], lp["w_up"], lp["w_down"]), None
@@ -369,37 +371,43 @@ def _ffn(cfg, lp, h, valid=None):
     with jax.named_scope("moe.shared"):
         shared = _gated_mlp(hp, lp["shared_gate"], lp["shared_up"],
                             lp["shared_down"])
-    routed, counts = routed_experts(
+    routed, counts, cost = routed_experts(
         lp, hp, held=cfg.experts_held, top_k=cfg.num_experts_per_tok,
-        scale=cfg.routed_scaling_factor, valid=valid)
-    return shared + routed, counts
+        scale=cfg.routed_scaling_factor, valid=valid,
+        use_pallas=kernels[0], interpret=kernels[1])
+    return shared + routed, (counts, cost)
 
 
-def _block(cfg, lp, x, attend, valid=None, scope="mla"):
+def _block(cfg, lp, x, attend, valid=None, scope="mla",
+           kernels=(False, False)):
     """One residual block. ``attend(h)`` maps the normed input to the
     mixer's output BEFORE ``W_o`` (``[N, H * dv]``): the paths (full
     sequence, prefill chunk, absorbed step; another family's mixer under
     its own ``scope``) differ only there. Sandwich-normed where the layer
     has the two outer gains (``norm_attn_out``, ``norm_ffn_out``), else
-    plain pre-norm: ``x + mix(norm(x))``, ``h + ffn(norm(h))``."""
+    plain pre-norm: ``x + mix(norm(x))``, ``h + ffn(norm(h))``. Returns
+    ``(x, tally)``, `_ffn`'s."""
     eps = cfg.rms_norm_eps
     with jax.named_scope(scope):
         a = _mm(attend(_rms(x, lp["norm_attn_in"], eps)), lp["wo"])
     sandwich = "norm_attn_out" in lp
     h = x + (_rms(a, lp["norm_attn_out"], eps) if sandwich else a)
-    f, counts = _ffn(cfg, lp, _rms(h, lp["norm_ffn_in"], eps), valid)
-    return h + (_rms(f, lp["norm_ffn_out"], eps) if sandwich else f), counts
+    f, tally = _ffn(cfg, lp, _rms(h, lp["norm_ffn_in"], eps), valid, kernels)
+    return h + (_rms(f, lp["norm_ffn_out"], eps) if sandwich else f), tally
 
 
-def _aux(cfg, all_counts, prefix=""):
-    """The expert layers' counts of one call as the engine's ``aux``."""
-    if not all_counts:
+def _aux(cfg, tallies, prefix=""):
+    """The expert layers' tallies of one call as the engine's ``aux``."""
+    if not tallies:
         return {}
-    c = jnp.stack(all_counts)                           # [layers, held]
-    return {prefix + "moe_assignments": jnp.sum(c),
-            prefix + "moe_busiest": jnp.sum(jnp.max(c, axis=1)),
-            prefix + "moe_experts_touched": jnp.sum(c > 0),
-            prefix + "moe_layer_steps": jnp.int32(len(all_counts))}
+    c = jnp.stack([counts for counts, _ in tallies])    # [layers, held]
+    aux = {"moe_assignments": jnp.sum(c),
+           "moe_busiest": jnp.sum(jnp.max(c, axis=1)),
+           "moe_experts_touched": jnp.sum(c > 0),
+           "moe_layer_steps": jnp.int32(len(tallies))}
+    for name in tallies[0][1]:          # what the forms that ran cost
+        aux[name] = sum(cost[name] for _, cost in tallies)
+    return {prefix + k: v for k, v in aux.items()}
 
 
 def _logits(cfg, params, x):
@@ -425,7 +433,8 @@ def moe_mla_forward(params, cfg, tokens, *, use_pallas=False,
                 o = _attend_expanded(cfg, lp["wkv_b"], q, rows, 0,
                                      use_pallas, interpret)
                 return o.reshape(S, -1)
-            x, _ = _block(cfg, lp, x, attend)
+            x, _ = _block(cfg, lp, x, attend,
+                          kernels=(use_pallas, interpret))
         return _logits(cfg, params, x)
     return lax.map(one, tokens)     # a sequence at a time (no vmap of the
     #                                 grouped product, no batch of scores)
@@ -492,7 +501,8 @@ def moe_mla_decode_prefill(params, cfg, cache, tokens, start, length, table,
                     cfg, lp, h, pool, l, pos, blk, slot, table, start,
                     spans, which, use_pallas, interpret)
                 return out
-            x, counts = _block(cfg, lp, x, attend, valid)
+            x, counts = _block(cfg, lp, x, attend, valid,
+                               kernels=(use_pallas, interpret))
             if counts is not None:
                 all_counts.append(counts)
     x_last = jnp.take(x, jnp.clip(length - 1, 0, C - 1), axis=0)
@@ -610,7 +620,8 @@ def moe_mla_decode_step(params, cfg, cache, token_ids, positions, tables,
                 out, pool = _step_attend(cfg, lp, h, pool, l, positions,
                                          blk, slot, walk)
                 return out
-            x, counts = _block(cfg, lp, x, attend, active)
+            x, counts = _block(cfg, lp, x, attend, active,
+                               kernels=(use_pallas, interpret))
             if counts is not None:
                 all_counts.append(counts)
     logits = _logits(cfg, params, x)

@@ -1,10 +1,17 @@
 """Mixture-of-Experts with expert parallelism over an 'ep' mesh axis.
 
 Two expert layers live here (ROADMAP.md C names the duplication):
-`routed_experts`, the served one — top-k over the router's full width, told
-which experts it holds, a grouped matrix product over those, no capacity and
-no dropped token (models/moe_mla.py) — and `moe_ffn`, the older top-1 switch
-with capacity buffers, reachable from no model.
+`routed_experts`, the served one (models/moe_mla.py) — top-k over the
+router's full width, told which experts it holds, ONE grouped matrix
+product a projection over the held assignments sorted by expert, no
+capacity and no dropped token. The product has a form a tier: on the
+kernel tier `kernels/grouped_experts.py` (``mx_grouped_experts``) over the
+first ``cap`` sorted rows in row tiles of 128, each row then summed into its
+token by a one-hot product, so that a call costs what its HELD rows cost;
+on the lax tier (the CPU's path and the kernel's reference) a packed
+bucket an expert or ``jax.lax.ragged_dot``, each token gathering its own
+rows back. And `moe_ffn`, the older top-1 switch with capacity buffers,
+reachable from no model.
 
 `moe_ffn`:
 
@@ -28,6 +35,8 @@ import numpy as _np
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..kernels.grouped_experts import ROW_TILE, grouped_experts
 
 __all__ = ["init_moe_ffn", "moe_ffn", "routed_experts"]
 
@@ -99,8 +108,32 @@ def moe_ffn(params, x, axis_name="ep", capacity_factor=2.0):
     return y.astype(x.dtype), aux_loss
 
 
+def _held_caps(rows, share):
+    """Sorted-row capacities of the grouped form for ``rows`` assignments
+    of which a router that spreads evenly sends ``share`` here: that
+    expectation and a quarter, twice the expectation, and all rows (so
+    nothing is ever dropped), each in whole row tiles (under a tile: whole
+    sublane groups of 16)."""
+    expected = max(int(rows * share), 1)
+    unit = lambda c: ROW_TILE if c >= ROW_TILE else 16          # noqa: E731
+    return sorted({-(-c // unit(c)) * unit(c)
+                   for c in (min(expected + expected // 4, rows),
+                             min(2 * expected, rows), rows)})
+
+
+def _sum_by_token(ys, token, T):
+    """``[T, d]`` float32: row ``r`` of ``ys`` ``[cap, d]`` (float32) added
+    into token ``token[r]`` (a ``token`` outside ``0 .. T-1`` adds nowhere).
+    A float32 product with the ``[T, cap]`` one-hot matrix: the matrix unit
+    sums ``cap`` rows where a gather to (token, choice) order moves ``T *
+    top_k`` of them and a scatter-add costs 8 microseconds a row."""
+    onehot = (token[None, :] == jnp.arange(T)[:, None]).astype(jnp.float32)
+    return jnp.matmul(onehot, ys, precision=lax.Precision.HIGHEST)
+
+
 def routed_experts(params, x, *, held, top_k, scale, axis_name=None,
-                   valid=None, buckets=(64, 256, 512)):
+                   valid=None, buckets=(64, 256, 512), use_pallas=False,
+                   interpret=False):
     """The routed part of an expert layer, for the experts held HERE.
 
     ``held = (first, count)`` names the experts whose weights ``params``
@@ -118,43 +151,58 @@ def routed_experts(params, x, *, held, top_k, scale, axis_name=None,
     is ONE grouped matrix product over the held experts: the rows of expert
     ``e`` meet ``e``'s matrix and no other. No one-hot dispatch tensor, no
     capacity, no dropped token: every assignment to a held expert is
-    computed, whatever the routing. The grouped product has two forms and
-    the busiest expert's count chooses between them (``lax.switch``: static
-    shapes, one program):
+    computed, whatever the routing. The traced counts choose the product's
+    form among those the tier has (``lax.switch``: static shapes, one
+    program); every form ends in its own combine and hands back ``[T, d]``
+    float32.
+
+    **The kernel tier** (``use_pallas``; ``interpret`` for the CPU's tests)
+    has one form, *grouped*: the first ``cap`` SORTED rows, ``cap`` the
+    smallest of `_held_caps` that holds every held assignment (what an even
+    router sends ``count`` of its ``E`` experts and a quarter, twice that,
+    all ``T * top_k`` rows), through
+    `kernels/grouped_experts.py` (``mx_grouped_experts``: row tiles of 128
+    paired with their experts, an expert's weights fetched once). Each row
+    is then weighted and summed into its token by a one-hot product
+    (`_sum_by_token`). What it costs follows the rows that are HELD, not
+    ``count x bucket`` and not ``T * top_k``: a prefill piece's and a decode
+    step's form alike (PERF.md, PR 37).
+
+    **The lax tier** (the CPU's path, and the kernel's reference) has two,
+    and the busiest expert's count chooses:
 
     * *packed* — when no held expert was sent more than a bucket's rows
       (the smallest of ``buckets`` that fits), each expert's sorted rows
       fill a bucket of that many and the product is one batched ``[count,
-      bucket, d] x [count, d, f]``. Up to 256 rows an expert it costs
-      little more than reading the weights: what a decode step (a bucket of
-      64) and most prefill pieces (256) take; a bucket of 512 costs twice
-      that and still a third less than the ragged form;
+      bucket, d] x [count, d, f]``: ``count x bucket`` row-equivalents;
     * *ragged* — otherwise ``jax.lax.ragged_dot`` over all ``T * top_k``
-      sorted rows (what a routing that sends every token here needs). On a
-      TPU its row tile is ``min(rows, 512)``, so every group costs a tile of
-      512 however few its rows: right, never dropping, and twice the packed
-      form at a decode step's sizes (PERF.md, PR 28).
+      sorted rows (on a TPU its row tile is ``min(rows, 512)``, so every
+      group costs a tile of 512 however few its rows; PERF.md, PR 28).
 
-    Each token then sums its own ``top_k`` weighted rows (a gather back to
-    (token, choice) order: the same additions as a scatter-add, in a fixed
-    order; a scatter-add of rows costs the TPU 8 microseconds a row).
+    Each token then gathers its own ``top_k`` weighted rows back and sums
+    them (the same additions as a scatter-add, in a fixed order).
 
-    Returns ``(partial, counts)``: ``partial`` ``[T, d]`` float32 is the part
-    of ``sum_e w_e Expert_e(x)`` that the held experts give — what the absent
-    experts would add is left out, not stood in for — and ``counts``
-    ``[count]`` int32 the assignments each held expert received.
+    Returns ``(partial, counts, cost)``: ``partial`` ``[T, d]`` float32 is
+    the part of ``sum_e w_e Expert_e(x)`` that the held experts give — what
+    the absent experts would add is left out, not stood in for; ``counts``
+    ``[count]`` int32 the assignments each held expert received; ``cost``
+    two int32 counters of the form that ran: ``moe_rows_computed`` (the
+    row-equivalents its products covered: row tiles x 128, ``count x
+    bucket``, or all ``T * top_k``; over ``sum(counts)`` it is the form's
+    padding) and ``moe_form_grouped`` (1 where the grouped form ran).
 
     With ``axis_name`` this is a ``shard_map`` body: ``x`` and the router are
     replicated over the axis, the expert leaves are this share's, the share
     with index ``i`` holds experts ``first + i * count ..``, and the partial
-    results are summed across the shares (``psum``); ``counts`` stays this
-    share's. On one chip it runs without the exchange.
+    results are summed across the shares (``psum``); ``counts`` and ``cost``
+    stay this share's. On one chip it runs without the exchange.
     """
     first, count = held
     if axis_name is not None:
         first = first + lax.axis_index(axis_name) * count
     T, d = x.shape
     rows = T * top_k
+    kernel_tier = bool(use_pallas or interpret)
     buckets = sorted({min(int(b), rows) for b in buckets})
     with jax.named_scope("moe.route"):
         logits = jnp.matmul(x.astype(jnp.float32),
@@ -175,9 +223,23 @@ def routed_experts(params, x, *, held, top_k, scale, axis_name=None,
                          axis=0, dtype=jnp.int32)            # [count]
         starts = jnp.cumsum(counts) - counts
         token_of = order // top_k
+        # a row that is not held here is never computed (or is another
+        # assignment's): masked, not trusted to read zero
+        w = jnp.where(here, weight, 0.0)
     wg, wu, wd = (params["experts_gate"], params["experts_up"],
                   params["experts_down"])
     mm = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
+
+    def whole(n):
+        """A form's static cost as the traced ones are typed: in a
+        `shard_map` body the branches' results must vary over the same
+        axes, and a count does."""
+        return counts[0] * 0 + n
+
+    def own(ys):
+        """Each token's own ``top_k`` weighted rows, summed."""
+        return jnp.sum(jnp.where(here[:, :, None], ys, 0.0) * w[:, :, None],
+                       axis=1)
 
     def packed(bucket):
         def run(_):
@@ -189,9 +251,9 @@ def routed_experts(params, x, *, held, top_k, scale, axis_name=None,
             ye = mm("ebf,efd->ebd", h.astype(x.dtype), wd)
             at = jnp.clip(local, 0, count - 1)
             slot = rank.reshape(T, top_k) - jnp.take(starts, at)
-            return jnp.take(ye.reshape(count * bucket, d),
-                            at * bucket + jnp.clip(slot, 0, bucket - 1),
-                            axis=0)
+            ys = jnp.take(ye.reshape(count * bucket, d),
+                          at * bucket + jnp.clip(slot, 0, bucket - 1), axis=0)
+            return own(ys), whole(count * bucket)
         return run
 
     def ragged(_):
@@ -200,17 +262,38 @@ def routed_experts(params, x, *, held, top_k, scale, axis_name=None,
                                 preferred_element_type=jnp.float32)
         h = jax.nn.silu(dot(xs, wg)) * dot(xs, wu)
         ys = dot(h.astype(x.dtype), wd)                      # [T*k, d] f32
-        return jnp.take(ys, rank, axis=0).reshape(T, top_k, d)
+        return (own(jnp.take(ys, rank, axis=0).reshape(T, top_k, d)),
+                whole(rows))
+
+    def grouped(cap):
+        def run(_):
+            r = jnp.arange(cap)
+            at = jnp.take(order, jnp.minimum(r, rows - 1))   # assignments
+            token = at // top_k
+            ys, cost = grouped_experts(jnp.take(x, token, axis=0), counts,
+                                       wg, wu, wd, interpret=interpret)
+            # sorted rows past the held ones were never written
+            live = r < total
+            ys = jnp.where(live[:, None], ys, 0.0) \
+                * jnp.take(w.reshape(rows), at)[:, None]
+            return _sum_by_token(ys, jnp.where(live, token, T), T), cost
+        return run
 
     with jax.named_scope("moe.experts"):
-        busiest = jnp.max(counts)
-        form = sum((busiest > b).astype(jnp.int32) for b in buckets)
-        ys = lax.switch(form, [packed(b) for b in buckets] + [ragged], None)
-        # a row that is not held here was never computed (or is another
-        # assignment's): masked, not trusted to read zero
-        w = jnp.where(here, weight, 0.0)
-        partial = jnp.sum(jnp.where(here[:, :, None], ys, 0.0)
-                          * w[:, :, None], axis=1)
+        if kernel_tier:
+            # the first capacity that holds all held rows
+            total = jnp.sum(counts)
+            caps = _held_caps(rows, count / params["router"].shape[1])
+            form = sum((total > c).astype(jnp.int32) for c in caps[:-1])
+            forms = [grouped(c) for c in caps]
+        else:
+            # the first bucket that holds the busiest expert's rows, else ...
+            busiest = jnp.max(counts)
+            form = sum((busiest > b).astype(jnp.int32) for b in buckets)
+            forms = [packed(b) for b in buckets] + [ragged]
+        partial, computed = lax.switch(form, forms, None)
+    cost = {"moe_rows_computed": computed,
+            "moe_form_grouped": whole(int(kernel_tier))}
     if axis_name is not None:
         partial = lax.psum(partial, axis_name)
-    return partial, counts
+    return partial, counts, cost

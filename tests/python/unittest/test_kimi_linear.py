@@ -324,6 +324,13 @@ def test_the_kernel_tier_step_serves_the_lax_tiers_tokens():
     rows = stats["tokens"] - stats["prefills"]
     assert live <= m["kv_walked_tokens"] < live + 4 * rows
     assert lm["kv_walked_tokens"] > m["kv_walked_tokens"]
+    # the expert layers of every piece and step: the grouped kernel
+    # (`mx_grouped_experts`, interpreted) on its tier, never on the other
+    for pre in ("prefill_", ""):
+        assert m[pre + "moe_assignments"] == lm[pre + "moe_assignments"]
+        assert m[pre + "moe_form_grouped"] == m[pre + "moe_layer_steps"] > 0
+        assert lm[pre + "moe_form_grouped"] == 0
+        assert m[pre + "moe_rows_computed"] >= m[pre + "moe_assignments"]
 
 
 def test_mla_projection_without_rotary_does_not_read_positions(params):
@@ -390,9 +397,12 @@ def test_a_slot_used_again_and_a_neighbour_in_prefill_leave_no_trace():
 # ---------------------------------------------------------------------------
 # (d) the expert shares
 # ---------------------------------------------------------------------------
-def test_all_eight_shares_add_up_to_the_uncut_layer(params):
+@pytest.mark.parametrize("tier", [{}, {"interpret": True}],
+                         ids=["lax", "interpret"])
+def test_all_eight_shares_add_up_to_the_uncut_layer(params, tier):
     """The routed parts that all 8 shares of a layer give, the shared
-    expert counted ONCE, add up to the uncut reference's expert layer."""
+    expert counted ONCE, add up to the uncut reference's expert layer; on
+    the lax tier and through the grouped kernel (interpreted)."""
     lp = params["layers"][1]
     x = jnp.asarray(np.random.default_rng(2).standard_normal((19, 64)),
                     jnp.float32)
@@ -401,9 +411,11 @@ def test_all_eight_shares_add_up_to_the_uncut_layer(params):
     for first in range(8):
         share = dict(lp, **{k: lp[k][first:first + 1] for k in
                             ("experts_gate", "experts_up", "experts_down")})
-        part, counts = routed_experts(share, x, held=(first, 1), top_k=2,
-                                      scale=TINY["routed_scaling_factor"])
+        part, counts, cost = routed_experts(
+            share, x, held=(first, 1), top_k=2,
+            scale=TINY["routed_scaling_factor"], **tier)
         assert int(counts.sum()) > 0
+        assert int(cost["moe_form_grouped"]) == bool(tier)
         total = total + part
     shared = M._gated_mlp(x, lp["shared_gate"], lp["shared_up"],
                           lp["shared_down"])

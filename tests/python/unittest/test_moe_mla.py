@@ -225,10 +225,25 @@ def test_prefill_then_decode_through_the_engine_matches_the_full_forward(
 
 
 def test_the_flash_tier_prefill_serves_the_same_tokens(params):
-    plain, _, _ = serve(params, "mlalax")
-    flash, rec, _ = serve(params, "mlaflash", flash="interpret")
+    """Chunked prefill, then decode, through a real engine on the kernels'
+    tier (interpreted): the flash kernel and, in every expert layer of
+    every piece and step, the grouped kernel `mx_grouped_experts` serve the
+    lax tier's tokens (packed buckets there), and both tiers count what
+    their forms computed."""
+    plain, _, lax_stats = serve(params, "mlalax")
+    flash, rec, stats = serve(params, "mlaflash", flash="interpret")
     assert [o for _, o in flash] == [o for _, o in plain]
     assert worst_logit_gap(params, flash, rec)[0] < TOL
+    m, lm = stats["model"], lax_stats["model"]
+    for pre in ("prefill_", ""):
+        assert m[pre + "moe_assignments"] == lm[pre + "moe_assignments"]
+        assert m[pre + "moe_form_grouped"] == m[pre + "moe_layer_steps"]
+        assert lm[pre + "moe_form_grouped"] == 0
+        assert m[pre + "moe_rows_computed"] >= m[pre + "moe_assignments"]
+    # the lax tier: one bucket of all a piece's rows for each of 8 experts
+    pieces = [min(16, n - s) for n in PROMPTS for s in range(0, n, 16)]
+    assert lm["prefill_moe_rows_computed"] == sum(
+        2 * 8 * 2 * (8 if p <= 8 else 16) for p in pieces)
 
 
 def test_the_kernel_tier_step_serves_the_lax_tiers_tokens(params):
@@ -393,25 +408,34 @@ def share_of(lp, first, count):
                                  "experts_down")})
 
 
+TIERS = {"lax": {}, "interpret": {"interpret": True}}
+tiers = pytest.mark.parametrize("tier", list(TIERS))
+
+
+@tiers
 @pytest.mark.parametrize("count,buckets", [
     (1, (64, 256, 512)), (2, (64, 256, 512)), (4, (64, 256, 512)),
     (8, (64, 256, 512)),
     (2, (4, 16)), (8, (3,)), (4, (2, 5))])
-def test_the_shares_add_up_to_the_uncut_layer(params, count, buckets):
+def test_the_shares_add_up_to_the_uncut_layer(params, count, buckets, tier):
     """Over all shares of the experts, the routed parts summed plus the
-    shared expert ONCE equal the uncut reference layer; in the packed form
-    of the grouped product (every expert's rows fit the first bucket, or
-    only the second) and in the ragged one (no bucket fits the busiest
-    expert)."""
+    shared expert ONCE equal the uncut reference layer; on the lax tier in
+    the packed form of the grouped product (every expert's rows fit the
+    first bucket, or only the second) and in the ragged one (no bucket fits
+    the busiest expert), on the kernel tier through the grouped kernel
+    (interpreted) whatever the buckets."""
     lp, x = params["layers"][2], expert_inputs()
     want = np.asarray(ref.expert_layer(TINY, lp, x, "float32"))
-    total, seen = 0.0, 0
+    total, seen, grouped = 0.0, 0, 0
     for first in range(0, 8, count):
-        part, counts = routed_experts(
+        part, counts, cost = routed_experts(
             share_of(lp, first, count), x, held=(first, count), top_k=2,
-            scale=2.5, buckets=buckets)
+            scale=2.5, buckets=buckets, **TIERS[tier])
         total = total + part
         seen += int(counts.sum())
+        grouped += int(cost["moe_form_grouped"])
+        assert int(cost["moe_rows_computed"]) >= int(counts.sum())
+    assert grouped == (8 // count if tier == "interpret" else 0)
     shared = M._gated_mlp(x, lp["shared_gate"], lp["shared_up"],
                           lp["shared_down"])
     assert seen == 24 * 2                       # every assignment, once
@@ -424,41 +448,159 @@ def test_the_shares_add_up_to_the_uncut_layer(params, count, buckets):
     assert np.abs(np.asarray(got - want1)).max() < 1e-5
 
 
+def to_expert_3(lp, x):
+    """A routing that sends every token of ``x`` to expert 3."""
+    lp = dict(lp)
+    lp["router"] = lp["router"].at[0, 3].set(50.0)
+    return lp, x.at[:, 0].set(4.0)
+
+
+@tiers
 @pytest.mark.parametrize("buckets", [(64, 256, 512), (8, 64), (8, 16)])
 def test_no_assignment_is_dropped_when_every_token_goes_to_one_expert(
-        params, buckets):
+        params, buckets, tier):
     """A routing that sends all forty tokens to expert 3: forty rows where a
     uniform router sends ten. Nothing is dropped, whichever form of the
     grouped product the counts choose: the first bucket, the second, or
-    (buckets of 8 and 16) the ragged one."""
-    lp = dict(params["layers"][1])
-    x = expert_inputs(n=40).at[:, 0].set(4.0)
-    lp["router"] = lp["router"].at[0, 3].set(50.0)   # expert 3 wins always
-    part, counts = routed_experts(lp, x, held=(0, 8), top_k=2, scale=2.5,
-                                  buckets=buckets)
+    (buckets of 8 and 16) the ragged one; on the kernel tier the grouped
+    kernel over ALL sorted rows (every assignment is held: its last
+    capacity)."""
+    lp, x = to_expert_3(params["layers"][1], expert_inputs(n=40))
+    part, counts, cost = routed_experts(lp, x, held=(0, 8), top_k=2,
+                                        scale=2.5, buckets=buckets,
+                                        **TIERS[tier])
     assert int(counts[3]) == 40 and int(counts.sum()) == 80
+    assert int(cost["moe_form_grouped"]) == (tier == "interpret")
     want = ref.routed_part(TINY, lp, x, "float32")
     assert np.abs(np.asarray(part - want)).max() < 1e-5
     # held alone, expert 3 still takes all forty (a capacity would not)
-    part3, c3 = routed_experts(share_of(lp, 3, 1), x, held=(3, 1), top_k=2,
-                               scale=2.5, buckets=buckets)
+    part3, c3, _ = routed_experts(share_of(lp, 3, 1), x, held=(3, 1),
+                                  top_k=2, scale=2.5, buckets=buckets,
+                                  **TIERS[tier])
     assert c3.tolist() == [40]
 
 
-def test_counts_equal_a_numpy_count_and_padding_counts_nowhere(params):
-    lp, x = params["layers"][1], expert_inputs(seed=6)
+def numpy_top2(lp, x):
     sigma = 1 / (1 + np.exp(-(np.asarray(x, np.float64)
                               @ np.asarray(lp["router"], np.float64))))
-    top = np.argsort(-sigma, axis=1)[:, :2]
+    return np.argsort(-sigma, axis=1)[:, :2]
+
+
+@tiers
+def test_counts_equal_a_numpy_count_and_padding_counts_nowhere(params, tier):
+    """Buckets of 2 and 3 (the ragged form on the lax tier); the grouped
+    kernel on its tier, over the held rows alone: a padding row is nobody's
+    row there either."""
+    lp, x = params["layers"][1], expert_inputs(seed=6)
+    top = numpy_top2(lp, x)
     valid = np.arange(24) % 3 != 0
-    _, counts = routed_experts(share_of(lp, 2, 4), x, held=(2, 4), top_k=2,
-                               scale=2.5)
+    kw = dict(held=(2, 4), top_k=2, scale=2.5, buckets=(2, 3), **TIERS[tier])
+    _, counts, _ = routed_experts(share_of(lp, 2, 4), x, **kw)
     assert counts.tolist() == [(top == e).sum() for e in range(2, 6)]
-    part, counts = routed_experts(share_of(lp, 2, 4), x, held=(2, 4),
-                                  top_k=2, scale=2.5,
-                                  valid=jnp.asarray(valid))
+    part, counts, cost = routed_experts(share_of(lp, 2, 4), x,
+                                        valid=jnp.asarray(valid), **kw)
     assert counts.tolist() == [(top[valid] == e).sum() for e in range(2, 6)]
     assert np.abs(np.asarray(part)[~valid]).max() == 0.0
+    assert int(cost["moe_form_grouped"]) == (tier == "interpret")
+    held = dict(tiny(), experts_held={"first": 2, "count": 4})
+    want = np.asarray(ref.routed_part(held, share_of(lp, 2, 4), x, "float32"))
+    assert np.abs(np.asarray(part) - want)[valid].max() < 1e-5
+
+
+def tiles_of(counts, tile):
+    """(row tile, expert) pairs that share rows: what the grouped kernel's
+    grid walks, counted in NumPy."""
+    ends = np.cumsum(counts)
+    return sum(int((e - 1) // tile - (e - c) // tile + 1)
+               for e, c in zip(ends, counts) if c)
+
+
+@pytest.mark.parametrize("n,buckets", [(24, (2,)), (40, (4, 8)),
+                                       (300, (16,)), (300, (64, 256))])
+def test_what_a_form_computes_equals_a_numpy_count(params, n, buckets):
+    """``moe_rows_computed`` and ``moe_form_grouped`` of both tiers: a
+    packed form computes count x bucket rows, the ragged one every
+    assignment's row, the grouped kernel a tile for every (row tile,
+    expert) pair that share rows, over the smallest capacity that holds
+    the held total."""
+    from mxnet_tpu.parallel.moe import ROW_TILE, _held_caps
+    lp, x = params["layers"][2], expert_inputs(n=n)
+    counts = np.bincount(numpy_top2(lp, x).ravel(), minlength=8)
+    fits = [b for b in buckets if b >= counts.max()]
+    for tier, kw in TIERS.items():
+        part, got, cost = routed_experts(lp, x, held=(0, 8), top_k=2,
+                                         scale=2.5, buckets=buckets, **kw)
+        assert got.tolist() == counts.tolist()
+        if tier == "lax":
+            rows, grouped = 8 * fits[0] if fits else 2 * n, 0
+        else:
+            cap = min(c for c in _held_caps(2 * n, 1.0) if c >= counts.sum())
+            tile = min(cap, ROW_TILE)
+            rows, grouped = tiles_of(counts, tile) * tile, 1
+        assert (int(cost["moe_rows_computed"]),
+                int(cost["moe_form_grouped"])) == (rows, grouped), tier
+        want = ref.routed_part(TINY, lp, x, "float32")
+        assert np.abs(np.asarray(part - want)).max() < 1e-5
+
+
+@tiers
+def test_every_row_to_one_expert_at_a_pieces_size(params, tier):
+    """160 tokens, all to expert 3 and each to one more: every one of the
+    320 assignments is held, so the grouped kernel runs over ALL sorted
+    rows (its last capacity) and expert 3's 160 rows span two row tiles."""
+    lp, x = to_expert_3(params["layers"][1], expert_inputs(n=160))
+    part, counts, cost = routed_experts(lp, x, held=(0, 8), top_k=2,
+                                        scale=2.5, **TIERS[tier])
+    assert int(counts[3]) == 160 and int(counts.sum()) == 320
+    assert int(cost["moe_form_grouped"]) == (tier == "interpret")
+    want = ref.routed_part(TINY, lp, x, "float32")
+    assert np.abs(np.asarray(part - want)).max() < 1e-5
+
+
+@tiers
+def test_no_row_held_at_all_gives_zeros(params, tier):
+    """Every token is padding: no assignment, no row, no grid step; what
+    the kernel's buffers hold is never read."""
+    lp, x = params["layers"][1], expert_inputs(n=80)
+    part, counts, cost = routed_experts(
+        lp, x, held=(0, 8), top_k=2, scale=2.5, buckets=(256,),
+        valid=jnp.zeros((80,), bool), **TIERS[tier])
+    assert counts.tolist() == [0] * 8
+    assert np.abs(np.asarray(part)).max() == 0.0
+    if tier == "interpret":
+        assert {k: int(v) for k, v in cost.items()} == {
+            "moe_rows_computed": 0, "moe_form_grouped": 1}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("counts", [
+    [128, 128, 0, 0],       # a group that ends exactly on a tile's edge
+    [127, 2, 100, 27],      # one that starts on a tile's last row
+    [5, 0, 130, 3],         # an empty group; one across two tiles
+    [0, 0, 0, 0], [0, 0, 0, 256]])
+def test_the_grouped_kernel_on_tile_edges(counts, dtype):
+    """kernels/grouped_experts.py alone (interpreted): each sorted row
+    against its own expert's matrices wherever its group begins and ends;
+    rows past the held total are nobody's and not compared."""
+    from mxnet_tpu.kernels.grouped_experts import grouped_experts
+    rng = np.random.default_rng(sum(counts))
+    dt = jnp.dtype(dtype)
+    x, wg, wu, wd = (jnp.asarray(rng.standard_normal(s) * a, dt)
+                     for s, a in (((256, 64), 1.0), ((4, 64, 48), 0.2),
+                                  ((4, 64, 48), 0.2), ((4, 48, 64), 0.2)))
+    y, rows = grouped_experts(x, jnp.asarray(counts, jnp.int32), wg, wu, wd,
+                              interpret=True)
+    assert y.dtype == jnp.float32
+    assert int(rows) == tiles_of(counts, 128) * 128
+    of = np.repeat(np.arange(4), counts)
+    f32 = lambda a: np.asarray(a, np.float32)               # noqa: E731
+    xs = f32(x)[:len(of)]
+    gate = np.einsum("rd,rdf->rf", xs, f32(wg)[of])
+    h = gate / (1 + np.exp(-gate)) * np.einsum("rd,rdf->rf", xs, f32(wu)[of])
+    want = np.einsum("rf,rfd->rd", f32(jnp.asarray(h, dt)), f32(wd)[of])
+    if len(of):
+        tol = 1e-5 if dtype == "float32" else 2e-2
+        assert np.abs(np.asarray(y)[:len(of)] - want).max() < tol
 
 
 def test_engine_aux_counters_equal_a_numpy_count(params):
@@ -490,9 +632,11 @@ def test_engine_aux_counters_equal_a_numpy_count(params):
         == sum(x for _, x in seen)
 
 
-def test_routed_experts_over_an_ep_axis_sums_the_shares(params):
+@tiers
+def test_routed_experts_over_an_ep_axis_sums_the_shares(params, tier):
     """The shard_map body: four shares of two experts each, the partial
-    results summed across the `ep` axis (parallel/mesh.py)."""
+    results summed across the `ep` axis (parallel/mesh.py); buckets of 2
+    and 4 send the busier shares to the ragged form or the grouped kernel."""
     from jax.sharding import PartitionSpec as P
     from mxnet_tpu.parallel import get_mesh
     mesh = get_mesh(dp=2, ep=4)
@@ -504,13 +648,17 @@ def test_routed_experts_over_an_ep_axis_sums_the_shares(params):
              "experts_down": P("ep")}
 
     def body(p, x):
-        part, counts = routed_experts(p, x, held=(0, 2), top_k=2, scale=2.5,
-                                      axis_name="ep")
+        part, counts, _ = routed_experts(p, x, held=(0, 2), top_k=2,
+                                         scale=2.5, axis_name="ep",
+                                         buckets=(2, 4), **TIERS[tier])
         return part, counts
 
+    # (the Pallas INTERPRETER cannot slice a varying table by a grid index
+    # under the varying-axes check; the compiled kernel states what its
+    # result varies over, `test_tpu_compile.py`)
     part, counts = jax.jit(jax.shard_map(
-        body, mesh=mesh, in_specs=(specs, P()), out_specs=(P(), P("ep"))))(
-            leaves, x)
+        body, mesh=mesh, in_specs=(specs, P()), out_specs=(P(), P("ep")),
+        check_vma=tier == "lax"))(leaves, x)
     want = ref.routed_part(TINY, lp, x, "float32")
     assert np.abs(np.asarray(part - want)).max() < 1e-5
     assert counts.shape == (8,) and int(counts.sum()) == 24 * 2

@@ -200,6 +200,93 @@ def test_grouped_expert_product_compiles_at_published_widths(one_chip):
     assert "ragged" in text.lower()
 
 
+@pytest.mark.parametrize("count,d,f", [(16, 7680, 2048), (32, 2304, 1024)],
+                         ids=["pangu", "kimi"])
+def test_grouped_experts_kernel_compiles_at_a_pieces_size(one_chip, count, d,
+                                                          f):
+    """parallel/moe.py::routed_experts on the kernel tier at a 1,024-token
+    prefill piece x top-8, for pangu's 16 held experts of 7680 x 2048 and
+    kimi's 32 of 2304 x 1024: every capacity of sorted rows is two
+    `mx_grouped_experts` calls (gate-and-up, down) whose whole-contraction weight blocks fit the kernel's VMEM, every form hands
+    back ``[1024, d]``, and no ``[T, top_k, d]`` float32 gather of a
+    token's rows is left in the program. The capacities follow the held
+    share of the router's width: 640 / 1,024 / 8,192 sorted rows for 16 of
+    256, 1,280 / 2,048 / 8,192 for 32."""
+    from mxnet_tpu.parallel.moe import _held_caps, routed_experts
+    sd = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16,      # noqa: E731
+                                         sharding=one_chip)
+    params = {"router": sd(d, 256), "experts_gate": sd(count, d, f),
+              "experts_up": sd(count, d, f), "experts_down": sd(count, f, d)}
+    text = _compile(lambda p, x: routed_experts(
+        p, x, held=(0, count), top_k=8, scale=2.5, use_pallas=True),
+        params, sd(1024, d))
+    calls = [ln for ln in text.splitlines()
+             if "mx_grouped_experts" in ln and "custom-call(" in ln]
+    assert _held_caps(8192, count / 256) == {
+        16: [640, 1024, 8192], 32: [1280, 2048, 8192]}[count]
+    assert len(calls) == 2 * 3, len(calls)
+    assert "f32[1024,8,%d]" % d not in text
+    assert "ragged" not in text.lower()
+
+
+def test_grouped_experts_kernel_compiles_as_an_ep_share(topo):
+    """The `shard_map` body over four described chips: each share's two
+    `mx_grouped_experts` calls state what their results vary over (the
+    `ep` axis), and the partial results meet in one all-reduce."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from mxnet_tpu.parallel.moe import routed_experts
+    mesh = Mesh(np.array(topo.devices), ("ep",))
+    sd = lambda spec, *s: jax.ShapeDtypeStruct(                # noqa: E731
+        s, jnp.bfloat16, sharding=NamedSharding(mesh, spec))
+    params = {"router": sd(P(), 2304, 256),
+              "experts_gate": sd(P("ep"), 32, 2304, 1024),
+              "experts_up": sd(P("ep"), 32, 2304, 1024),
+              "experts_down": sd(P("ep"), 32, 1024, 2304)}
+    specs = {k: v.sharding.spec for k, v in params.items()}
+
+    def body(p, x):
+        part, counts, _ = routed_experts(p, x, held=(0, 8), top_k=8,
+                                         scale=2.446, axis_name="ep",
+                                         use_pallas=True)
+        return part, counts
+    text = _compile(jax.shard_map(
+        body, mesh=mesh, in_specs=(specs, P()), out_specs=(P(), P("ep"))),
+        params, sd(P(), 256, 2304))
+    assert "mx_grouped_experts" in text and "all-reduce" in text
+
+
+@pytest.mark.parametrize("cell", ["kimi", "pangu"])
+def test_latent_prefill_piece_runs_its_experts_through_the_grouped_kernel(
+        one_chip, cell):
+    """The two latent cells' 1,024-token prefill programs at the served
+    sizes, on the kernel tier: every expert layer's routed product is the
+    grouped kernel (two `mx_grouped_experts` calls for each of its three
+    capacities), no conditional hands a ``[1024, 8, d]`` float32 back (the
+    parent's four heaviest device operations, PERF.md PR 37), and the
+    program's temporaries are under the parent's (counted, PR 37)."""
+    model, e, _ = _latent_cell(cell)
+    on_chip = lambda t: jax.tree_util.tree_map(                 # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    B, bs = e["batch_size"], e["block_size"]
+    mb = e["max_seq_len"] // bs
+    cache = on_chip(model.cache_spec(e["num_blocks"], bs, B))
+    sd = lambda s, d: jax.ShapeDtypeStruct(s, d,               # noqa: E731
+                                           sharding=one_chip)
+    compiled = jax.jit(model.prefill_fn, donate_argnums=(1,)).lower(
+        on_chip(model.params), cache, sd((1024,), jnp.int32),
+        sd((), jnp.int32), sd((), jnp.int32), sd((mb,), jnp.int32),
+        sd((), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines()
+             if "mx_grouped_experts" in ln and "custom-call(" in ln]
+    layers = sum("router" in lp for lp in model.params["layers"])
+    assert len(calls) == layers * 3 * 2, len(calls)
+    assert "f32[1024,8,%d]" % model.cfg.hidden_size not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < {
+        "pangu": 831837696, "kimi": 372275200}[cell]
+
+
 def test_gpt2_decode_step_keeps_heads_in_lanes(one_chip):
     """models/transformer.py's decode step at the benchmark cell's shapes
     (64 rows, tables of 64 blocks of 16, 12 heads of 64, two layers): the
