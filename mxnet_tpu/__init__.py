@@ -6,7 +6,12 @@ capability map. Import as `import mxnet_tpu as mx` — reference scripts written
 against `import mxnet as mx` run with only the import line changed (or via
 `sys.modules` aliasing in examples/).
 """
+import time as _time
 
+_t_import = _time.time()  # the import's own stamp: profiler.record_import
+
+# first, so that its compile listeners see every compile from here on
+from . import profiler  # noqa: E402
 from .libinfo import __version__  # noqa: E402
 
 # Join the launcher's process group BEFORE anything can touch a backend
@@ -75,7 +80,6 @@ from . import gluon
 from . import rnn
 from . import recordio
 from . import visualization
-from . import profiler
 from . import monitor
 from .monitor import Monitor
 from . import image
@@ -91,3 +95,5 @@ from . import test_utils
 from . import random as rnd  # reference: mx.rnd alias
 
 viz = visualization
+
+profiler.record_import(_t_import, _time.time())

@@ -131,8 +131,6 @@ class ProgramBuilder:
         self.compiles = 0            # programs built by THIS builder
         self.traces = 0              # distinct traces performed
         self.lowerings = 0           # distinct lowerings performed
-        from .. import profiler as _prof
-        _prof.ensure_compile_listener()
 
     # ------------------------------------------------------------------
     # keys

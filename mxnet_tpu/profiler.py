@@ -28,9 +28,8 @@ __all__ = ["set_config", "set_state", "dump", "dumps", "pause", "resume",
            "record_supervisor_event", "supervisor_counters",
            "record_decode_event", "decode_counters",
            "record_compile", "record_compile_hit", "record_compile_corrupt",
-           "compile_counters",
-           "ensure_compile_listener", "persistent_cache_hit_count",
-           "thread_persistent_cache_hits"]
+           "compile_counters", "thread_persistent_cache_hits",
+           "CompilePhases", "record_import", "compile_phase_counters"]
 
 _state = {"running": False, "filename": "profile.json", "events": [],
           "jax_trace_dir": None, "lock": threading.Lock()}
@@ -549,7 +548,7 @@ _COMPILE_ZERO = {"compiles": 0, "compile_ms": 0.0, "aot": 0,
                  "cache_corrupt": 0}
 _compile_total = dict(_COMPILE_ZERO)
 _compile_sites = {}
-_pcache = {"hits": 0, "listener": False}
+_pcache = {"hits": 0}
 _pcache_tls = threading.local()
 
 
@@ -563,25 +562,6 @@ def _pcache_listener(event, **kwargs):
         _pcache_tls.hits = getattr(_pcache_tls, "hits", 0) + 1
         with _state["lock"]:
             _pcache["hits"] += 1
-
-
-def ensure_compile_listener():
-    """Register the jax.monitoring listener that counts persistent
-    compile-cache hits. Idempotent; called once per ProgramBuilder
-    construction (never on a dispatch path)."""
-    with _state["lock"]:
-        if _pcache["listener"]:
-            return
-        _pcache["listener"] = True
-    from jax import monitoring as _monitoring
-    _monitoring.register_event_listener(_pcache_listener)
-
-
-def persistent_cache_hit_count():
-    """Raw count of jax persistent-compilation-cache hits observed this
-    process (the process-wide figure `compile_counters()` reports)."""
-    with _state["lock"]:
-        return _pcache["hits"]
 
 
 def thread_persistent_cache_hits():
@@ -640,6 +620,151 @@ def compile_counters(reset=False):
             _compile_sites.clear()
             _pcache["hits"] = 0
     return out
+
+
+# ----------------------------------------------------------------------
+# compile-phase counters: where a process's set-up goes. JAX reports the
+# phases of every compile through jax.monitoring, with wall-clock start
+# and end (time.time()) and the function's name: the Python-level trace,
+# the lowering to MLIR (every Pallas kernel's Mosaic lowering inside it)
+# and the backend's compile-or-load (a persistent-cache hit is a load).
+# They fire on compiles only, never on a call to a built program, and
+# for every compile in the process, inside ProgramBuilder or not. Traces
+# nest (an outer function's trace holds its inner jits' own), so a phase
+# keeps the UNION of its intervals, as wall time: a list of disjoint
+# stretches. Beside it, per function, its summed seconds in bursts, which
+# names the programs that cost most. Storage grows with the functions
+# compiled, never with the calls made.
+# ----------------------------------------------------------------------
+_PHASE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend"}
+
+
+def _merge_span(spans, start, end):
+    """Fold [start, end] into ``spans``, a sorted list of disjoint
+    ``[start, end]`` stretches. Intervals arrive as they end, so the
+    stretches they touch are the last few."""
+    i = len(spans)
+    while i and spans[i - 1][1] >= start:
+        i -= 1
+    j = i
+    while j < len(spans) and spans[j][0] <= end:
+        start, end = min(start, spans[j][0]), max(end, spans[j][1])
+        j += 1
+    spans[i:j] = [[start, end]]
+
+
+class CompilePhases:
+    """Wall time of a process's compiles by phase (``trace``, ``lower``,
+    ``backend``) and the package's import. ``listener`` is the
+    ``jax.monitoring`` time-span listener; the module keeps one instance,
+    registered at import. The listener only appends to a list (atomic
+    under the interpreter lock, so it takes no lock of its own):
+    intervals are folded into the stretches and into per-function bursts
+    when a snapshot is taken, or once ``FOLD_AT`` of them wait."""
+
+    PHASES = ("trace", "lower", "backend")
+    FOLD_AT = 4096
+    # a function's intervals less than this apart are one burst, which a
+    # snapshot's cut takes or leaves whole
+    BURST_GAP_S = 1.0
+
+    def __init__(self):
+        self._lock = threading.Lock()              # one fold at a time
+        self._pending = pending = []               # (event, name, start, end)
+        self._spans = {p: [] for p in self.PHASES}
+        self._funs = {p: {} for p in self.PHASES}  # name -> [[end, s, n]]
+        self._import = None
+
+        append, fold_at, events = pending.append, self.FOLD_AT, _PHASE_EVENTS
+
+        def listener(event, start_time, end_time, fun_name=None, **kwargs):
+            if event in events:
+                append((event, fun_name, start_time, end_time))
+                if len(pending) >= fold_at:
+                    self._fold()
+
+        self.listener = listener
+
+    def _fold(self):
+        with self._lock:
+            # intervals appended while this runs stay for the next fold
+            n = len(self._pending)
+            for event, name, start, end in self._pending[:n]:
+                phase = _PHASE_EVENTS[event]
+                _merge_span(self._spans[phase], start, end)
+                bursts = self._funs[phase].setdefault(name, [])
+                if bursts and start <= bursts[-1][0] + self.BURST_GAP_S:
+                    burst = bursts[-1]
+                    burst[0] = max(burst[0], end)
+                    burst[1] += end - start
+                    burst[2] += 1
+                else:
+                    bursts.append([end, end - start, 1])
+            del self._pending[:n]
+
+    def stamp_import(self, start, end):
+        self._import = (start, end)
+
+    def snapshot(self, before=None, top=5):
+        """``{"import_s", "trace_s", "lower_s", "backend_s", "events",
+        "top"}``. Each phase reads the seconds of the union of its
+        intervals that end at or before ``before`` (a ``time.time()``;
+        ``None``: all). Overlapping intervals are kept merged, so a
+        stretch that runs past ``before`` is left out whole. ``events``
+        counts a phase's intervals and ``top`` lists its ``top``
+        functions by their summed seconds, ``[name, seconds]``, both cut
+        at ``before`` by bursts. ``import_s`` is ``None`` until the
+        import is stamped."""
+        cut = float("inf") if before is None else before
+        self._fold()
+        with self._lock:
+            imp = self._import
+            out = {"import_s": None if imp is None else imp[1] - imp[0]}
+            for p in self.PHASES:
+                out[p + "_s"] = sum((e - s for s, e in self._spans[p]
+                                     if e <= cut), 0.0)
+            funs = {p: [(str(name), sum(b[1] for b in bursts if b[0] <= cut),
+                         sum(b[2] for b in bursts if b[0] <= cut))
+                        for name, bursts in self._funs[p].items()]
+                    for p in self.PHASES}
+        out["events"] = {p: sum(n for _, _, n in funs[p])
+                         for p in self.PHASES}
+        out["top"] = {p: [[name, s] for name, s, _ in sorted(
+                          (f for f in funs[p] if f[2]),
+                          key=lambda f: -f[1])[:top]]
+                      for p in self.PHASES}
+        return out
+
+
+_phases = CompilePhases()
+
+
+def record_import(start, end):
+    """Stamp the package's own import (``mxnet_tpu/__init__.py``), wall
+    clock: what ``compile_phase_counters()["import_s"]`` reads."""
+    _phases.stamp_import(start, end)
+
+
+def compile_phase_counters(before=None, top=5):
+    """Where set-up went, by phase, as wall-clock seconds: the package's
+    import (``import_s``), and the union over every compile of the
+    process of its Python-level trace (``trace_s``), its lowering to MLIR
+    (``lower_s``) and the backend's compile or persistent-cache load
+    (``backend_s``), counting intervals that end by ``before`` (a
+    ``time.time()``; the benchmark passes the opening of its window).
+    ``events`` and ``top`` name how many compiles and which functions
+    cost most in each phase (:meth:`CompilePhases.snapshot`)."""
+    return _phases.snapshot(before, top)
+
+
+# Both compile listeners are registered here, once, as the module is
+# imported: nothing is recorded on a dispatch path, and a compile that
+# runs before any ProgramBuilder exists is counted too.
+jax.monitoring.register_event_listener(_pcache_listener)
+jax.monitoring.register_event_time_span_listener(_phases.listener)
 
 
 def record_op_event(name, dur_s, category="operator"):

@@ -132,12 +132,14 @@ WORKER = textwrap.dedent("""
             optimizer_params={"learning_rate": 0.2})
     kv = mod._kvstore
     assert kv.type == "dist_async", kv.type
+    # read the server after BOTH workers are done: under load one worker
+    # can finish all its epochs before the other has pushed at all
+    kv.barrier()
     stats = kv.server_stats()
     with open(%(outdir)r + "/worker%%d.json" %% rank, "w") as f:
         json.dump({"acc": metric.get()[1], "rank": rank,
                    "push_count": stats["push_count"],
                    "per_server": stats.get("per_server", [])}, f)
-    kv.barrier()
 """)
 
 
