@@ -29,6 +29,7 @@ __all__ = ["set_config", "set_state", "dump", "dumps", "pause", "resume",
            "record_decode_event", "decode_counters",
            "record_compile", "record_compile_hit", "record_compile_corrupt",
            "compile_counters", "thread_persistent_cache_hits",
+           "record_lowering", "lowering_counters",
            "CompilePhases", "record_import", "compile_phase_counters"]
 
 _state = {"running": False, "filename": "profile.json", "events": [],
@@ -619,6 +620,33 @@ def compile_counters(reset=False):
             _compile_total.update(_COMPILE_ZERO)
             _compile_sites.clear()
             _pcache["hits"] = 0
+    return out
+
+
+# ----------------------------------------------------------------------
+# lowering counters: how often an op took a lowering chosen from its
+# shapes, counted as the op's function runs: once a trace in a compiled
+# program (the fused step, an executor), once a call in eager mode.
+# ``conv_space_to_depth``: a strided convolution over few input channels
+# run as a stride-1 one over a space-to-depth input (ops/nn.py).
+# ----------------------------------------------------------------------
+_LOWERING_ZERO = {"conv_space_to_depth": 0}
+_lowering = dict(_LOWERING_ZERO)
+
+
+def record_lowering(form):
+    """Count one trace of an op through the lowering ``form``."""
+    with _state["lock"]:
+        _lowering[form] = _lowering.get(form, 0) + 1
+
+
+def lowering_counters(reset=False):
+    """Snapshot (optionally reset) the lowering counters."""
+    with _state["lock"]:
+        out = dict(_lowering)
+        if reset:
+            _lowering.clear()
+            _lowering.update(_LOWERING_ZERO)
     return out
 
 
