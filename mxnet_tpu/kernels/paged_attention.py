@@ -12,6 +12,11 @@ place (`paged_latent_attention`, ``mx_paged_latent_attn``). A row a head
 (twin pools, heads folded into the lanes) is another contraction: the same
 compaction, page copies and running softmax as `paged_head_attention`
 (``mx_eva_paged_attn``; `models/evabyte.py`: summaries and window, one walk).
+A sliding window is no page range of one table: a window layer of the
+latent family keeps a RING of its last ``W`` latent rows a decode slot
+(position ``p`` at ring row ``p % W``, a per-slot pool), which
+`window_latent_attention` (``mx_window_latent_attn``) attends and writes in
+place; `models/motif.py` puts it beside paged full layers.
 
 **The page format.** A pool is ``(layers, num_blocks, block_size, width)``.
 Position ``p`` of a sequence whose block table is ``table`` lives at
@@ -666,3 +671,173 @@ def paged_head_attention(q, k_new, v_new, k_pool, v_pool, layer, last,
             k_new.astype(dt)[:, None], v_new.astype(dt)[:, None], k_pool,
             v_pool)
     return jnp.where(active[:, None], out[:, 0], 0.0)
+
+
+# ---------------------------------------------------------------------------
+# a window of latent rows: a ring a slot, attended and written in place
+# ---------------------------------------------------------------------------
+#: Rows of a ring copied back to the pool at once: a bfloat16 tile's height.
+_RING_TILE = 16
+
+
+def ring_live(positions, width):
+    """``[..., width]`` bool: which rows of a ``width``-row ring hold one of
+    the positions ``p - width + 1 .. p`` (and none below 0) once the row at
+    position ``p`` (``positions`` ``[...]``) has been written at ``p %
+    width``."""
+    w = jnp.arange(width, dtype=jnp.int32)
+    p = jnp.asarray(positions, jnp.int32)[..., None]
+    return (w <= p) | (p >= width - 1)
+
+
+def _window_attn_kernel(layer_ref, rows_ref, n_ref, pos_ref, q_ref, new_ref,
+                        ring_ref, o_ref, ring_out_ref, buf, sems, back_sem,
+                        slot_ref, *, sm_scale, width):
+    """Grid step ``i``: the ``i``-th ACTIVE row. Its slot's ring arrives in
+    one copy in a half of ``buf`` ``[2, W, row]``, the next row's started as
+    soon as this one has landed; ``slot_ref`` carries which half is due. The
+    row's NEW latent row (``new_ref``) is set into its tile of the ring in
+    VMEM, the tile goes back to the pool (``ring_out_ref`` is ``ring_ref``'s
+    own buffer), and the row attends the whole ring under `ring_live`'s
+    mask: one softmax, no chunks."""
+    i = pl.program_id(0)
+    n = n_ref[0]
+    _, W, _ = buf.shape
+    dt = buf.dtype
+    tile_rows = min(_RING_TILE, W)
+
+    def copy_in(r, slot):
+        return pltpu.make_async_copy(ring_ref.at[layer_ref[0], r],
+                                     buf.at[slot], sems.at[slot])
+
+    @pl.when(i < n)
+    def _():
+        r = rows_ref[i]
+        pos = pos_ref[r]
+
+        @pl.when(i == 0)
+        def _():
+            slot_ref[0] = 0
+            copy_in(r, 0).start()
+
+        slot = slot_ref[0]
+        copy_in(r, slot).wait()
+
+        @pl.when(i + 1 < n)
+        def _():
+            copy_in(rows_ref[i + 1], 1 - slot).start()
+
+        at = pos % W
+        t = pl.multiple_of(at // tile_rows * tile_rows, tile_rows)
+        tile = buf[slot, pl.ds(t, tile_rows)].astype(jnp.float32)
+        row = lax.broadcasted_iota(jnp.int32, tile.shape, 0)
+        buf[slot, pl.ds(t, tile_rows)] = jnp.where(
+            row == at - t, new_ref[0].astype(jnp.float32), tile).astype(dt)
+        back = pltpu.make_async_copy(
+            buf.at[slot, pl.ds(t, tile_rows)],
+            ring_out_ref.at[layer_ref[0], r, pl.ds(t, tile_rows)], back_sem)
+        back.start()
+        lat = buf[slot]                                     # [W, row]
+        s = lax.dot_general(q_ref[0], lat, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * sm_scale
+        w = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where((w <= pos) | (pos >= W - 1), s, MASKED)
+        p = jnp.exp(s - jnp.max(s, axis=1, keepdims=True))
+        o_ref[0] = jnp.dot(p.astype(dt), lat[:, :width],
+                           preferred_element_type=jnp.float32) \
+            / jnp.sum(p, axis=1, keepdims=True)
+        slot_ref[0] = 1 - slot
+        # before this half of ``buf`` is due again, and before the end
+        back.wait()
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "width",
+                                             "interpret"))
+def window_latent_attention(q, new_rows, ring, layer, positions, active, *,
+                            sm_scale, width, interpret=False):
+    """One window layer's cache write and decode attention of the latent
+    family, the ring pool read and written in place: ONE ``pallas_call``
+    named ``mx_window_latent_attn``. ``q`` ``(B, H, row)`` holds every
+    head's query against a whole ring row, ``new_rows`` ``(B, row)`` the
+    rows' new latent rows, ``ring`` ``(L, slots, W, row)`` stays in HBM and
+    is used at ``layer``; row ``b`` is slot ``b``, writes position
+    ``positions[b]`` at ring row ``positions[b] % W`` and attends the ring
+    rows `ring_live` names. Returns ``(u, ring)``: ``u`` ``(B, H, width)``
+    float32, the softmax-weighted sum of the rows' first ``width`` numbers
+    (an inactive row's is 0), and the ring with the active rows' new rows in
+    it (an inactive row writes nothing).
+
+    The grid walks the ACTIVE rows only (compacted through scalar prefetch,
+    as `paged_latent_attention`); a row copies its slot's ring into VMEM in
+    one piece and copies back the one tile of `_RING_TILE` rows (a ring
+    under that, whole) it changed.
+    The pool is aliased to the kernel's output and written by it alone:
+    three window layers' XLA scatters into one pool beside the kernels'
+    reads would be the chain of in-place updates the compiler may
+    rematerialise (PERF.md §6). Arithmetic: `softmax_fold`'s over one
+    piece (operands in the pool's dtype, float32 softmax). Jitted, though it
+    only ever runs inside a program: the layers share one trace."""
+    B, H, row_width = q.shape
+    W = ring.shape[2]
+    if W % min(_RING_TILE, W):
+        raise ValueError("a ring of %d rows is no whole number of %d-row "
+                         "tiles" % (W, _RING_TILE))
+    n = jnp.sum(active.astype(jnp.int32))
+    order = jnp.argsort(jnp.logical_not(active), stable=True).astype(
+        jnp.int32)
+    at = jnp.minimum(jnp.arange(B, dtype=jnp.int32), jnp.maximum(n - 1, 0))
+    rows = jnp.take(order, at)
+    row_block = lambda i, layer, rows, *_: (rows[i], 0, 0)      # noqa: E731
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4, grid=(B,),
+        in_specs=[pl.BlockSpec((1, H, row_width), row_block),
+                  pl.BlockSpec((1, 1, row_width), row_block), in_hbm],
+        out_specs=[pl.BlockSpec((1, H, width), row_block), in_hbm],
+        scratch_shapes=[pltpu.VMEM((2, W, row_width), ring.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SemaphoreType.DMA(()),
+                        pltpu.SMEM((1,), jnp.int32)])
+    params = None if interpret else pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",), has_side_effects=True)
+    u, ring = pl.pallas_call(
+        functools.partial(_window_attn_kernel, sm_scale=sm_scale,
+                          width=width),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, H, width), jnp.float32),
+                   jax.ShapeDtypeStruct(ring.shape, ring.dtype)],
+        # operands count the scalar-prefetch ones: 4 of them, q, new_rows
+        input_output_aliases={6: 1},
+        compiler_params=params, interpret=interpret,
+        name="mx_window_latent_attn")(
+            jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)), rows,
+            jnp.reshape(n, (1,)), positions.astype(jnp.int32),
+            q.astype(ring.dtype), new_rows.astype(ring.dtype)[:, None], ring)
+    return jnp.where(active[:, None, None], u, 0.0), ring
+
+
+def window_latent_attention_lax(q, new_rows, ring, layer, positions, active,
+                                *, sm_scale, width):
+    """`window_latent_attention` in ``jax.numpy``: the lax tier's, and the
+    kernel's reference. The same roundings (operands in the pool's dtype,
+    float32 scores and softmax); the ring comes back with the active rows'
+    new rows set by one scatter."""
+    B = q.shape[0]
+    W = ring.shape[2]
+    dt = ring.dtype
+    at = positions % W
+    b = jnp.arange(B)
+    lat = ring[layer]                                       # [B, W, row]
+    lat = jnp.where((jnp.arange(W)[None, :] == at[:, None])[..., None],
+                    new_rows.astype(dt)[:, None], lat)
+    s = jnp.einsum("bhc,bwc->bhw", q.astype(dt), lat,
+                   preferred_element_type=jnp.float32) * sm_scale
+    s = jnp.where(ring_live(positions, W)[:, None, :], s, MASKED)
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    u = jnp.einsum("bhw,bwr->bhr", p.astype(dt), lat[..., :width],
+                   preferred_element_type=jnp.float32) \
+        / jnp.sum(p, axis=-1)[..., None]
+    keep = ring[layer, b, at]
+    ring = ring.at[layer, b, at].set(
+        jnp.where(active[:, None], new_rows.astype(dt), keep))
+    return jnp.where(active[:, None, None], u, 0.0), ring

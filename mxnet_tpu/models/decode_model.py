@@ -27,7 +27,9 @@ the programs by the XLA module names ``jit_prefill_fn`` / ``jit_step_fn``.
 Clients: `transformer.TransformerDecodeModel`, `moe_mla.MoEMLADecodeModel`,
 `kimi_linear.KimiLinearDecodeModel` (the one with per-slot state),
 `evabyte.EvaByteDecodeModel` (the one with ``cache_pages``: a window of
-exact rows that are handed back, chunk summaries that stay) and
+exact rows that are handed back, chunk summaries that stay),
+`motif.MotifDecodeModel` (paged full layers beside window layers whose
+latent rows are a per-slot ring, four residual streams a token) and
 `tiny_lm.TinyLMDecodeModel` (the tests' single-layer fixture).
 """
 from __future__ import annotations

@@ -534,14 +534,9 @@ def _absorbed_attention(cfg, lp, q_nope, q_rope, pool, l, walk,
     """The decode step's attention in the latent space, over the live
     positions only. ``q_nope`` ``[B, H, dn]``, ``q_rope`` ``[B, H, dr]``,
     ``pool`` ``[L, blocks, bs, row]`` used at layer ``l``; ``walk`` is
-    `_step_walk`'s. Returns ``(out [B, H * dv], pool)``.
-
-    Lax tier (the CPU's, and the kernel's reference):
-    `paged_attention.live_walk` over a ``pool`` that HOLDS the rows' new
-    latent rows already; what is live at once is a piece's gathered latent
-    rows and scores. Kernel tier:
-    `paged_attention.paged_latent_attention` reads the pool's pages in
-    place and sets ``new_rows`` ``[B, row]`` into it itself."""
+    `_step_walk`'s. Returns ``(out [B, H * dv], pool)``: `_latent_attend`
+    between the up-projection moved onto the query and the one applied to
+    its result."""
     H, dn, dv, rkv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                       cfg.v_head_dim, cfg.kv_lora_rank)
     dt = pool.dtype
@@ -550,33 +545,49 @@ def _absorbed_attention(cfg, lp, q_nope, q_rope, pool, l, walk,
     # q'_h = q_nope_h W_kvb,k,h^T: the up-projection moves onto the query
     q_lat = jnp.einsum("bhn,rhn->bhr", q_nope.astype(dt), w_k,
                        preferred_element_type=jnp.float32)
-    qq = jnp.concatenate([q_lat, q_rope], -1).astype(dt)    # [B, H, rkv+dr]
-    qq = jnp.pad(qq, ((0, 0), (0, 0), (0, pool.shape[3] - qq.shape[-1])))
-    sm = 1.0 / _np.sqrt(dn + cfg.qk_rope_head_dim)
-
-    if isinstance(walk, paged.PagedRows):
-        u, pool = paged.paged_latent_attention(
-            qq, new_rows, pool, l, walk.positions, walk.tables, walk.active,
-            sm_scale=float(sm), width=rkv, interpret=walk.interpret)
-    else:
-        def rows_block(qq_b, pos_b, pieces_of):
-            def fold(carry, pieces, tpos):
-                lat, = pieces                       # [rb, span, row]
-                s = jnp.einsum("bhc,btc->bht", qq_b, lat,
-                               preferred_element_type=jnp.float32) * sm
-                return paged.softmax_fold(
-                    carry, s, tpos, pos_b, 2,
-                    lambda p: jnp.einsum("bht,btr->bhr", p.astype(dt),
-                                         lat[..., :rkv],
-                                         preferred_element_type=jnp.float32))
-
-            _, den, acc = pieces_of(fold, (qq_b.shape[0], H), rkv)
-            return acc / den[..., None]
-
-        u = paged.live_walk(walk, (pool,), l, qq, rows_block)
+    u, pool = _latent_attend(cfg, q_lat, q_rope, pool, l, walk, new_rows)
     o = jnp.einsum("bhr,rhv->bhv", u.astype(dt), w_v,
                    preferred_element_type=jnp.float32)
     return o.reshape(-1, H * dv), pool
+
+
+def _latent_attend(cfg, q_lat, q_rope, pool, l, walk, new_rows=None):
+    """Queries in the latent space, ``q_lat`` ``[B, H, rkv]`` beside
+    ``q_rope`` ``[B, H, dr]``, against the live latent rows of layer ``l``:
+    ``(u [B, H, rkv] float32, pool)``, ``u_h = sum_j p c(j)``.
+
+    Lax tier (the CPU's, and the kernel's reference):
+    `paged_attention.live_walk` over a ``pool`` that HOLDS the rows' new
+    latent rows already; what is live at once is a piece's gathered latent
+    rows and scores. Kernel tier:
+    `paged_attention.paged_latent_attention` reads the pool's pages in
+    place and sets ``new_rows`` ``[B, row]`` into it itself."""
+    H, rkv = q_lat.shape[1], cfg.kv_lora_rank
+    dt = pool.dtype
+    qq = jnp.concatenate([q_lat, q_rope], -1).astype(dt)    # [B, H, rkv+dr]
+    qq = jnp.pad(qq, ((0, 0), (0, 0), (0, pool.shape[3] - qq.shape[-1])))
+    sm = 1.0 / _np.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+
+    if isinstance(walk, paged.PagedRows):
+        return paged.paged_latent_attention(
+            qq, new_rows, pool, l, walk.positions, walk.tables, walk.active,
+            sm_scale=float(sm), width=rkv, interpret=walk.interpret)
+
+    def rows_block(qq_b, pos_b, pieces_of):
+        def fold(carry, pieces, tpos):
+            lat, = pieces                       # [rb, span, row]
+            s = jnp.einsum("bhc,btc->bht", qq_b, lat,
+                           preferred_element_type=jnp.float32) * sm
+            return paged.softmax_fold(
+                carry, s, tpos, pos_b, 2,
+                lambda p: jnp.einsum("bht,btr->bhr", p.astype(dt),
+                                     lat[..., :rkv],
+                                     preferred_element_type=jnp.float32))
+
+        _, den, acc = pieces_of(fold, (qq_b.shape[0], H), rkv)
+        return acc / den[..., None]
+
+    return paged.live_walk(walk, (pool,), l, qq, rows_block), pool
 
 
 def _step_attend(cfg, lp, h, pool, l, positions, blk, slot, walk):

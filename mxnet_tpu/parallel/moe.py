@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..kernels.grouped_experts import ROW_TILE, grouped_experts
+from ..kernels.grouped_experts import ROW_TILE, activate, grouped_experts
 
 __all__ = ["init_moe_ffn", "moe_ffn", "routed_experts"]
 
@@ -133,7 +133,7 @@ def _sum_by_token(ys, token, T):
 
 def routed_experts(params, x, *, held, top_k, scale, axis_name=None,
                    valid=None, buckets=(64, 256, 512), use_pallas=False,
-                   interpret=False):
+                   interpret=False, activation="silu"):
     """The routed part of an expert layer, for the experts held HERE.
 
     ``held = (first, count)`` names the experts whose weights ``params``
@@ -182,6 +182,12 @@ def routed_experts(params, x, *, held, top_k, scale, axis_name=None,
     Each token then gathers its own ``top_k`` weighted rows back and sums
     them (the same additions as a scatter-add, in a fixed order).
 
+    ``activation`` is the experts' (`kernels/grouped_experts.py`):
+    ``"silu"``, or ``("polynorm", eps, scale, clamp)`` with each held
+    expert's coefficients in ``params["experts_poly"]`` ``[count, 4]``; the
+    lax forms round the PolyNorm expert's gate and up products to ``x``'s
+    dtype before it, as the kernel hands them on.
+
     Returns ``(partial, counts, cost)``: ``partial`` ``[T, d]`` float32 is
     the part of ``sum_e w_e Expert_e(x)`` that the held experts give — what
     the absent experts would add is left out, not stood in for; ``counts``
@@ -228,7 +234,16 @@ def routed_experts(params, x, *, held, top_k, scale, axis_name=None,
         w = jnp.where(here, weight, 0.0)
     wg, wu, wd = (params["experts_gate"], params["experts_up"],
                   params["experts_down"])
+    coef = params.get("experts_poly")
     mm = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
+
+    def act(gate, up, c):
+        """The lax forms' ``act(gate) * up``; ``c``: the rows'
+        coefficients."""
+        if activation == "silu":
+            return jax.nn.silu(gate) * up
+        f32 = lambda t: t.astype(x.dtype).astype(jnp.float32)   # noqa: E731
+        return activate(activation, f32(gate), f32(up), c)
 
     def whole(n):
         """A form's static cost as the traced ones are typed: in a
@@ -247,7 +262,8 @@ def routed_experts(params, x, *, held, top_k, scale, axis_name=None,
             idx = starts[:, None] + jnp.arange(bucket)[None, :]
             xe = jnp.take(x, jnp.take(token_of, jnp.clip(idx, 0, rows - 1)),
                           axis=0)                            # [count, b, d]
-            h = jax.nn.silu(mm("ebd,edf->ebf", xe, wg)) * mm("ebd,edf->ebf", xe, wu)
+            h = act(mm("ebd,edf->ebf", xe, wg), mm("ebd,edf->ebf", xe, wu),
+                    None if coef is None else coef[:, None, :])
             ye = mm("ebf,efd->ebd", h.astype(x.dtype), wd)
             at = jnp.clip(local, 0, count - 1)
             slot = rank.reshape(T, top_k) - jnp.take(starts, at)
@@ -260,7 +276,9 @@ def routed_experts(params, x, *, held, top_k, scale, axis_name=None,
         xs = jnp.take(x, token_of, axis=0)                   # [T*k, d]
         dot = functools.partial(lax.ragged_dot, group_sizes=counts,
                                 preferred_element_type=jnp.float32)
-        h = jax.nn.silu(dot(xs, wg)) * dot(xs, wu)
+        h = act(dot(xs, wg), dot(xs, wu), None if coef is None else
+                jnp.take(coef, jnp.minimum(jnp.take(key, order), count - 1),
+                         axis=0))
         ys = dot(h.astype(x.dtype), wd)                      # [T*k, d] f32
         return (own(jnp.take(ys, rank, axis=0).reshape(T, top_k, d)),
                 whole(rows))
@@ -271,7 +289,8 @@ def routed_experts(params, x, *, held, top_k, scale, axis_name=None,
             at = jnp.take(order, jnp.minimum(r, rows - 1))   # assignments
             token = at // top_k
             ys, cost = grouped_experts(jnp.take(x, token, axis=0), counts,
-                                       wg, wu, wd, interpret=interpret)
+                                       wg, wu, wd, interpret=interpret,
+                                       activation=activation, coef=coef)
             # sorted rows past the held ones were never written
             live = r < total
             ys = jnp.where(live[:, None], ys, 0.0) \

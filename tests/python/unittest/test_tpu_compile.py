@@ -536,3 +536,58 @@ def test_evabyte_programs_write_each_pool_once_and_copy_none(one_chip,
         calls = [ln for ln in text.splitlines()
                  if "mx_eva_paged_attn" in ln and "custom-call(" in ln]
         assert len(calls) == model.cfg.num_hidden_layers
+
+
+def test_motif_step_writes_its_rings_in_place_and_rematerialises_no_pool(
+        one_chip):
+    """The Motif-3 cell's decode step at the served size (512 slots, tables
+    of 384 pages, the two full layers' latent pool and the three window
+    layers' rings), on the kernel tier. Each ring is written by its
+    `mx_window_latent_attn` call alone, a chain of aliased calls that takes
+    the pool whole and hands it on: no scatter of XLA's into it, and no
+    instruction the compiler rematerialised touches a donated pool (three
+    XLA scatters a step into one pool beside the kernels' reads are what
+    the one-write rule forbids). The full layers walk their pages in
+    `mx_paged_latent_attn`; both pools come back in place."""
+    import json
+    import os
+    from mxnet_tpu.models.motif import (MotifConfig, MotifDecodeModel,
+                                        init_motif)
+    cells = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
+                         "..", "..", "benchmark", "cells")
+    with open(os.path.join(cells, "configs", "motif3_ep8.json")) as f:
+        cfg = MotifConfig.from_dict(json.load(f))
+    with open(os.path.join(cells, "traffic",
+                           "decode_batch_reason.json")) as f:
+        e = json.load(f)["engine"]
+    params = jax.eval_shape(lambda: init_motif(cfg, jax.random.PRNGKey(0),
+                                               jnp.bfloat16))
+    model = MotifDecodeModel(cfg, params=params, flash="on")
+    on_chip = lambda t: jax.tree_util.tree_map(                 # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    B, bs = e["batch_size"], e["block_size"]
+    mb = e["max_seq_len"] // bs
+    cache = on_chip(model.cache_spec(e["num_blocks"], bs, B))
+    sd = lambda s, d: jax.ShapeDtypeStruct(s, d,               # noqa: E731
+                                           sharding=one_chip)
+    compiled = jax.jit(model.step_fn, donate_argnums=(1,)).lower(
+        on_chip(model.params), cache, sd((B,), jnp.int32),
+        sd((B,), jnp.int32), sd((B, mb), jnp.int32),
+        sd((B,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    pools = {k: "bf16[%s]" % ",".join(str(n) for n in p.shape)
+             for k, p in cache.items()}
+    lines = text.splitlines()
+    assert not [ln for ln in lines if ".remat" in ln.split("=")[0]
+                and any(p in ln for p in pools.values())]
+    calls = lambda name: [ln for ln in lines                    # noqa: E731
+                          if name in ln and "custom-call(" in ln]
+    assert len(calls("mx_window_latent_attn")) == cfg.window_layers == 3
+    assert len(calls("mx_paged_latent_attn")) == cfg.full_layers == 2
+    assert not [ln for ln in lines if any(p in ln for p in pools.values())
+                and ("scatter" in ln or " copy(" in ln)]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == sum(
+        int(np.prod(p.shape)) * 2 for p in cache.values())
+    assert mem.temp_size_in_bytes < 300 << 20
