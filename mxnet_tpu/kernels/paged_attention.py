@@ -213,6 +213,17 @@ _CHUNK_VMEM = 4 << 20
 #: its issue cost falls with it.
 _COPY_GROUP = 8
 
+#: Rows in a block of the two latent kernels' queries and results: a
+#: heads-major block ``[H, rows, w]`` spans whole tiles of its rows' axis
+#: (16 bfloat16 rows). The grid still takes one row a step.
+_ROW_GROUP = 16
+
+#: VMEM the two latent kernels may use: their chunk or ring buffers, the
+#: double-buffered blocks of `_ROW_GROUP` rows' queries and results and, for
+#: heads-major blocks, `_each_row`'s float32 copies of them (about 18 MB at
+#: Motif's 80 heads, over the v5e's default of 16 MB).
+_LATENT_VMEM = 48 << 20
+
 
 def chunk_pages(heads, mb, block_size, width, itemsize):
     """Pages a chunk of `paged_latent_attention`'s walk: the largest power
@@ -225,23 +236,128 @@ def chunk_pages(heads, mb, block_size, width, itemsize):
     return pages
 
 
-def _latent_attn_kernel(layer_ref, rows_ref, n_ref, pos_ref, tab_ref, q_ref,
-                        new_ref, pool_ref, o_ref, pool_out_ref, buf, sems,
-                        back_sem, slot_ref, m_ref, den_ref, acc_ref, *,
-                        sm_scale, width, mb):
-    """Grid step ``i``: the ``i``-th ACTIVE row. Its pages arrive a chunk
-    at a time in ``buf`` ``[2, pages, block_size, row]``, the next chunk's
-    copies (the next row's first, at a row's last) started before this
-    chunk's products; ``slot_ref`` carries which half is due from row to
-    row. The row's NEW latent row (``new_ref``) is set into its last page
-    as that page passes through VMEM, and the page is copied back to the
-    pool (``pool_out_ref`` is ``pool_ref``'s own buffer). Inside a chunk:
-    `softmax_fold`'s arithmetic."""
-    i = pl.program_id(0)
-    n = n_ref[0]
+def _row_chain(active):
+    """The rows as the two latent kernels walk them, for scalar memory:
+    ``(act, first, after)``, int32: ``act`` ``(B,)`` 1 for an active row,
+    ``first`` ``(1,)`` the first active row and ``after`` ``(B,)`` the next
+    active row after each (``B`` where there is none). A row's copies for
+    the next active row start while it still computes."""
+    B = active.shape[0]
+    at = jnp.where(active, jnp.arange(B, dtype=jnp.int32), B)
+    ahead = lax.cummin(at, axis=0, reverse=True)    # first active from b on
+    return (active.astype(jnp.int32), ahead[:1],
+            jnp.concatenate([ahead[1:], jnp.full((1,), B, jnp.int32)]))
+
+
+def _row_blocks(B, H, width, dr, heads_major):
+    """``(R, q, q_rope, u)``: the rows of a block of the two latent kernels'
+    queries and results, and their BlockSpecs over the grid ``(blocks,
+    R)``. ``q`` and ``u`` come in blocks of ``R`` rows of ``[B, H, w]``, or
+    heads-major ``[H, B, w]`` whose rows' axis a block spans whole tiles of
+    (16 bfloat16 rows, twice 8 float32 ones) or the whole batch; ``q_rope``
+    ``[B, H, dr]`` in blocks of rows either way (the rotary part leaves its
+    projection rows-major). A block's index does not change over its ``R``
+    grid steps: it is fetched once and written back once."""
+    R = min(_ROW_GROUP, B)
+    rows = lambda w: pl.BlockSpec((R, H, w),                # noqa: E731
+                                  lambda g, j, *_: (g, 0, 0))
+    if heads_major:
+        heads = pl.BlockSpec((H, R, width), lambda g, j, *_: (0, g, 0))
+        return R, heads, rows(dr), heads
+    return R, rows(width), rows(dr), rows(width)
+
+
+def _each_row(B, act_ref, q_ref, qr_ref, o_ref, forms, attend):
+    """Grid step ``(g, j)`` of a latent kernel: row ``b = g R + j``, ``R``
+    the rows of a block. ``attend(b, j, q, q_rope)`` gives an active row's
+    result ``[H, width]`` float32 from its queries; an inactive row's result
+    is exact zeros, and a row past ``B`` (a last block's padding) does
+    nothing. ``forms`` is ``None`` for blocks of rows. For heads-major
+    blocks it is two float32 VMEM buffers ``[width / 128, H R, 128]`` (a
+    block's 128-lane columns, its heads and rows merged; a width under 128,
+    one column): the block's latent queries go into the one at its first
+    step, and a row's ``[H, 128]`` pieces are read from it with a stride of
+    ``R`` rows (a sublane-strided load, which takes 32-bit numbers and a
+    128-lane buffer); its results go into the other so, and to the block at
+    its last step."""
+    g, j = pl.program_id(0), pl.program_id(1)
+    R = qr_ref.shape[0]
+    b = g * R + j
+    if forms is None:
+        q_row = lambda: q_ref[j]                            # noqa: E731
+
+        def put(u):
+            o_ref[j] = u.astype(o_ref.dtype)
+    else:
+        qs, res = forms
+        H = q_ref.shape[0]
+        cols, lanes = range(qs.shape[0]), qs.shape[2]
+
+        @pl.when(j == 0)
+        def _():
+            q = q_ref[...].astype(jnp.float32)
+            for c in cols:
+                qs[c] = q[:, :, c * lanes:(c + 1) * lanes].reshape(
+                    H * R, lanes)
+
+        def q_row():
+            return jnp.concatenate([qs[c, pl.ds(j, H, stride=R)]
+                                    for c in cols], 1).astype(q_ref.dtype)
+
+        def put(u):
+            for c in cols:
+                res[c, pl.ds(j, H, stride=R)] = u[:, c * lanes:
+                                                  (c + 1) * lanes]
+
+    live = b < B
+    active = act_ref[jnp.minimum(b, B - 1)] != 0
+
+    @pl.when(live & active)
+    def _():
+        put(attend(b, j, q_row(), qr_ref[j]))
+
+    @pl.when(live & jnp.logical_not(active))
+    def _():
+        put(jnp.zeros((qr_ref.shape[1], o_ref.shape[2]), jnp.float32))
+
+    if forms is not None:
+        @pl.when(j == R - 1)
+        def _():
+            for c in cols:
+                o_ref[:, :, c * lanes:(c + 1) * lanes] = res[c].reshape(
+                    H, R, lanes).astype(o_ref.dtype)
+
+
+def _latent_scores(q, q_rope, lat, sm_scale):
+    """``[H, positions]`` float32 scores of the latent queries ``q`` ``[H,
+    rkv]`` and ``q_rope`` ``[H, dr]`` against rows ``lat`` ``[positions,
+    row]`` = ``[c | k_rope | 0]``: two products, one a part of the row."""
+    rkv, dr = q.shape[1], q_rope.shape[1]
+    nt = (((1,), (1,)), ((), ()))
+    return (lax.dot_general(q, lat[:, :rkv], nt,
+                            preferred_element_type=jnp.float32)
+            + lax.dot_general(q_rope, lat[:, rkv:rkv + dr], nt,
+                              preferred_element_type=jnp.float32)) * sm_scale
+
+
+def _latent_attn_kernel(layer_ref, act_ref, first_ref, after_ref, pos_ref,
+                        tab_ref, q_ref, qr_ref, new_ref, pool_ref, o_ref,
+                        pool_out_ref, buf, sems, back_sem, slot_ref, m_ref,
+                        den_ref, acc_ref, *forms, sm_scale, mb):
+    """Grid step ``g``: `_each_row` over rows ``g R ..``. An active row's
+    pages arrive a chunk at a time in ``buf`` ``[2, pages, block_size,
+    row]``, the next chunk's copies (the next ACTIVE row's first, at a
+    row's last) started before this chunk's products; ``slot_ref`` carries
+    which half is due from row to row. The row's NEW latent row
+    (``new_ref``) is set into its last page as that page passes through
+    VMEM, and the page is copied back to the pool (``pool_out_ref`` is
+    ``pool_ref``'s own buffer). Inside a chunk: `softmax_fold`'s
+    arithmetic."""
+    B = act_ref.shape[0]
     _, pages, bs, row_width = buf.shape
     span = pages * bs
     dt = buf.dtype
+    width = acc_ref.shape[1]
 
     group = min(pages, _COPY_GROUP)
 
@@ -285,13 +401,11 @@ def _latent_attn_kernel(layer_ref, rows_ref, n_ref, pos_ref, tab_ref, q_ref,
         chunk_copies(r, c, lambda j: pages_at(j, group),
                      lambda j: pages_at(j, 1))
 
-    @pl.when(i < n)
-    def _():
-        r = rows_ref[i]
+    def attend(r, j, q, q_rope):
         pos = pos_ref[r]
         chunks = pos // span + 1
 
-        @pl.when(i == 0)
+        @pl.when(r == first_ref[0])
         def _():
             # a partly filled chunk leaves the rows of an earlier one
             # behind it (finite, masked to weight 0): never VMEM as found
@@ -303,12 +417,10 @@ def _latent_attn_kernel(layer_ref, rows_ref, n_ref, pos_ref, tab_ref, q_ref,
         m_ref[...] = jnp.full_like(m_ref, MASKED)
         den_ref[...] = jnp.zeros_like(den_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        q = q_ref[0]                                    # [H, row]
 
         def fold(c, slot):
             lat = buf[slot].reshape(span, row_width)
-            s = lax.dot_general(q, lat, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
+            s = _latent_scores(q, q_rope, lat, sm_scale)
             tpos = c * span + lax.broadcasted_iota(jnp.int32, s.shape, 1)
             s = jnp.where(tpos <= pos, s, MASKED)
             m = m_ref[...]
@@ -333,103 +445,140 @@ def _latent_attn_kernel(layer_ref, rows_ref, n_ref, pos_ref, tab_ref, q_ref,
 
         # the row's last chunk holds the position this step writes: the new
         # row goes into its page here, and the page back to the pool ahead
-        # of the next row's first copies
+        # of the next active row's first copies
         c = chunks - 1
         slot = (first_slot + c) % 2
         wait(r, c, slot)
-        j = pos // bs - c * pages
-        page = buf[slot, j].astype(jnp.float32)
+        k = pos // bs - c * pages
+        page = buf[slot, k].astype(jnp.float32)
         at = lax.broadcasted_iota(jnp.int32, page.shape, 0)
-        buf[slot, j] = jnp.where(at == pos % bs,
-                                 new_ref[0].astype(jnp.float32),
+        buf[slot, k] = jnp.where(at == pos % bs,
+                                 new_ref[j].astype(jnp.float32),
                                  page).astype(dt)
         back = pltpu.make_async_copy(
-            buf.at[slot, j],
+            buf.at[slot, k],
             pool_out_ref.at[layer_ref[0], tab_ref[r * mb + pos // bs]],
             back_sem)
         back.start()
 
-        @pl.when(i + 1 < n)
+        @pl.when(after_ref[r] < B)
         def _():
-            start(rows_ref[i + 1], 0, 1 - slot)
+            start(after_ref[r], 0, 1 - slot)
 
         fold(c, slot)
         slot_ref[0] = (first_slot + chunks) % 2
-        o_ref[0] = acc_ref[...] / den_ref[...]
         # before this half of ``buf`` is due again, and before the end
         back.wait()
+        return acc_ref[...] / den_ref[...]
+
+    _each_row(B, act_ref, q_ref, qr_ref, o_ref, forms or None, attend)
 
 
-@functools.partial(jax.jit, static_argnames=("sm_scale", "width",
-                                             "interpret"))
-def paged_latent_attention(q, new_rows, pool, layer, positions, tables,
-                           active, *, sm_scale, width, interpret=False):
-    """One layer's cache write and decode attention of the latent family,
-    the pool read and written in place: ONE ``pallas_call`` named
-    ``mx_paged_latent_attn``. ``q`` ``(B, H, row)`` holds every head's
-    query against a whole pool row, ``new_rows`` ``(B, row)`` the rows'
-    new latent rows, ``pool`` ``(L, blocks, block_size, row)`` stays in HBM
-    and is used at ``layer``; row ``b`` writes position ``positions[b]`` of
-    ``tables[b]`` and attends positions ``0 .. positions[b]``. Returns
-    ``(u, pool)``: ``u`` ``(B, H, width)`` float32, the softmax-weighted
-    sum of the rows' first ``width`` numbers (an inactive row's is 0), and
-    the pool with the active rows' new rows in it (an inactive row writes
-    nothing).
-
-    The grid walks the ACTIVE rows only (compacted through scalar prefetch
-    as `kda._step_pallas` does); a row copies its own pages ``0 ..
-    positions[b] // block_size`` and no further, each one contiguous copy
-    into VMEM, and no gathered piece is ever written to HBM. The pool is
-    aliased to the kernel's output and written by it alone, a page a row:
-    a chain of in-place updates in XLA's hands beside the kernel's reads
-    was rematerialised at the Kimi-Linear cell's sizes (PERF.md, PR 35; PR
-    34 for what that can do). Arithmetic: `softmax_fold`'s, a chunk of
-    `chunk_pages` pages a fold (operands in the pool's dtype, float32
-    accumulation and softmax). Jitted, though it only ever runs inside a
-    program: the layers share one trace."""
-    B, H, row_width = q.shape
-    bs = pool.shape[2]
-    mb = tables.shape[1]
-    pages = chunk_pages(H, mb, bs, row_width, pool.dtype.itemsize)
-    n = jnp.sum(active.astype(jnp.int32))
-    order = jnp.argsort(jnp.logical_not(active), stable=True).astype(
-        jnp.int32)
-    at = jnp.minimum(jnp.arange(B, dtype=jnp.int32), jnp.maximum(n - 1, 0))
-    rows = jnp.take(order, at)
-    row_block = lambda i, layer, rows, *_: (rows[i], 0, 0)      # noqa: E731
+def _latent_call(kernel, name, layer, prefetch, q_lat, q_rope, new_rows,
+                 pool, active, scratch, heads_major, out_dtype, interpret):
+    """ONE ``pallas_call`` of a latent kernel, named ``name``, over the grid
+    ``(blocks, R)`` of `_row_blocks`: its scalar-prefetch operands are
+    ``layer``, `_row_chain`'s three and ``prefetch``; then ``q_lat``,
+    ``q_rope`` and ``new_rows`` in blocks of rows, and ``pool`` in HBM,
+    aliased to the second result. ``scratch`` goes before `_each_row`'s
+    VMEM. Returns ``(u, pool)``."""
+    dt = pool.dtype
+    B, H = q_rope.shape[:2]
+    width = q_lat.shape[-1]
+    if q_lat.shape[:2] != ((H, B) if heads_major else (B, H)):
+        raise ValueError("q_lat %s is not %s for q_rope %s"
+                         % (q_lat.shape, "heads-major" if heads_major
+                            else "rows-major", q_rope.shape))
+    forms = []
+    if heads_major:
+        lanes = 128 if width % 128 == 0 else width
+        forms = [pltpu.VMEM((width // lanes, H * min(_ROW_GROUP, B), lanes),
+                            jnp.float32)] * 2
+    R, q_spec, qr_spec, o_spec = _row_blocks(B, H, width, q_rope.shape[-1],
+                                             heads_major)
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5, grid=(B,),
-        in_specs=[pl.BlockSpec((1, H, row_width), row_block),
-                  pl.BlockSpec((1, 1, row_width), row_block), in_hbm],
-        out_specs=[pl.BlockSpec((1, H, width), row_block), in_hbm],
-        scratch_shapes=[pltpu.VMEM((2, pages, bs, row_width), pool.dtype),
-                        pltpu.SemaphoreType.DMA((2,)),
-                        pltpu.SemaphoreType.DMA(()),
-                        pltpu.SMEM((1,), jnp.int32),
-                        pltpu.VMEM((H, 1), jnp.float32),
-                        pltpu.VMEM((H, 1), jnp.float32),
-                        pltpu.VMEM((H, width), jnp.float32)])
+        num_scalar_prefetch=4 + len(prefetch), grid=(pl.cdiv(B, R), R),
+        in_specs=[q_spec, qr_spec,
+                  pl.BlockSpec((R, 1, pool.shape[-1]),
+                               lambda g, j, *_: (g, 0, 0)),
+                  in_hbm],
+        out_specs=[o_spec, in_hbm],
+        scratch_shapes=list(scratch) + forms)
     # (side effects: the call writes the pool; XLA neither drops it nor
     # makes it twice)
     params = None if interpret else pltpu.CompilerParams(
-        dimension_semantics=("arbitrary",), has_side_effects=True)
-    u, pool = pl.pallas_call(
-        functools.partial(_latent_attn_kernel, sm_scale=sm_scale,
-                          width=width, mb=mb),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, H, width), jnp.float32),
-                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
-        # operands count the scalar-prefetch ones: 5 of them, q, new_rows
-        input_output_aliases={7: 1},
-        compiler_params=params, interpret=interpret,
-        name="mx_paged_latent_attn")(
-            jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)), rows,
-            jnp.reshape(n, (1,)), positions.astype(jnp.int32),
-            tables.astype(jnp.int32).reshape(-1), q.astype(pool.dtype),
-            new_rows.astype(pool.dtype)[:, None], pool)
-    # (the kernel leaves an inactive row's block unwritten)
-    return jnp.where(active[:, None, None], u, 0.0), pool
+        dimension_semantics=("arbitrary", "arbitrary"),
+        has_side_effects=True, vmem_limit_bytes=_LATENT_VMEM)
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(
+            q_lat.shape, dt if out_dtype is None else out_dtype),
+                   jax.ShapeDtypeStruct(pool.shape, dt)],
+        # operands count the scalar-prefetch ones, then q_lat, q_rope and
+        # new_rows
+        input_output_aliases={7 + len(prefetch): 1},
+        compiler_params=params, interpret=interpret, name=name)(
+            jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)),
+            *_row_chain(active), *prefetch, q_lat.astype(dt),
+            q_rope.astype(dt), new_rows.astype(dt)[:, None], pool)
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "heads_major",
+                                             "out_dtype", "interpret"))
+def paged_latent_attention(q_lat, q_rope, new_rows, pool, layer, positions,
+                           tables, active, *, sm_scale, heads_major=False,
+                           out_dtype=None, interpret=False):
+    """One layer's cache write and decode attention of the latent family,
+    the pool read and written in place: ONE ``pallas_call`` named
+    ``mx_paged_latent_attn``. ``q_lat`` holds every head's latent query
+    (``rkv`` wide, against the first ``rkv`` numbers of a pool row) and
+    ``q_rope`` ``(B, H, dr)`` its rotary part (against the ``dr`` numbers
+    after them), both cast to the pool's dtype; ``new_rows`` ``(B, row)``
+    the rows' new latent rows; ``pool`` ``(L, blocks, block_size, row)``
+    stays in HBM and is used at ``layer``. Row ``b`` writes position
+    ``positions[b]`` of ``tables[b]`` and attends positions ``0 ..
+    positions[b]``. Returns ``(u, pool)``: ``u`` the softmax-weighted sum of
+    the rows' first ``rkv`` numbers in ``out_dtype`` (the pool's by
+    default; an inactive row's is exact zeros, written by the kernel), and
+    the pool with the active rows' new rows in it (an inactive row writes
+    nothing).
+
+    The CALLER chooses the form of ``q_lat`` and of ``u`` to be the one its
+    neighbouring products make and take, so that no XLA pass sits between
+    them and the kernel: rows-major ``(B, H, rkv)`` (the latent family's
+    per-head ``bhn,rhn->bhr`` and ``bhr,rhv->bhv``), or with
+    ``heads_major`` ``(H, B, rkv)`` (Motif's grouped ``gsbn,rgn->gsbr`` and
+    ``gsbr,rgv->bgsv``); ``q_rope`` leaves its projection rows-major and is
+    taken so in both. It chooses ``out_dtype`` as its consumer reads ``u``.
+
+    The grid walks the rows in order, one a step, their queries and results
+    in blocks of `_ROW_GROUP` rows; an inactive row skips its copies and
+    products. A row copies its own pages ``0 ..
+    positions[b] // block_size`` and no further, each one contiguous copy
+    into VMEM, and no gathered piece is ever written to HBM; the next
+    active row's first copies start under a row's last products. The pool
+    is aliased to the kernel's output and written by it alone, a page a
+    row: a chain of in-place updates in XLA's hands beside the kernel's
+    reads was rematerialised at the Kimi-Linear cell's sizes (PERF.md §6,
+    with what that can do). Arithmetic: `softmax_fold`'s, a chunk of
+    `chunk_pages` pages a fold (operands in the pool's dtype, float32
+    accumulation and softmax). Jitted, though it only ever runs inside a
+    program: the layers share one trace."""
+    H, row_width = q_rope.shape[1], pool.shape[3]
+    bs, mb = pool.shape[2], tables.shape[1]
+    pages = chunk_pages(H, mb, bs, row_width, pool.dtype.itemsize)
+    return _latent_call(
+        functools.partial(_latent_attn_kernel, sm_scale=sm_scale, mb=mb),
+        "mx_paged_latent_attn", layer,
+        (positions.astype(jnp.int32), tables.astype(jnp.int32).reshape(-1)),
+        q_lat, q_rope, new_rows, pool, active,
+        [pltpu.VMEM((2, pages, bs, row_width), pool.dtype),
+         pltpu.SemaphoreType.DMA((2,)), pltpu.SemaphoreType.DMA(()),
+         pltpu.SMEM((1,), jnp.int32), pltpu.VMEM((H, 1), jnp.float32),
+         pltpu.VMEM((H, 1), jnp.float32),
+         pltpu.VMEM((H, q_lat.shape[-1]), jnp.float32)],
+        heads_major, out_dtype, interpret)
 
 
 def paged_walked(positions, active, block_size):
@@ -690,18 +839,17 @@ def ring_live(positions, width):
     return (w <= p) | (p >= width - 1)
 
 
-def _window_attn_kernel(layer_ref, rows_ref, n_ref, pos_ref, q_ref, new_ref,
-                        ring_ref, o_ref, ring_out_ref, buf, sems, back_sem,
-                        slot_ref, *, sm_scale, width):
-    """Grid step ``i``: the ``i``-th ACTIVE row. Its slot's ring arrives in
-    one copy in a half of ``buf`` ``[2, W, row]``, the next row's started as
-    soon as this one has landed; ``slot_ref`` carries which half is due. The
-    row's NEW latent row (``new_ref``) is set into its tile of the ring in
-    VMEM, the tile goes back to the pool (``ring_out_ref`` is ``ring_ref``'s
-    own buffer), and the row attends the whole ring under `ring_live`'s
-    mask: one softmax, no chunks."""
-    i = pl.program_id(0)
-    n = n_ref[0]
+def _window_attn_kernel(layer_ref, act_ref, first_ref, after_ref, pos_ref,
+                        q_ref, qr_ref, new_ref, ring_ref, o_ref, ring_out_ref,
+                        buf, sems, back_sem, slot_ref, *forms, sm_scale):
+    """Grid step ``g``: `_each_row` over rows (slots) ``g R ..``. An active
+    row's ring arrives in one copy in a half of ``buf`` ``[2, W, row]``, the
+    next ACTIVE row's started as soon as this one has landed; ``slot_ref``
+    carries which half is due. The row's NEW latent row (``new_ref``) is set
+    into its tile of the ring in VMEM, the tile goes back to the pool
+    (``ring_out_ref`` is ``ring_ref``'s own buffer), and the row attends the
+    whole ring under `ring_live`'s mask: one softmax, no chunks."""
+    B = act_ref.shape[0]
     _, W, _ = buf.shape
     dt = buf.dtype
     tile_rows = min(_RING_TILE, W)
@@ -710,12 +858,10 @@ def _window_attn_kernel(layer_ref, rows_ref, n_ref, pos_ref, q_ref, new_ref,
         return pltpu.make_async_copy(ring_ref.at[layer_ref[0], r],
                                      buf.at[slot], sems.at[slot])
 
-    @pl.when(i < n)
-    def _():
-        r = rows_ref[i]
+    def attend(r, j, q, q_rope):
         pos = pos_ref[r]
 
-        @pl.when(i == 0)
+        @pl.when(r == first_ref[0])
         def _():
             slot_ref[0] = 0
             copy_in(r, 0).start()
@@ -723,97 +869,79 @@ def _window_attn_kernel(layer_ref, rows_ref, n_ref, pos_ref, q_ref, new_ref,
         slot = slot_ref[0]
         copy_in(r, slot).wait()
 
-        @pl.when(i + 1 < n)
+        @pl.when(after_ref[r] < B)
         def _():
-            copy_in(rows_ref[i + 1], 1 - slot).start()
+            copy_in(after_ref[r], 1 - slot).start()
 
         at = pos % W
         t = pl.multiple_of(at // tile_rows * tile_rows, tile_rows)
         tile = buf[slot, pl.ds(t, tile_rows)].astype(jnp.float32)
         row = lax.broadcasted_iota(jnp.int32, tile.shape, 0)
         buf[slot, pl.ds(t, tile_rows)] = jnp.where(
-            row == at - t, new_ref[0].astype(jnp.float32), tile).astype(dt)
+            row == at - t, new_ref[j].astype(jnp.float32), tile).astype(dt)
         back = pltpu.make_async_copy(
             buf.at[slot, pl.ds(t, tile_rows)],
             ring_out_ref.at[layer_ref[0], r, pl.ds(t, tile_rows)], back_sem)
         back.start()
         lat = buf[slot]                                     # [W, row]
-        s = lax.dot_general(q_ref[0], lat, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
+        s = _latent_scores(q, q_rope, lat, sm_scale)
         w = lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where((w <= pos) | (pos >= W - 1), s, MASKED)
         p = jnp.exp(s - jnp.max(s, axis=1, keepdims=True))
-        o_ref[0] = jnp.dot(p.astype(dt), lat[:, :width],
-                           preferred_element_type=jnp.float32) \
+        u = jnp.dot(p.astype(dt), lat[:, :q.shape[1]],
+                    preferred_element_type=jnp.float32) \
             / jnp.sum(p, axis=1, keepdims=True)
         slot_ref[0] = 1 - slot
         # before this half of ``buf`` is due again, and before the end
         back.wait()
+        return u
+
+    _each_row(B, act_ref, q_ref, qr_ref, o_ref, forms or None, attend)
 
 
-@functools.partial(jax.jit, static_argnames=("sm_scale", "width",
-                                             "interpret"))
-def window_latent_attention(q, new_rows, ring, layer, positions, active, *,
-                            sm_scale, width, interpret=False):
+@functools.partial(jax.jit, static_argnames=("sm_scale", "heads_major",
+                                             "out_dtype", "interpret"))
+def window_latent_attention(q_lat, q_rope, new_rows, ring, layer, positions,
+                            active, *, sm_scale, heads_major=False,
+                            out_dtype=None, interpret=False):
     """One window layer's cache write and decode attention of the latent
     family, the ring pool read and written in place: ONE ``pallas_call``
-    named ``mx_window_latent_attn``. ``q`` ``(B, H, row)`` holds every
-    head's query against a whole ring row, ``new_rows`` ``(B, row)`` the
-    rows' new latent rows, ``ring`` ``(L, slots, W, row)`` stays in HBM and
-    is used at ``layer``; row ``b`` is slot ``b``, writes position
+    named ``mx_window_latent_attn``. ``q_lat`` and ``q_rope`` are the
+    latent and rotary queries as `paged_latent_attention` takes them:
+    ``q_lat`` in the form the CALLER chooses (rows-major ``(B, H, rkv)`` or,
+    with ``heads_major``, ``(H, B, rkv)``), ``q_rope`` ``(B, H, dr)``; ``u``
+    comes back in ``q_lat``'s form, in ``out_dtype`` (the ring's by
+    default). ``new_rows`` ``(B, row)`` holds the rows' new latent rows,
+    ``ring`` ``(L, slots, W, row)`` stays in HBM and is used at ``layer``;
+    row ``b`` is slot ``b``, writes position
     ``positions[b]`` at ring row ``positions[b] % W`` and attends the ring
-    rows `ring_live` names. Returns ``(u, ring)``: ``u`` ``(B, H, width)``
-    float32, the softmax-weighted sum of the rows' first ``width`` numbers
-    (an inactive row's is 0), and the ring with the active rows' new rows in
-    it (an inactive row writes nothing).
+    rows `ring_live` names. Returns ``(u, ring)``: ``u`` the
+    softmax-weighted sum of the rows' first ``rkv`` numbers (an inactive
+    row's is exact zeros, written by the kernel), and the ring with the
+    active rows' new rows in it (an inactive row writes nothing).
 
-    The grid walks the ACTIVE rows only (compacted through scalar prefetch,
-    as `paged_latent_attention`); a row copies its slot's ring into VMEM in
-    one piece and copies back the one tile of `_RING_TILE` rows (a ring
-    under that, whole) it changed.
-    The pool is aliased to the kernel's output and written by it alone:
-    three window layers' XLA scatters into one pool beside the kernels'
-    reads would be the chain of in-place updates the compiler may
-    rematerialise (PERF.md §6). Arithmetic: `softmax_fold`'s over one
-    piece (operands in the pool's dtype, float32 softmax). Jitted, though it
-    only ever runs inside a program: the layers share one trace."""
-    B, H, row_width = q.shape
-    W = ring.shape[2]
+    The grid walks the rows in order as `paged_latent_attention`'s does;
+    an active row copies its slot's ring
+    into VMEM in one piece and copies back the one tile of `_RING_TILE`
+    rows (a ring under that, whole) it changed. The pool is aliased to the
+    kernel's output and written by it alone: three window layers' XLA
+    scatters into one pool beside the kernels' reads would be the chain of
+    in-place updates the compiler may rematerialise (PERF.md §6).
+    Arithmetic: `softmax_fold`'s over one piece (operands in the pool's
+    dtype, float32 softmax). Jitted, though it only ever runs inside a
+    program: the layers share one trace."""
+    W, row_width = ring.shape[2:]
     if W % min(_RING_TILE, W):
         raise ValueError("a ring of %d rows is no whole number of %d-row "
                          "tiles" % (W, _RING_TILE))
-    n = jnp.sum(active.astype(jnp.int32))
-    order = jnp.argsort(jnp.logical_not(active), stable=True).astype(
-        jnp.int32)
-    at = jnp.minimum(jnp.arange(B, dtype=jnp.int32), jnp.maximum(n - 1, 0))
-    rows = jnp.take(order, at)
-    row_block = lambda i, layer, rows, *_: (rows[i], 0, 0)      # noqa: E731
-    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4, grid=(B,),
-        in_specs=[pl.BlockSpec((1, H, row_width), row_block),
-                  pl.BlockSpec((1, 1, row_width), row_block), in_hbm],
-        out_specs=[pl.BlockSpec((1, H, width), row_block), in_hbm],
-        scratch_shapes=[pltpu.VMEM((2, W, row_width), ring.dtype),
-                        pltpu.SemaphoreType.DMA((2,)),
-                        pltpu.SemaphoreType.DMA(()),
-                        pltpu.SMEM((1,), jnp.int32)])
-    params = None if interpret else pltpu.CompilerParams(
-        dimension_semantics=("arbitrary",), has_side_effects=True)
-    u, ring = pl.pallas_call(
-        functools.partial(_window_attn_kernel, sm_scale=sm_scale,
-                          width=width),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, H, width), jnp.float32),
-                   jax.ShapeDtypeStruct(ring.shape, ring.dtype)],
-        # operands count the scalar-prefetch ones: 4 of them, q, new_rows
-        input_output_aliases={6: 1},
-        compiler_params=params, interpret=interpret,
-        name="mx_window_latent_attn")(
-            jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)), rows,
-            jnp.reshape(n, (1,)), positions.astype(jnp.int32),
-            q.astype(ring.dtype), new_rows.astype(ring.dtype)[:, None], ring)
-    return jnp.where(active[:, None, None], u, 0.0), ring
+    return _latent_call(
+        functools.partial(_window_attn_kernel, sm_scale=sm_scale),
+        "mx_window_latent_attn", layer, (positions.astype(jnp.int32),),
+        q_lat, q_rope, new_rows, ring, active,
+        [pltpu.VMEM((2, W, row_width), ring.dtype),
+         pltpu.SemaphoreType.DMA((2,)), pltpu.SemaphoreType.DMA(()),
+         pltpu.SMEM((1,), jnp.int32)],
+        heads_major, out_dtype, interpret)
 
 
 def window_latent_attention_lax(q, new_rows, ring, layer, positions, active,

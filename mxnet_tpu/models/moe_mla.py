@@ -551,27 +551,35 @@ def _absorbed_attention(cfg, lp, q_nope, q_rope, pool, l, walk,
     return o.reshape(-1, H * dv), pool
 
 
+def _pool_query(q_lat, q_rope, pool):
+    """``[q_lat | q_rope | 0]`` ``[B, H, row]`` in the pool's dtype: one
+    query against a whole pool row, as the lax tier scores it."""
+    qq = jnp.concatenate([q_lat, q_rope], -1).astype(pool.dtype)
+    return jnp.pad(qq, ((0, 0), (0, 0), (0, pool.shape[-1] - qq.shape[-1])))
+
+
 def _latent_attend(cfg, q_lat, q_rope, pool, l, walk, new_rows=None):
     """Queries in the latent space, ``q_lat`` ``[B, H, rkv]`` beside
     ``q_rope`` ``[B, H, dr]``, against the live latent rows of layer ``l``:
-    ``(u [B, H, rkv] float32, pool)``, ``u_h = sum_j p c(j)``.
+    ``(u [B, H, rkv], pool)``, ``u_h = sum_j p c(j)``.
 
     Lax tier (the CPU's, and the kernel's reference):
     `paged_attention.live_walk` over a ``pool`` that HOLDS the rows' new
     latent rows already; what is live at once is a piece's gathered latent
-    rows and scores. Kernel tier:
+    rows and scores; ``u`` float32. Kernel tier:
     `paged_attention.paged_latent_attention` reads the pool's pages in
-    place and sets ``new_rows`` ``[B, row]`` into it itself."""
+    place and sets ``new_rows`` ``[B, row]`` into it itself; it takes the
+    queries rows-major as they are and writes ``u`` in the pool's dtype,
+    what the per-head ``bhr,rhv->bhv`` after it reads."""
     H, rkv = q_lat.shape[1], cfg.kv_lora_rank
     dt = pool.dtype
-    qq = jnp.concatenate([q_lat, q_rope], -1).astype(dt)    # [B, H, rkv+dr]
-    qq = jnp.pad(qq, ((0, 0), (0, 0), (0, pool.shape[3] - qq.shape[-1])))
     sm = 1.0 / _np.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
 
     if isinstance(walk, paged.PagedRows):
         return paged.paged_latent_attention(
-            qq, new_rows, pool, l, walk.positions, walk.tables, walk.active,
-            sm_scale=float(sm), width=rkv, interpret=walk.interpret)
+            q_lat, q_rope, new_rows, pool, l, walk.positions, walk.tables,
+            walk.active, sm_scale=float(sm), interpret=walk.interpret)
+    qq = _pool_query(q_lat, q_rope, pool)                   # [B, H, row]
 
     def rows_block(qq_b, pos_b, pieces_of):
         def fold(carry, pieces, tpos):

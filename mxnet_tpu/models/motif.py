@@ -80,6 +80,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import profiler
 from ..kernels import paged_attention as paged
 from ..kernels.grouped_experts import polynorm
 from ..parallel.moe import routed_experts
@@ -376,12 +377,17 @@ def _gates(cfg, lp, h):
             jax.nn.sigmoid(M._mm(h, lp["wg_o"])))
 
 
-def _differential(cfg, o, lam):
-    """Per-head results ``o`` ``[N, H, w]`` -> the signal heads' ``[N, G,
-    S, w]``: ``a_h - lambda_h a_noise(g)``, float32."""
-    N, G, S = o.shape[0], cfg.groups, cfg.signal_heads
-    o = o.astype(jnp.float32).reshape(N, G, S + 1, -1)
-    return o[:, :, :S] - lam[..., None] * o[:, :, S:]
+def _differential(cfg, o, lam, axis=2):
+    """Per-head results -> the signal heads' ``a_h - lambda_h a_noise(g)``,
+    float32. ``o`` is ``[N, H, w]`` (taken as ``[N, G, S + 1, w]``) or
+    already grouped with its ``S + 1`` heads on ``axis``; ``lam`` has the
+    result's shape less ``w``."""
+    S = cfg.signal_heads
+    if o.ndim == 3:
+        o = o.reshape(o.shape[0], cfg.groups, S + 1, -1)
+    o = o.astype(jnp.float32)
+    return lax.slice_in_dim(o, 0, S, axis=axis) \
+        - lam[..., None] * lax.slice_in_dim(o, S, S + 1, axis=axis)
 
 
 def _out(cfg, lp, o, gate):
@@ -390,23 +396,41 @@ def _out(cfg, lp, o, gate):
     return M._mm(o.reshape(o.shape[0], -1) * gate, lp["wo"])
 
 
-def _absorbed(cfg, lp, h, q_nope, q_rope, attend):
+def _absorbed(cfg, lp, h, q_nope, q_rope, attend, heads_major=False):
     """The decode step's GDLA after the projections: queries moved into the
-    latent space a group, ``attend(q_lat) -> u [B, H, rkv]``, signal and
-    noise combined THERE, then ``W_UV,g``, the gate and ``W_O``."""
-    G, dn, dv, rkv = (cfg.groups, cfg.qk_nope_head_dim, cfg.v_head_dim,
-                      cfg.kv_lora_rank)
-    B, H = q_nope.shape[:2]
+    latent space a group, ``attend(q_lat, q_rope) -> u``, signal and noise
+    combined THERE in float32, then ``W_UV,g``, the gate and ``W_O``.
+
+    The caller's ``heads_major`` chooses the form of ``q_lat`` and ``u``:
+    rows-major ``[B, H, rkv]`` (the lax tier's walks), or heads-major ``[H,
+    B, rkv]`` (the kernels'), head ``h = g (S + 1) + j`` in either;
+    ``q_rope`` ``[B, H, dr]`` is handed on as it is. Heads-major is the form
+    the grouped products emit and read: ``q_lat`` comes out of
+    ``gsbn,rgn->gsbr`` and ``u`` goes into ``gsbr,rgv->bgsv`` with no
+    relayout between them and the kernel. ``u`` may come back in any
+    float dtype; the combination is float32."""
+    G, S, dn, dv, rkv = (cfg.groups, cfg.signal_heads, cfg.qk_nope_head_dim,
+                         cfg.v_head_dim, cfg.kv_lora_rank)
+    B = q_nope.shape[0]
     dt = lp["wkv_b"].dtype
     w_kvb = lp["wkv_b"].reshape(rkv, G, dn + dv)
     w_k, w_v = w_kvb[..., :dn], w_kvb[..., dn:]
-    q_lat = jnp.einsum("bgsn,rgn->bgsr",
-                       q_nope.astype(dt).reshape(B, G, H // G, dn), w_k,
+    q_nope = q_nope.astype(dt).reshape(B, G, S + 1, dn)
+    lam, gate = _gates(cfg, lp, h)                        # lam [B, G, S]
+    form, flat = "bgsr", (B, -1, rkv)
+    if heads_major:
+        # the queries turned BEFORE the product, which then emits
+        # [G][S+1][B][r] as the kernels take it (turned after it, the
+        # product emits B minor and XLA copies it)
+        profiler.record_lowering("latent_heads_major")
+        form, flat = "gsbr", (-1, B, rkv)
+        q_nope = jnp.transpose(q_nope, (1, 2, 0, 3))
+        lam = jnp.transpose(lam, (1, 2, 0))               # [G, S, B]
+    q_lat = jnp.einsum(form[:3] + "n,rgn->" + form, q_nope, w_k,
                        preferred_element_type=jnp.float32)
-    u = attend(q_lat.reshape(B, H, rkv))
-    lam, gate = _gates(cfg, lp, h)
-    sig = _differential(cfg, u, lam)                      # [B, G, S, rkv]
-    o = jnp.einsum("bgsr,rgv->bgsv", sig.astype(dt), w_v,
+    u = attend(q_lat.reshape(flat), q_rope).reshape(q_lat.shape)
+    sig = _differential(cfg, u, lam, form.index("s"))
+    o = jnp.einsum(form + ",rgv->bgsv", sig.astype(dt), w_v,
                    preferred_element_type=jnp.float32)
     return _out(cfg, lp, o, gate)
 
@@ -610,20 +634,21 @@ def motif_decode_step(params, cfg, cache, token_ids, positions, tables,
                                                           positions)
                     rows = M._cache_rows(rows, ring)
 
-                    def latent(q_lat):
+                    def latent(q_lat, q_rope):
                         nonlocal ring
-                        qq = jnp.concatenate([q_lat, q_rope], -1)
-                        qq = jnp.pad(qq.astype(ring.dtype), (
-                            (0, 0), (0, 0), (0, ring.shape[3]
-                                             - qq.shape[-1])))
-                        fn = paged.window_latent_attention if kernel_tier \
-                            else paged.window_latent_attention_lax
-                        kw = {"interpret": interpret} if kernel_tier else {}
-                        u, ring = fn(qq, rows, ring, li, positions, active,
-                                     sm_scale=sm, width=cfg.kv_lora_rank,
-                                     **kw)
+                        if kernel_tier:
+                            u, ring = paged.window_latent_attention(
+                                q_lat, q_rope, rows, ring, li, positions,
+                                active, sm_scale=sm, heads_major=True,
+                                out_dtype=jnp.float32, interpret=interpret)
+                        else:
+                            u, ring = paged.window_latent_attention_lax(
+                                M._pool_query(q_lat, q_rope, ring), rows,
+                                ring, li, positions, active, sm_scale=sm,
+                                width=cfg.kv_lora_rank)
                         return u
-                    return _absorbed(cfg, lp, h, q_nope, q_rope, latent)
+                    return _absorbed(cfg, lp, h, q_nope, q_rope, latent,
+                                     kernel_tier)
                 scope = "gdla.window"
             else:
                 def attend(h, lp=lp, li=li):
@@ -634,12 +659,20 @@ def motif_decode_step(params, cfg, cache, token_ids, positions, tables,
                     if not isinstance(walk, paged.PagedRows):
                         pool = pool.at[li, blk, at].set(rows)
 
-                    def latent(q_lat):
+                    def latent(q_lat, q_rope):
                         nonlocal pool
-                        u, pool = M._latent_attend(cfg, q_lat, q_rope, pool,
-                                                   li, walk, rows)
+                        if kernel_tier:
+                            u, pool = paged.paged_latent_attention(
+                                q_lat, q_rope, rows, pool, li, positions,
+                                tables, active, sm_scale=sm,
+                                heads_major=True, out_dtype=jnp.float32,
+                                interpret=interpret)
+                        else:
+                            u, pool = M._latent_attend(cfg, q_lat, q_rope,
+                                                       pool, li, walk, rows)
                         return u
-                    return _absorbed(cfg, lp, h, q_nope, q_rope, latent)
+                    return _absorbed(cfg, lp, h, q_nope, q_rope, latent,
+                                     kernel_tier)
                 scope = "gdla"
             x = _layer(cfg, lp, x, scope, attend, active, kernels, tallies)
     logits = _logits(cfg, params, x)

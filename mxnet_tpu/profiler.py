@@ -629,8 +629,11 @@ def compile_counters(reset=False):
 # program (the fused step, an executor), once a call in eager mode.
 # ``conv_space_to_depth``: a strided convolution over few input channels
 # run as a stride-1 one over a space-to-depth input (ops/nn.py).
+# ``latent_heads_major``: an attention layer whose latent kernel takes its
+# queries and gives its result heads-major (models/motif.py, a layer a
+# decode step's trace).
 # ----------------------------------------------------------------------
-_LOWERING_ZERO = {"conv_space_to_depth": 0}
+_LOWERING_ZERO = {"conv_space_to_depth": 0, "latent_heads_major": 0}
 _lowering = dict(_LOWERING_ZERO)
 
 

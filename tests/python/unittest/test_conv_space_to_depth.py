@@ -184,4 +184,5 @@ def test_stem_gradient_has_no_lhs_dilated_convolution():
 def test_lowering_counters_reset():
     profiler.record_lowering("conv_space_to_depth")
     assert profiler.lowering_counters(reset=True)["conv_space_to_depth"] >= 1
-    assert profiler.lowering_counters() == {"conv_space_to_depth": 0}
+    assert profiler.lowering_counters() == {"conv_space_to_depth": 0,
+                                            "latent_heads_major": 0}

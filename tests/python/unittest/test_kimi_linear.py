@@ -333,6 +333,23 @@ def test_the_kernel_tier_step_serves_the_lax_tiers_tokens():
         assert m[pre + "moe_rows_computed"] >= m[pre + "moe_assignments"]
 
 
+def test_the_kernel_tier_step_keeps_its_latent_kernel_rows_major(params):
+    """Kimi-Linear's latent layers hand `mx_paged_latent_attn` rows, as the
+    per-head product after it reads them: ``latent_heads_major`` counts
+    none over a trace of the kernel tier's step."""
+    from mxnet_tpu import profiler
+    model = K.KimiLinearDecodeModel(cfg_of(ONE_PERIOD), params=params,
+                                    flash="interpret")
+    sd = lambda s, d: jax.ShapeDtypeStruct(s, d)               # noqa: E731
+    cache = jax.tree_util.tree_map(lambda a: sd(a.shape, a.dtype),
+                                   model.cache_spec(16, 4, 4))
+    profiler.lowering_counters(reset=True)
+    jax.eval_shape(model.step_fn, model.params, cache, sd((4,), jnp.int32),
+                   sd((4,), jnp.int32), sd((4, 4), jnp.int32),
+                   sd((4,), jnp.bool_))
+    assert profiler.lowering_counters()["latent_heads_major"] == 0
+
+
 def test_mla_projection_without_rotary_does_not_read_positions(params):
     """(e) `mla_use_nope`: the same rows whatever the positions; with the
     rotary on, they differ."""

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import motif_reference as ref
 from mxnet_tpu.kernels import paged_attention as paged
 from mxnet_tpu.kernels.grouped_experts import activate, grouped_experts
+from mxnet_tpu.models import moe_mla as MM
 from mxnet_tpu.models import motif as MT
 from mxnet_tpu.models.decode_model import SlotPool
 from mxnet_tpu.parallel.moe import routed_experts
@@ -125,25 +126,63 @@ def test_sinkhorn_is_doubly_stochastic():
 def test_the_window_kernel_equals_its_lax_form(W, row):
     """`mx_window_latent_attn` (interpreted) against its lax form: positions
     before the first wrap, at it and well past it; an inactive row's slot
-    is written by neither, and no other slot or layer either."""
-    B, H, L, width = 6, 8, 3, 64
-    ks = jax.random.split(jax.random.PRNGKey(W), 3)
-    q = jax.random.normal(ks[0], (B, H, row), jnp.float32)
+    is written by neither, and no other slot or layer either; its result is
+    exact zeros, written by the kernel. Both forms of the queries and the
+    result: rows-major, and heads-major as Motif's step hands them."""
+    B, H, L, width, dr = 6, 8, 3, 64, 32
+    ks = jax.random.split(jax.random.PRNGKey(W), 4)
+    q = jax.random.normal(ks[0], (B, H, width), jnp.float32)
+    q_rope = jax.random.normal(ks[3], (B, H, dr), jnp.float32)
     new = jax.random.normal(ks[1], (B, row), jnp.float32)
     ring = jax.random.normal(ks[2], (L, B, W, row), jnp.float32)
     pos = jnp.array([0, 5, W - 1, W, 3 * W + 2, 2], jnp.int32)
     active = jnp.array([True, True, False, True, True, True])
-    kw = dict(sm_scale=0.1, width=width)
-    u, got = paged.window_latent_attention(q, new, ring, 1, pos, active,
-                                           interpret=True, **kw)
-    u_lax, want = paged.window_latent_attention_lax(q, new, ring, 1, pos,
-                                                    active, **kw)
+    u, got = paged.window_latent_attention(q, q_rope, new, ring, 1, pos,
+                                           active, sm_scale=0.1,
+                                           interpret=True)
+    u_lax, want = paged.window_latent_attention_lax(
+        MM._pool_query(q, q_rope, ring), new, ring, 1, pos, active,
+        sm_scale=0.1, width=width)
+    assert u.dtype == ring.dtype
     assert np.abs(np.asarray(u - u_lax)).max() < 1e-5
     assert np.array_equal(np.asarray(got), np.asarray(want))
     changed = np.argwhere(np.asarray(got != ring).any(-1))
     assert sorted(map(tuple, changed)) == sorted(
         (1, b, int(pos[b]) % W) for b in range(B) if active[b])
     assert not np.asarray(u[2]).any()
+    u_h, got_h = paged.window_latent_attention(
+        jnp.swapaxes(q, 0, 1), q_rope, new, ring, 1, pos, active,
+        sm_scale=0.1, heads_major=True, out_dtype=jnp.float32,
+        interpret=True)
+    assert u_h.shape == (H, B, width)
+    assert np.abs(np.asarray(jnp.swapaxes(u_h, 0, 1) - u_lax)).max() < 1e-5
+    assert np.array_equal(np.asarray(got_h), np.asarray(want))
+    assert not np.asarray(u_h[:, 2]).any()
+
+
+def heads_major_forms(model, B=4, mb=4):
+    """``latent_heads_major`` counted over ONE trace of ``model``'s decode
+    step (shapes only: nothing is computed)."""
+    from mxnet_tpu import profiler
+    sd = lambda s, d: jax.ShapeDtypeStruct(s, d)               # noqa: E731
+    cache = jax.tree_util.tree_map(lambda a: sd(a.shape, a.dtype),
+                                   model.cache_spec(16, 4, B))
+    profiler.lowering_counters(reset=True)
+    jax.eval_shape(model.step_fn, model.params, cache, sd((B,), jnp.int32),
+                   sd((B,), jnp.int32), sd((B, mb), jnp.int32),
+                   sd((B,), jnp.bool_))
+    return profiler.lowering_counters()["latent_heads_major"]
+
+
+@pytest.mark.parametrize("flash,forms", [("interpret", 5), ("0", 0)],
+                         ids=["kernels", "lax"])
+def test_the_step_hands_its_kernels_the_heads_major_form(params, flash,
+                                                         forms):
+    """On the kernels' tier every attention layer of a step (two full, three
+    window) takes the heads-major form: ``latent_heads_major`` counts 5 a
+    trace of the step; the lax tier's walks take rows and count none."""
+    model = MT.MotifDecodeModel(cfg_of(TINY), params=params, flash=flash)
+    assert heads_major_forms(model) == forms
 
 
 def test_polynorm_experts_in_the_kernel_equal_the_lax_forms(params):

@@ -372,19 +372,153 @@ def test_the_kernel_serves_no_active_row_and_a_full_batch():
     results."""
     B, H, W = 4, 8, 128
     pool = jnp.full((1, 6, K_BS, W), jnp.nan, jnp.float32)
-    q = jnp.ones((B, H, W), jnp.float32)
+    q = jnp.ones((B, H, 64), jnp.float32)
+    q_rope = jnp.ones((B, H, 32), jnp.float32)
     new = jnp.ones((B, W), jnp.float32)
     tables = jnp.zeros((B, 4), jnp.int32)
     pos = jnp.full((B,), 20, jnp.int32)
-    none, kept = paged_latent_attention(q, new, pool, 0, pos, tables,
+    none, kept = paged_latent_attention(q, q_rope, new, pool, 0, pos, tables,
                                         jnp.zeros((B,), bool), sm_scale=1.0,
-                                        width=128, interpret=True)
+                                        interpret=True)
     assert (np.asarray(none) == 0).all() and np.isnan(np.asarray(kept)).all()
     rng = np.random.RandomState(0)
     pool = jnp.asarray(rng.standard_normal((1, 6, K_BS, W)), jnp.float32)
     tables = jnp.tile(jnp.asarray([[3, 5, 0, 0]], jnp.int32), (B, 1))
     every, _ = paged_latent_attention(
-        q, new, pool, 0, pos, tables, jnp.ones((B,), bool), sm_scale=0.1,
-        width=128, interpret=True)
+        q, q_rope, new, pool, 0, pos, tables, jnp.ones((B,), bool),
+        sm_scale=0.1, interpret=True)
     every = np.asarray(every)
     assert np.abs(every[0]).max() > 0 and (every == every[:1]).all()
+
+
+def ragged_step(B, heads, dtype, seed):
+    """A step of ``B`` rows over `latent_cfg`'s widths: lengths ragged up to
+    the table's end, every third row inactive (interleaved with active
+    ones), random pages. Returns the config, the pool BEFORE the step's
+    write, the pool after it (as the lax tier reads it), the queries
+    ``(q_lat [B, H, rkv], q_rope [B, H, dr])``, the new rows and the rows'
+    ``(positions, tables, active)``."""
+    cfg = latent_cfg(heads)
+    dt = jnp.dtype(dtype)
+    rng = np.random.RandomState(seed)
+    positions = rng.randint(0, K_MB * K_BS, size=B).astype(np.int32)
+    positions[:2] = (0, K_MB * K_BS - 1)
+    active = np.arange(B) % 3 != 1
+    blocks = 1 + rng.permutation(B * K_MB)
+    tables = blocks.reshape(B, K_MB).astype(np.int32)
+    row = cfg.cache_row_width
+    before = rng.standard_normal((2, B * K_MB + 1, K_BS, row))
+    new_rows = rng.standard_normal((B, row))
+    pos, tab, act = (jnp.asarray(a) for a in (positions, tables, active))
+    blk, slot = step_addresses(tab, pos, act, K_BS)
+    before, new_rows = jnp.asarray(before, dt), jnp.asarray(new_rows, dt)
+    written = before.at[LAYER, blk, slot].set(new_rows)
+    q_lat = jnp.asarray(rng.standard_normal((B, heads, cfg.kv_lora_rank)),
+                        jnp.float32)
+    q_rope = jnp.asarray(rng.standard_normal((B, heads,
+                                              cfg.qk_rope_head_dim)),
+                         jnp.float32)
+    return cfg, before, written, (q_lat, q_rope), new_rows, (pos, tab, act)
+
+
+@pytest.mark.parametrize("B", [20, 16, 5])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_latent_kernel_takes_both_forms_and_zeroes_inactive_rows(B,
+                                                                     dtype):
+    """`mx_paged_latent_attn` (interpreted) against the lax walk
+    (`moe_mla._latent_attend`'s, over the pool that already holds the new
+    rows), inactive rows interleaved with active ones, in blocks of 16 rows
+    (20: a last block of 4; 5: one block of the whole batch): rows-major
+    and heads-major give the lax walk's ``u``, transposed for the latter,
+    and every inactive row's result is exact zeros; the pool-dtype result
+    (the rows-major default, what pangu and kimi read) is the float32 one
+    rounded to the pool's dtype; every form writes the same pool."""
+    cfg, before, written, (q_lat, q_rope), new, rows = ragged_step(
+        B, 8, dtype, B)
+    pos, tab, act = rows
+    plan, _ = moe_mla._step_walk(cfg, pos, tab, act, K_BS, False, False)
+    want, _ = moe_mla._latent_attend(cfg, q_lat, q_rope, written, LAYER,
+                                     plan)
+    want = np.asarray(want)
+    sm = float(1.0 / np.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim))
+    call = functools.partial(paged_latent_attention, new_rows=new,
+                             pool=before, layer=LAYER, positions=pos,
+                             tables=tab, active=act, sm_scale=sm,
+                             interpret=True)
+    rows_f32, pool = call(q_lat, q_rope, out_dtype=jnp.float32)
+    heads, pool_h = call(jnp.swapaxes(q_lat, 0, 1), q_rope,
+                         heads_major=True, out_dtype=jnp.float32)
+    in_pool, _ = call(q_lat, q_rope)
+    assert heads.shape == (8, B, cfg.kv_lora_rank)
+    assert in_pool.dtype == before.dtype
+    tol = 1e-5 if dtype == "float32" else 2e-2
+    live = np.asarray(act)
+    for got in (np.asarray(rows_f32), np.swapaxes(np.asarray(heads), 0, 1)):
+        assert (got[~live] == 0).all()
+        np.testing.assert_allclose(got[live], want[live], rtol=tol,
+                                   atol=tol)
+    # the cast the latent family made after the call, moved into it: the
+    # float32 result rounded once, bit for bit
+    assert np.array_equal(np.asarray(in_pool, np.float32), np.asarray(
+        rows_f32.astype(before.dtype), np.float32))
+    # the lax tier wrote the inactive rows' rows into the null block; the
+    # kernel writes an active row's alone
+    blk, slot = (np.asarray(a) for a in step_addresses(tab, pos, act, K_BS))
+    kept = np.asarray(before).copy()
+    kept[LAYER, blk[live], slot[live]] = np.asarray(new)[live]
+    assert (np.asarray(pool) == kept).all()
+    assert (np.asarray(pool_h) == kept).all()
+
+
+@pytest.mark.parametrize("B", [20, 5])
+def test_the_window_kernel_takes_both_forms_past_a_block_of_rows(B):
+    """`mx_window_latent_attn` (interpreted) against its lax form over a
+    ring of 32 rows, inactive rows interleaved, 20 rows (a last block of 4)
+    and 5 (one block): both forms give the lax form's ``u``, exact zeros
+    for an inactive row, and the same ring."""
+    from mxnet_tpu.kernels.paged_attention import (
+        window_latent_attention, window_latent_attention_lax)
+    cfg = latent_cfg(8)
+    rng = np.random.RandomState(B)
+    W, row = 32, cfg.cache_row_width
+    ring = jnp.asarray(rng.standard_normal((2, B, W, row)), jnp.float32)
+    new = jnp.asarray(rng.standard_normal((B, row)), jnp.float32)
+    pos = jnp.asarray(rng.randint(0, 4 * W, size=B), jnp.int32)
+    act = jnp.asarray(np.arange(B) % 3 != 1)
+    q_lat = jnp.asarray(rng.standard_normal((B, 8, cfg.kv_lora_rank)),
+                        jnp.float32)
+    q_rope = jnp.asarray(rng.standard_normal((B, 8, cfg.qk_rope_head_dim)),
+                         jnp.float32)
+    want, ring_want = window_latent_attention_lax(
+        moe_mla._pool_query(q_lat, q_rope, ring), new, ring, 1, pos, act,
+        sm_scale=0.1, width=cfg.kv_lora_rank)
+    rows, ring_rows = window_latent_attention(q_lat, q_rope, new, ring, 1,
+                                              pos, act, sm_scale=0.1,
+                                              interpret=True)
+    heads, ring_heads = window_latent_attention(
+        jnp.swapaxes(q_lat, 0, 1), q_rope, new, ring, 1, pos, act,
+        sm_scale=0.1, heads_major=True, out_dtype=jnp.float32,
+        interpret=True)
+    live = np.asarray(act)
+    for got in (np.asarray(rows), np.swapaxes(np.asarray(heads), 0, 1)):
+        assert (got[~live] == 0).all()
+        assert np.abs(got - np.asarray(want)).max() < 1e-5
+    for got in (ring_rows, ring_heads):
+        assert np.array_equal(np.asarray(got), np.asarray(ring_want))
+
+
+def test_the_latent_family_keeps_its_kernels_rows_major():
+    """The latent family's step (pangu's) on the kernels' tier hands its
+    kernel rows: ``latent_heads_major`` counts none over a trace of it."""
+    from mxnet_tpu import profiler
+    cfg = latent_cfg(8)
+    model = moe_mla.MoEMLADecodeModel(cfg, seed=0, dtype=jnp.float32,
+                                      flash="interpret")
+    sd = lambda s, d: jax.ShapeDtypeStruct(s, d)               # noqa: E731
+    cache = jax.tree_util.tree_map(lambda a: sd(a.shape, a.dtype),
+                                   model.cache_spec(16, K_BS, 4))
+    profiler.lowering_counters(reset=True)
+    jax.eval_shape(model.step_fn, model.params, cache, sd((4,), jnp.int32),
+                   sd((4,), jnp.int32), sd((4, K_MB), jnp.int32),
+                   sd((4,), jnp.bool_))
+    assert profiler.lowering_counters()["latent_heads_major"] == 0

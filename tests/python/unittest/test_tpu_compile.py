@@ -12,6 +12,8 @@ this one file (a second file could land on another worker, whose fixture
 would then skip), compile in this process, and keep the persistent compile
 cache off (a described-device executable cannot be read back from it).
 """
+import functools
+
 import numpy as np
 import pytest
 
@@ -355,21 +357,24 @@ def test_paged_latent_attention_compiles_at_the_cells_shapes(
     """`mx_paged_latent_attn` at the two latent cells' shapes (256 rows of
     128 or 32 heads over 640-wide bfloat16 rows, tables of 256 or 512 pages
     of 16, the whole table in scalar memory): the page copies, the merged
-    ``(pages, 16)`` view of a chunk and the lane slice of the values pass
-    Mosaic, as do the new row's select into its page and the page's copy
-    back; the pool is aliased to the output, never copied, and nothing but
-    the rows' order is made beside it."""
+    ``(pages, 16)`` view of a chunk, the two score products (the latent
+    query against a row's first 512 numbers, the rotary one against the 64
+    after them) and the lane slice of the values pass Mosaic, as do the new
+    row's select into its page and the page's copy back; the pool is
+    aliased to the output, never copied, and nothing but the rows' chain is
+    made beside it."""
     from mxnet_tpu.kernels.paged_attention import paged_latent_attention
     B = 256
     sd = lambda s, d: jax.ShapeDtypeStruct(s, d,               # noqa: E731
                                            sharding=one_chip)
 
-    def attend(q, new_rows, pool, positions, tables, active):
-        return paged_latent_attention(q, new_rows, pool, 1, positions,
-                                      tables, active, sm_scale=0.0722,
-                                      width=512)
-    compiled = jax.jit(attend, donate_argnums=(2,)).lower(
-        sd((B, heads, 640), jnp.bfloat16), sd((B, 640), jnp.bfloat16),
+    def attend(q, q_rope, new_rows, pool, positions, tables, active):
+        return paged_latent_attention(q, q_rope, new_rows, pool, 1,
+                                      positions, tables, active,
+                                      sm_scale=0.0722)
+    compiled = jax.jit(attend, donate_argnums=(3,)).lower(
+        sd((B, heads, 512), jnp.bfloat16), sd((B, heads, 64), jnp.bfloat16),
+        sd((B, 640), jnp.bfloat16),
         sd((layers, blocks, 16, 640), jnp.bfloat16), sd((B,), jnp.int32),
         sd((B, mb), jnp.int32), sd((B,), jnp.bool_)).compile()
     text = compiled.as_text()
@@ -412,6 +417,83 @@ def _latent_cell(name):
     return Model(cfg, params=params, flash="on"), engine, latent
 
 
+def _instructions(text):
+    """``{name: line}`` of every instruction in a compiled program's text."""
+    import re
+    out = {}
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = ", ln)
+        if m:
+            out[m.group(1)] = ln
+    return out
+
+
+def _kernel_neighbours(text, kernel, operand):
+    """For every call of the Pallas kernel named ``kernel``: the instruction
+    that makes its ``operand``-th operand and those that read its first
+    result, bitcasts looked through (they move no byte)."""
+    import re
+    lines = _instructions(text)
+    users = {}
+    for name, ln in lines.items():
+        for used in set(re.findall(r"(%[\w.\-]+)(?=[,)])",
+                                   ln.split("=", 1)[1])):
+            users.setdefault(used, []).append(name)
+
+    def made_by(name):
+        while " bitcast(" in lines[name]:
+            name = re.search(r" bitcast\((%[\w.\-]+)\)",
+                             lines[name]).group(1)
+        return name
+
+    def read_by(name):
+        out = []
+        for u in users.get(name, []):
+            out += read_by(u) if " bitcast(" in lines[u] else [u]
+        return out
+
+    found = []
+    for name, ln in lines.items():
+        if kernel not in ln or "custom-call(" not in ln:
+            continue
+        args = re.search(r"custom-call\((.*?)\), custom_call_target",
+                         ln).group(1)
+        args = [a.strip() for a in re.sub(r"/\*index=\d+\*/", "",
+                                          args).split(",")]
+        result = [u for u in users.get(name, [])
+                  if "index=0" in lines[u] and "get-tuple-element(" in
+                  lines[u]]
+        found.append((lines[made_by(args[operand])],
+                      [lines[r] for g in result for r in read_by(g)]))
+    return found
+
+
+def _relayout(ln):
+    """A copy or a select: an instruction that moves a result as it is."""
+    name, rhs = ln.split("=", 1)
+    return " copy(" in rhs.split("metadata=")[0] or "select" in name
+
+
+@functools.lru_cache(maxsize=None)
+def _latent_step(cell, one_chip):
+    """A latent cell's decode step compiled at the served size, on the
+    kernel tier: ``(model, cache, compiled)``."""
+    model, e, _ = _latent_cell(cell)
+    on_chip = lambda t: jax.tree_util.tree_map(                 # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        t)
+    params = on_chip(model.params)
+    B, bs = e["batch_size"], e["block_size"]
+    mb = e["max_seq_len"] // bs
+    cache = on_chip(model.cache_spec(e["num_blocks"], bs, B))
+    sd = lambda s, d: jax.ShapeDtypeStruct(s, d,               # noqa: E731
+                                           sharding=one_chip)
+    compiled = jax.jit(model.step_fn, donate_argnums=(1,)).lower(
+        params, cache, sd((B,), jnp.int32), sd((B,), jnp.int32),
+        sd((B, mb), jnp.int32), sd((B,), jnp.bool_)).compile()
+    return model, cache, compiled
+
+
 @pytest.mark.parametrize("cell", ["kimi", "pangu"])
 def test_latent_step_reads_its_pages_in_place_and_rematerialises_no_pool(
         one_chip, cell):
@@ -433,19 +515,9 @@ def test_latent_step_reads_its_pages_in_place_and_rematerialises_no_pool(
     was ``[32, 512, 640]``), and its temporaries are under the lax
     step's."""
     import re
-    model, e, latent = _latent_cell(cell)
-    on_chip = lambda t: jax.tree_util.tree_map(                 # noqa: E731
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        t)
-    params = on_chip(model.params)
-    B, bs = e["batch_size"], e["block_size"]
-    mb = e["max_seq_len"] // bs
-    cache = on_chip(model.cache_spec(e["num_blocks"], bs, B))
-    sd = lambda s, d: jax.ShapeDtypeStruct(s, d,               # noqa: E731
-                                           sharding=one_chip)
-    compiled = jax.jit(model.step_fn, donate_argnums=(1,)).lower(
-        params, cache, sd((B,), jnp.int32), sd((B,), jnp.int32),
-        sd((B, mb), jnp.int32), sd((B,), jnp.bool_)).compile()
+    _, e, latent = _latent_cell(cell)
+    B = e["batch_size"]
+    model, cache, compiled = _latent_step(cell, one_chip)
     text = compiled.as_text()
     if cell == "kimi":
         assert text.count("mx_kda_step") >= len(model.cfg.kda_layers)
@@ -461,13 +533,37 @@ def test_latent_step_reads_its_pages_in_place_and_rematerialises_no_pool(
     latent = "bf16" + pools[0]
     assert not [ln for ln in text.splitlines()
                 if latent in ln and "scatter" in ln]
-    # 640-wide and three-dimensional: the queries and the new rows only
+    # 640-wide and three-dimensional: the new rows only (the queries go in
+    # as a 512-wide latent part and a 64-wide rotary one)
     pieces = set(re.findall(r"bf16\[\d+,\d+,640\]", text))
-    H = model.cfg.num_attention_heads
-    assert pieces <= {"bf16[%d,%d,640]" % (B, H), "bf16[%d,1,640]" % B}, \
-        pieces
+    assert pieces <= {"bf16[%d,1,640]" % B}, pieces
     assert compiled.memory_analysis().temp_size_in_bytes < {
         "pangu": 280950272, "kimi": 331771392}[cell]
+
+
+@pytest.mark.parametrize("cell", ["kimi", "pangu"])
+def test_latent_step_hands_its_kernel_rows_and_takes_its_result_as_it_is(
+        one_chip, cell):
+    """The two latent cells' decode steps at the served sizes: every
+    `mx_paged_latent_attn` takes its latent queries without a 640-wide
+    padded copy and hands ``u``, in the pool's bfloat16 and rows-major,
+    straight to the per-head value product: no select over ``[rows, heads,
+    512]`` (the kernel writes an inactive row's zeros itself) and no copy
+    after the call. (Before the call XLA still turns the per-head query
+    product's ``[H][r][B]`` into rows, a copy the padded query fusion held
+    before; PERF.md §7.5.)"""
+    model, cache, compiled = _latent_step(cell, one_chip)
+    text = compiled.as_text()
+    B, H = _latent_cell(cell)[1]["batch_size"], model.cfg.num_attention_heads
+    assert "bf16[%d,%d,640]" % (B, H) not in text
+    assert not [ln for name, ln in _instructions(text).items()
+                if "select" in name and "[%d,%d,512]" % (B, H) in ln]
+    found = _kernel_neighbours(text, "mx_paged_latent_attn", 6)
+    assert len(found) == (len(model.cfg.full_attn_layers) if cell == "kimi"
+                          else model.cfg.num_hidden_layers)
+    for made, read in found:
+        assert "640]" not in made.split("=", 1)[1][:40], made[:160]
+        assert read and not [ln for ln in read if _relayout(ln)], read
 
 
 def _eva_cell():
@@ -538,17 +634,10 @@ def test_evabyte_programs_write_each_pool_once_and_copy_none(one_chip,
         assert len(calls) == model.cfg.num_hidden_layers
 
 
-def test_motif_step_writes_its_rings_in_place_and_rematerialises_no_pool(
-        one_chip):
-    """The Motif-3 cell's decode step at the served size (512 slots, tables
-    of 384 pages, the two full layers' latent pool and the three window
-    layers' rings), on the kernel tier. Each ring is written by its
-    `mx_window_latent_attn` call alone, a chain of aliased calls that takes
-    the pool whole and hands it on: no scatter of XLA's into it, and no
-    instruction the compiler rematerialised touches a donated pool (three
-    XLA scatters a step into one pool beside the kernels' reads are what
-    the one-write rule forbids). The full layers walk their pages in
-    `mx_paged_latent_attn`; both pools come back in place."""
+@functools.lru_cache(maxsize=None)
+def _motif_step(one_chip):
+    """The Motif-3 cell's decode step compiled at the served size, on the
+    kernel tier: ``(cfg, engine geometry, cache, compiled)``."""
     import json
     import os
     from mxnet_tpu.models.motif import (MotifConfig, MotifDecodeModel,
@@ -575,6 +664,21 @@ def test_motif_step_writes_its_rings_in_place_and_rematerialises_no_pool(
         on_chip(model.params), cache, sd((B,), jnp.int32),
         sd((B,), jnp.int32), sd((B, mb), jnp.int32),
         sd((B,), jnp.bool_)).compile()
+    return cfg, e, cache, compiled
+
+
+def test_motif_step_writes_its_rings_in_place_and_rematerialises_no_pool(
+        one_chip):
+    """The Motif-3 cell's decode step at the served size (512 slots, tables
+    of 384 pages, the two full layers' latent pool and the three window
+    layers' rings), on the kernel tier. Each ring is written by its
+    `mx_window_latent_attn` call alone, a chain of aliased calls that takes
+    the pool whole and hands it on: no scatter of XLA's into it, and no
+    instruction the compiler rematerialised touches a donated pool (three
+    XLA scatters a step into one pool beside the kernels' reads are what
+    the one-write rule forbids). The full layers walk their pages in
+    `mx_paged_latent_attn`; both pools come back in place."""
+    cfg, _, cache, compiled = _motif_step(one_chip)
     text = compiled.as_text()
     pools = {k: "bf16[%s]" % ",".join(str(n) for n in p.shape)
              for k, p in cache.items()}
@@ -591,3 +695,30 @@ def test_motif_step_writes_its_rings_in_place_and_rematerialises_no_pool(
     assert mem.alias_size_in_bytes == sum(
         int(np.prod(p.shape)) * 2 for p in cache.values())
     assert mem.temp_size_in_bytes < 300 << 20
+
+
+@pytest.mark.parametrize("kernel,operand", [
+    ("mx_paged_latent_attn", 6), ("mx_window_latent_attn", 5)],
+    ids=["full", "window"])
+def test_motif_step_hands_its_kernels_heads_major_and_copies_neither_way(
+        one_chip, kernel, operand):
+    """The Motif-3 cell's decode step at the served size: each GDLA kernel
+    takes its latent queries ``[80, 512, 512]`` straight from the grouped
+    ``gsbn,rgn->gsbr`` product and hands ``u`` straight to the signal-noise
+    combination and ``gsbr,rgv->bgsv``: no copy or select makes the one or
+    reads the other, no 640-wide padded query is made and no select runs
+    over a ``[512, 80, 512]`` result (the kernel writes an inactive row's
+    zeros itself)."""
+    cfg, e, _, compiled = _motif_step(one_chip)
+    text = compiled.as_text()
+    B, H = e["batch_size"], cfg.num_attention_heads
+    assert "bf16[%d,%d,640]" % (B, H) not in text
+    assert not [ln for name, ln in _instructions(text).items()
+                if "select" in name and ("[%d,%d,512]" % (B, H) in ln
+                                         or "[%d,%d,512]" % (H, B) in ln)]
+    found = _kernel_neighbours(text, kernel, operand)
+    assert len(found) == (cfg.full_layers if "paged" in kernel
+                          else cfg.window_layers)
+    for made, read in found:
+        assert not _relayout(made), made[:160]
+        assert read and not [ln for ln in read if _relayout(ln)], read
